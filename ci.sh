@@ -41,13 +41,13 @@ cd "$(dirname "$0")"
 echo "=== lint 1/3: jsan (python -m rlgpuschedule_tpu.analysis) ==="
 JSAN_CACHE="${JSAN_CACHE:-.jsan_cache}"
 python -m rlgpuschedule_tpu.analysis \
-    rlgpuschedule_tpu bench.py __graft_entry__.py \
+    rlgpuschedule_tpu bench.py chip_smoke.py __graft_entry__.py \
     --baseline jsan_baseline.json --fail-stale --cache "$JSAN_CACHE"
 
 echo "=== lint 1/3b: jsan SARIF gate (warm --cache replay) ==="
 JSAN_SARIF=$(mktemp /tmp/ci_jsan.XXXXXX.sarif)
 python -m rlgpuschedule_tpu.analysis \
-    rlgpuschedule_tpu bench.py __graft_entry__.py \
+    rlgpuschedule_tpu bench.py chip_smoke.py __graft_entry__.py \
     --baseline jsan_baseline.json --format sarif \
     --cache "$JSAN_CACHE" > "$JSAN_SARIF"
 python - "$JSAN_SARIF" <<'PY'
@@ -79,7 +79,7 @@ if command -v ruff >/dev/null 2>&1; then
         echo "FAIL: ruff $have installed but pyproject.toml pins ruff==$want" >&2
         exit 1
     fi
-    ruff check rlgpuschedule_tpu tests
+    ruff check rlgpuschedule_tpu tests chip_smoke.py
 else
     echo "SKIP: ruff not installed (pinned ruff==0.6.9 in pyproject.toml)"
 fi

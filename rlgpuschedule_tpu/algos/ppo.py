@@ -386,7 +386,13 @@ def make_train_state(net, key: jax.Array, example_obs: jax.Array,
     interchangeable with the default state, by design)."""
     params = net.init(key, example_obs, *extra_apply_args, example_mask)
     if reward_norm:
-        return NormTrainState.create(apply_fn=net.apply, params=params,
-                                     tx=tx,
-                                     reward_stats=init_reward_stats())
-    return TrainState.create(apply_fn=net.apply, params=params, tx=tx)
+        state = NormTrainState.create(apply_fn=net.apply, params=params,
+                                      tx=tx,
+                                      reward_stats=init_reward_stats())
+    else:
+        state = TrainState.create(apply_fn=net.apply, params=params, tx=tx)
+    # flax starts ``step`` as the Python int 0 and the first train step
+    # hands back an int32 array: on jax 0.9.0 that is a different jit
+    # signature, so iteration 1 re-traced the whole train step (a
+    # post-warmup recompile alarm, and seconds of host time at config 2)
+    return state.replace(step=jnp.zeros((), jnp.int32))

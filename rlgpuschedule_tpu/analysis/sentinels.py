@@ -79,14 +79,7 @@ class CompileCounter:
         return self
 
     def __exit__(self, *exc) -> None:
-        # unregistration is a private API; degrade to a dead listener
-        # (self-deactivating closure) if it moves
-        try:
-            from jax._src import monitoring as _monitoring
-            _monitoring._unregister_event_duration_listener_by_callback(
-                self._listener)
-        except (ImportError, AttributeError, ValueError):  # pragma: no cover
-            self.backend_compiles = self.traces = -1
+        jax.monitoring.unregister_event_duration_listener(self._listener)
         self._listener = None
 
 

@@ -10,9 +10,12 @@ implemented natively here (``fast_oracle.cpp``) and cross-validated
 against the Python oracle property-by-property.
 
 The shared library is built on first use with the system ``g++`` (no build
-system, no pybind11 — plain C ABI via ctypes), cached next to the source
-keyed by source hash, and every entry point degrades gracefully to the
-Python oracle when no toolchain is present (``available()`` gates it).
+system, no pybind11 — plain C ABI via ctypes) from the committed
+``fast_oracle.cpp`` alone, cached keyed by source hash in the same
+directory as the jax compile cache (``utils.platform.cache_dir``: inside
+the checkout unless the environment places it), and every entry point
+degrades gracefully to the Python oracle when no toolchain is present
+(``available()`` gates it; ``chip_smoke.py`` prints which one ran).
 """
 from __future__ import annotations
 
@@ -33,12 +36,11 @@ _build_error: str | None = None
 
 
 def _so_path() -> str:
-    # user-owned 0700 cache dir, NOT the shared tmp dir: a predictable
-    # world-writable path could be pre-seeded by another local user and
-    # dlopen runs arbitrary constructors
-    cache = os.environ.get("XDG_CACHE_HOME",
-                           os.path.join(os.path.expanduser("~"), ".cache"))
-    d = os.path.join(cache, "rlgpuschedule_tpu")
+    # 0700 and never the shared tmp dir: a predictable world-writable
+    # path could be pre-seeded by another local user and dlopen runs
+    # arbitrary constructors
+    from ..utils.platform import cache_dir
+    d = os.path.join(cache_dir(), "native")
     os.makedirs(d, mode=0o700, exist_ok=True)
     with open(_SRC, "rb") as f:
         tag = hashlib.sha256(f.read()).hexdigest()[:16]

@@ -37,21 +37,6 @@ from .sharding import put_global as _put_global
 from .sharding import shrink_env_rows_by_rule as _shrink_by_rule
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across jax versions: newer jax exposes it at top
-    level with a ``check_vma`` kwarg; 0.4/0.5 at
-    ``jax.experimental.shard_map`` with the same knob named
-    ``check_rep``. The seed imported only the new location, so the whole
-    explicit-collective path was an ImportError on the pinned jax."""
-    try:
-        from jax import shard_map as sm
-        kw = {"check_vma": check}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        kw = {"check_rep": check}
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def put_global(tree: Any, sharding: NamedSharding) -> Any:
     """DEPRECATED shim: the implementation moved to
     ``parallel.sharding.put_global`` (the rule engine owns placement).
@@ -175,11 +160,11 @@ def shard_map_train(mesh: Mesh, train_step_axis: Callable, train_state,
             lambda m: jax.lax.pmean(m, DATA_AXIS), metrics)
         return state, local._replace(key=local.key[None]), metrics
 
-    jitted = jax.jit(shard_map_compat(
+    jitted = jax.jit(jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(rep_spec, carry_spec, env_spec, rep_spec),
         out_specs=(rep_spec, carry_spec, rep_spec),
-        check=False), donate_argnums=(0, 1))
+        check_vma=False), donate_argnums=(0, 1))
 
     keys = jax.random.split(jnp.asarray(carry.key), n_data)
     carry = carry._replace(key=keys)

@@ -161,8 +161,8 @@ def main(argv: list[str] | None = None) -> None:
     jax.config.update("jax_platforms", "cpu")
     # no persistent compile cache in multi-controller workers: RELOADING
     # a serialized gloo-collective executable segfaults the rank on this
-    # jax (measured; __graft_entry__'s spawners scrub the env var too,
-    # but the config can arrive set via enable_compile_cache's export)
+    # jax (measured; __graft_entry__'s spawners drop
+    # JAX_COMPILATION_CACHE_DIR too, but any other launcher may pass it)
     jax.config.update("jax_compilation_cache_dir", None)
     from rlgpuschedule_tpu.parallel import multihost
     from rlgpuschedule_tpu.resilience import (FaultInjector, HeartbeatWriter,

@@ -94,10 +94,28 @@ def _median_time(fn, repeats: int) -> float:
 # for the "dispatch/HBM-bound" assertion (VERDICT r4 missing #4):
 # mfu_total over the whole fused step, and mfu_update over the update
 # stage alone (the only stage whose matmuls could fill the MXU — the
-# env scan does no matmul work). Public bf16 peaks per chip.
+# env scan does no matmul work). Public bf16 peaks per chip, keyed by
+# the lower-cased ``device_kind`` without its "TPU " prefix.
 BF16_PEAK = {"v4": 275e12, "v5 lite": 197e12, "v5e": 197e12,
              "v5p": 459e12, "v5": 459e12, "v6 lite": 918e12,
              "v6e": 918e12}
+
+
+def bf16_peak(platform: str, device_kind: str) -> "float | None":
+    """Peak bf16 FLOP/s of the attached chip; ``None`` off-TPU (no MFU
+    is priced there). The match is EXACT on the kind — a substring match
+    would price ``"TPU v5 lite"`` (v5e) at the ``"v5"`` (v5p) row — and a
+    TPU kind that is not in the table is an error, not a silently-null
+    MFU."""
+    if platform != "tpu":
+        return None
+    kind = device_kind.lower().removeprefix("tpu").strip()
+    if kind not in BF16_PEAK:
+        raise SystemExit(
+            f"profile_breakdown: no bf16 peak recorded for TPU "
+            f"device_kind {device_kind!r}; add it to BF16_PEAK (known: "
+            f"{sorted(BF16_PEAK)})")
+    return BF16_PEAK[kind]
 
 
 def _sweep_minibatch(args, ppo, platform, kind, peak, B, n_params,
@@ -355,10 +373,8 @@ def main(argv: list[str] | None = None) -> dict:
     key, k_sweep, k_upd, k_warm = jax.random.split(key, 4)
     B = n_steps * n_envs
     n_params = sum(x.size for x in jax.tree.leaves(state.params))
-    kind = getattr(jax.devices()[0], "device_kind", "").lower()
-    peak = next((v for k, v in BF16_PEAK.items()
-                 if f"tpu {k}" in kind or kind == k), None) \
-        if platform == "tpu" else None
+    kind = jax.devices()[0].device_kind.lower()
+    peak = bf16_peak(platform, kind)
 
     # ---- stage jits (batch inputs are reused across repeats, so only the
     # update's state — the buffers production donates — is donated and

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import os
 import time
+import zlib
 from typing import Any
 
 import jax
@@ -112,6 +113,12 @@ def run_bench(engine, server, pool: "list[tuple[Any, Any]]",
             cursor += 1
         server.pump()
     results = [f.result(timeout=60) for f in futures]
+    # digest of every served action in submit order: two runs of the same
+    # checkpoint and stream (a cold and a cache-warm one, say) must agree
+    crc = 0
+    for res in results:
+        for leaf in jax.tree.leaves(res.action):
+            crc = zlib.crc32(np.asarray(leaf).tobytes(), crc)
 
     # a DATA site, not a gauge refresh: the snapshot dict is the bench
     # report (gauge freshness is the registry collector hook's job now)
@@ -126,6 +133,7 @@ def run_bench(engine, server, pool: "list[tuple[Any, Any]]",
         "warmed_buckets": [int(b) for b in engine.warmed_buckets],
         **snap,
         "requests": len(results),
+        "actions_crc32": f"{crc:08x}",
     }
 
 

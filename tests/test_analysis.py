@@ -144,7 +144,8 @@ class TestCLI:
     def test_shipped_tree_is_clean(self):
         """Acceptance gate: the analyzer exits 0 over the shipped
         package + top-level scripts (everything fixed or suppressed)."""
-        r = _cli("rlgpuschedule_tpu", "bench.py", "__graft_entry__.py")
+        r = _cli("rlgpuschedule_tpu", "bench.py", "chip_smoke.py",
+                 "__graft_entry__.py")
         assert r.returncode == 0, r.stdout + r.stderr
 
     def test_seeded_bad_snippet_fails_the_tree(self, tmp_path):

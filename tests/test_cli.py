@@ -67,24 +67,107 @@ class TestMetricsLogger:
         assert "a" in t.report() and t.report()["a"] >= 0
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run_script(*argv, cwd=REPO, timeout=300):
+    """``python <argv>`` as a user runs it, on the CPU: the scripts that
+    need a chip must fail here, visibly."""
+    import subprocess
+    import sys
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO}
+    return subprocess.run([sys.executable, *argv], cwd=cwd, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
 class TestCompileCache:
-    def test_enable_compile_cache_points_jax_at_the_dir(self, tmp_path,
-                                                        monkeypatch):
-        # CLI processes must reuse one persistent XLA cache (measured:
-        # the grid-CNN program build is ~10 min on this host, re-paid
-        # per process without it). Explicit env var wins; jax config and
-        # the subprocess-facing env var both end up set.
+    @pytest.fixture(autouse=True)
+    def _restore_jax_cache_dir(self):
         import jax
-        from rlgpuschedule_tpu.utils.platform import enable_compile_cache
         prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_var_places_the_cache_and_nothing_else_does(self, tmp_path,
+                                                            monkeypatch):
+        # whoever runs the program places the cache: with the env var set
+        # the helper uses exactly that directory, for jax and for the
+        # native oracle's .so alike, and sets no other in code
+        import jax
+        from rlgpuschedule_tpu.utils.platform import (cache_dir,
+                                                      enable_compile_cache)
         target = str(tmp_path / "cache")
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
-        try:
-            assert enable_compile_cache() == target
-            assert jax.config.jax_compilation_cache_dir == target
-            assert os.environ["JAX_COMPILATION_CACHE_DIR"] == target
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+        cap = jax.config.jax_compilation_cache_max_size
+        assert enable_compile_cache() == target == cache_dir()
+        assert jax.config.jax_compilation_cache_dir == target
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == target
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        # the helper sets no size cap of its own: one would switch the
+        # directory to jax's LRU layout, unwritable for every process
+        # that did not set the same cap. Whoever places it bounds it.
+        assert jax.config.jax_compilation_cache_max_size == cap
+
+    def test_default_is_one_fixed_path_inside_the_checkout(self,
+                                                           monkeypatch):
+        # unset: <repo>/.jax_cache — resolved from the package's own
+        # location, so two calls and two processes agree and nothing of
+        # $HOME, a temp dir, a pid or the clock is in it
+        import jax
+        from rlgpuschedule_tpu.utils.platform import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(REPO, ".jax_cache")
+        assert enable_compile_cache() == want == enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == want
+        assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+        for cwd in (REPO, "/"):
+            out = _run_script(
+                "-c", "from rlgpuschedule_tpu.utils.platform import "
+                      "enable_compile_cache as e; print(e())", cwd=cwd)
+            assert out.stdout.strip() == want, out.stderr
+
+
+class TestNoHiddenFallback:
+    """The chip entry points fail without a TPU; --cpu / --tiny are the
+    only ways onto the CPU and both label their output."""
+
+    @pytest.mark.parametrize("script", ["bench.py", "chip_smoke.py"])
+    def test_chip_script_without_a_tpu_fails_and_prints_no_result(
+            self, script):
+        r = _run_script(script)
+        assert r.returncode != 0
+        assert r.stdout == "", r.stdout       # no metric, no result line
+        assert "TPU" in r.stderr.strip().splitlines()[-1]
+
+    def test_chip_smoke_tiny_rehearsal_ends_ok_false_on_cpu(self):
+        r = _run_script("chip_smoke.py", "--tiny")
+        assert r.returncode == 0, r.stderr[-4000:]   # the phases passed
+        lines = [json.loads(ln) for ln in r.stdout.strip().splitlines()]
+        assert lines[-1] == {"ok": False,
+                             "device": {"platform": "cpu", "kind": "cpu",
+                                        "count": 1}}
+        assert lines[-2]["phases_ok"] is True
+        assert lines[-2]["ran"] == ["train", "serve", "evaluate"]
+        phases = {ln["phase"]: ln for ln in lines[:-1]}
+        assert phases["serve"]["post_warmup_recompiles"] == 0
+        assert phases["evaluate"]["policy_completion"] == 1.0
+        # bench.py, the CLIs and this script share one cache directory
+        # and one on-disk format
+        assert "Error writing persistent compilation cache" not in r.stderr
+
+    @pytest.mark.parametrize("kind,peak", [
+        ("TPU v5 lite", 197e12),    # what a v5e chip reports: NOT the
+        ("TPU v5e", 197e12),        # "v5" (v5p) row a substring would hit
+        ("TPU v5", 459e12), ("TPU v5p", 459e12), ("TPU v6 lite", 918e12)])
+    def test_mfu_peak_lookup_is_exact_on_device_kind(self, kind, peak):
+        from rlgpuschedule_tpu.profile_breakdown import bf16_peak
+        assert bf16_peak("tpu", kind) == peak
+        assert bf16_peak("cpu", "cpu") is None
+
+    def test_unknown_tpu_kind_is_an_error_not_a_null_mfu(self):
+        from rlgpuschedule_tpu.profile_breakdown import bf16_peak
+        with pytest.raises(SystemExit, match="TPU v9"):
+            bf16_peak("tpu", "TPU v9")
 
 
 class TestTrainCLI:

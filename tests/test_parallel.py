@@ -128,7 +128,6 @@ class TestDPTraining:
         # variance; the E[x²]−mean² form is. With per-shard-constant values
         # the old form divided by ~0 and exploded.
         from jax.sharding import PartitionSpec as P
-        from rlgpuschedule_tpu.parallel.dp import shard_map_compat
         mesh = make_mesh()
         x = jnp.repeat(jnp.arange(8.0), 2)  # 16 vals, constant per shard
 
@@ -137,8 +136,8 @@ class TestDPTraining:
             sq = jax.lax.pmean(jnp.mean(xs ** 2), DATA_AXIS)
             return (xs - m) / jnp.sqrt(sq - m ** 2 + 1e-8)
 
-        y = shard_map_compat(normalize, mesh=mesh, in_specs=P(DATA_AXIS),
-                             out_specs=P(DATA_AXIS))(x)
+        y = jax.shard_map(normalize, mesh=mesh, in_specs=P(DATA_AXIS),
+                          out_specs=P(DATA_AXIS))(x)
         np.testing.assert_allclose(float(jnp.std(y)), 1.0, rtol=1e-4)
 
     def test_indivisible_envs_rejected(self):
@@ -181,7 +180,6 @@ class TestShardMapDP:
         # per-shard advantage moments are globally pmean'd).
         from jax.sharding import PartitionSpec as P
         from rlgpuschedule_tpu.algos import ppo_loss, Transition
-        from rlgpuschedule_tpu.parallel.dp import shard_map_compat
         from rlgpuschedule_tpu.algos.ppo import normalize_advantages
         env_params, traces, state, carry, _ = build(n_envs=8,
                                                     dtype=jnp.float32)
@@ -213,10 +211,10 @@ class TestShardMapDP:
             return jax.lax.pmean(g, DATA_AXIS)
 
         g_ref = jax.jit(global_grad)(state.params)
-        g_map = jax.jit(shard_map_compat(
+        g_map = jax.jit(jax.shard_map(
             shard_grad, mesh=mesh,
             in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
-            out_specs=P(), check=False))(state.params, batch, adv, ret)
+            out_specs=P(), check_vma=False))(state.params, batch, adv, ret)
         for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_map)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-5)

@@ -694,6 +694,11 @@ class TestBench:
         assert report["post_warmup_recompiles"] == 0
         assert report["buckets"] == [8]
         assert report["requests"] == 2 * (5 + 7 + 8)
+        # same pool, same stream -> the same served actions
+        again = run_bench(engine, server, pool, rounds=6,
+                          request_sizes=(5, 7, 8))
+        assert len(report["actions_crc32"]) == 8
+        assert again["actions_crc32"] == report["actions_crc32"]
         assert report["decisions_per_s"] > 0
         assert report["latency_p50_ms"] > 0
         assert report["latency_p99_ms"] >= report["latency_p50_ms"]

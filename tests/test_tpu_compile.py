@@ -1,0 +1,118 @@
+"""The main path's jitted programs compile for the chip, without the chip.
+
+The TPU compiler is installed here and compiles for a DESCRIBED topology
+(``v5e:2x2`` -> 4 x "TPU v5 lite"), so these guard every later PR at no
+chip time: a program the chip's compiler would refuse, or one that no
+longer fits 16 GB of HBM, fails here first. Shapes are the real ones
+``chip_smoke.py`` runs (config 2, 512 GPUs, 256 envs x 768-job windows,
+queue 128, 128-step rollouts). Nothing executes, so nothing here says
+anything about results or speed.
+
+These are not two-second kernels: a whole train step costs ~35 s to
+compile whatever the batch (the sim's control flow, not the shapes) on top
+of ~15 s of host-side ``Experiment.build``, so exactly one is kept, next
+to the serve engine's ``policy_step``. The update stage alone is not
+cheap (26 s) and the train step contains it, so it is not kept. Each test
+has its own time limit; the limit cannot interrupt a compile, it fails
+the test that overran. The four-chip mesh compile stays a builder's
+rehearsal (CHANGES.md, PR 24).
+"""
+import contextlib
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import chip_smoke
+from rlgpuschedule_tpu.experiment import Experiment
+
+HBM_BYTES = 16 * 1024 ** 3      # one v5e chip
+
+
+@contextlib.contextmanager
+def time_limit(seconds: float):
+    t0 = time.monotonic()
+    yield
+    took = time.monotonic() - t0
+    assert took <= seconds, f"took {took:.0f}s, limit {seconds}s"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip as a sharding; skips where the
+    topology cannot be described. The persistent compile cache is off
+    around the module: a described-topology executable is written to it
+    but can never be read back without a chip, so every later run would
+    warn and compile again."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    assert {d.device_kind for d in topo.devices} == {"TPU v5 lite"}
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def exp():
+    cfg = chip_smoke.smoke_cfg(chip_smoke.FULL)    # exactly what it runs
+    assert cfg.name == "ppo-cnn-philly512" and cfg.total_gpus == 512
+    assert cfg.ppo.n_steps * cfg.n_envs == 32_768
+    return Experiment.build(cfg, jit=False)
+
+
+def shapes(tree, sharding):
+    """The tree as ShapeDtypeStructs on the described chip (there is no
+    device to hold an array, so programs are lowered from shapes)."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x),
+                                       sharding=sharding), tree)
+
+
+def device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def test_config2_train_step_compiles_and_fits_hbm(one_chip, exp):
+    """Rollout scan (the sim's while_loop event advance inside), GAE and
+    the epoch x minibatch update as the ONE donated program train runs."""
+    with time_limit(150):
+        compiled = jax.jit(exp.train_step_raw, donate_argnums=(0, 1)).lower(
+            shapes(exp.train_state, one_chip), shapes(exp.carry, one_chip),
+            shapes(exp.traces, one_chip),
+            shapes(jax.random.PRNGKey(0), one_chip), None).compile()
+    assert device_bytes(compiled) < HBM_BYTES, compiled.memory_analysis()
+
+
+# the actions output is smaller than the donated request buffers; the
+# engine filters the same compile-time note at its warm-up dispatch
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+def test_config2_serve_policy_step_compiles(one_chip, exp):
+    """The serve engine's donated ``policy_step`` at bucket 16."""
+    from rlgpuschedule_tpu.serve.engine import InferenceEngine
+    engine = InferenceEngine(exp.apply_fn, exp.train_state.params,
+                             exp.env_params, max_bucket=16)
+    bucket = lambda x: jax.ShapeDtypeStruct((16,) + x.shape[1:], x.dtype,
+                                            sharding=one_chip)
+    obs, mask = bucket(exp.carry.obs), bucket(exp.carry.mask)
+    assert obs.shape == (16, 192, 8, 2) and mask.shape == (16, 129)
+    with time_limit(30):
+        compiled = engine._step.lower(
+            shapes(exp.train_state.params, one_chip), obs, mask).compile()
+    assert "fusion" in compiled.as_text()
+    assert device_bytes(compiled) < HBM_BYTES
