@@ -22,6 +22,7 @@ import optax
 from flax.training.train_state import TrainState
 
 from ..env.env import EnvParams
+from ..obs import scopes
 from ..ops.gae import compute_gae
 from . import action_dist
 from . import ppo as ppo_norm  # shared RewardNormState/Welford helpers
@@ -87,25 +88,29 @@ def make_a2c_grad_step(apply_fn: PolicyApply, config: A2CConfig,
 
     def grad_step(state: TrainState, mb_data):
         mb, adv, ret = mb_data
-        if config.bf16_update:
-            c = lambda t: update_engine.cast_floating(t, jnp.bfloat16)
-            (loss, aux), grads = jax.value_and_grad(
-                a2c_loss, argnums=1, has_aux=True)(
-                apply_fn, c(state.params), c(mb), c(adv), c(ret), config)
-            grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
-                                 grads, state.params)
-            loss, aux = jax.tree.map(
-                lambda x: x.astype(jnp.float32), (loss, aux))
-        else:
-            (loss, aux), grads = jax.value_and_grad(
-                a2c_loss, argnums=1, has_aux=True)(
-                apply_fn, state.params, mb, adv, ret, config)
-        state = apply_grads(state, grads)
+        with jax.named_scope(scopes.LOSS_GRAD):
+            if config.bf16_update:
+                c = lambda t: update_engine.cast_floating(t, jnp.bfloat16)
+                (loss, aux), grads = jax.value_and_grad(
+                    a2c_loss, argnums=1, has_aux=True)(
+                    apply_fn, c(state.params), c(mb), c(adv), c(ret),
+                    config)
+                grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
+                                     grads, state.params)
+                loss, aux = jax.tree.map(
+                    lambda x: x.astype(jnp.float32), (loss, aux))
+            else:
+                (loss, aux), grads = jax.value_and_grad(
+                    a2c_loss, argnums=1, has_aux=True)(
+                    apply_fn, state.params, mb, adv, ret, config)
+        with jax.named_scope(scopes.APPLY):
+            state = apply_grads(state, grads)
         return state, (loss, *aux)
 
     return grad_step
 
 
+@scopes.scoped(scopes.UPDATE)
 def run_a2c_update(apply_fn: PolicyApply, config: A2CConfig,
                    state: TrainState, tr: Transition,
                    advantages: jax.Array, returns: jax.Array,
@@ -143,18 +148,19 @@ def make_learn_step(apply_fn: PolicyApply, config: A2CConfig,
 
     def learn_step(train_state: TrainState, tr: Transition,
                    last_value: jax.Array, key: jax.Array):
-        rewards = tr.reward
-        if config.reward_norm:
-            stats = ppo_norm.update_reward_stats(
-                train_state.reward_stats, rewards, axis_name)
-            rewards = rewards * ppo_norm.reward_scale(stats)
-            train_state = train_state.replace(reward_stats=stats)
-        advantages, returns = compute_gae(rewards, tr.value, tr.done,
-                                          last_value, config.gamma,
-                                          config.gae_lambda)
-        if config.bf16_advantages:
-            advantages = advantages.astype(jnp.bfloat16)
-            returns = returns.astype(jnp.bfloat16)
+        with jax.named_scope(scopes.ADVANTAGE):
+            rewards = tr.reward
+            if config.reward_norm:
+                stats = ppo_norm.update_reward_stats(
+                    train_state.reward_stats, rewards, axis_name)
+                rewards = rewards * ppo_norm.reward_scale(stats)
+                train_state = train_state.replace(reward_stats=stats)
+            advantages, returns = compute_gae(rewards, tr.value, tr.done,
+                                              last_value, config.gamma,
+                                              config.gae_lambda)
+            if config.bf16_advantages:
+                advantages = advantages.astype(jnp.bfloat16)
+                returns = returns.astype(jnp.bfloat16)
         return run_a2c_update(apply_fn, config, train_state, tr,
                               advantages, returns, key, apply_grads)
 

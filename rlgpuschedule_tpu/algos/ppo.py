@@ -22,6 +22,7 @@ import optax
 from flax.training.train_state import TrainState
 
 from ..env.env import EnvParams
+from ..obs import scopes
 from ..ops.gae import compute_gae
 from . import action_dist
 from . import update as update_engine
@@ -203,6 +204,7 @@ def normalize_advantages(advantages: jax.Array,
     return (advantages - adv_mean) / jnp.sqrt(adv_var + 1e-8)
 
 
+@scopes.scoped(scopes.ADVANTAGE)
 def compute_advantages(apply_fn: PolicyApply, config: PPOConfig, state,
                        tr: Transition, last_value: jax.Array,
                        axis_name: str | None = None):
@@ -269,27 +271,30 @@ def make_ppo_grad_step(apply_fn: PolicyApply, config: PPOConfig,
     def grad_step(state, mb_data):
         mb, adv, ret = mb_data
         params = _params_of(state)
-        if config.bf16_update:
-            c = lambda t: update_engine.cast_floating(t, jnp.bfloat16)
-            (loss, aux), grads = jax.value_and_grad(
-                ppo_loss, argnums=1, has_aux=True)(
-                apply_fn, c(params), c(mb), c(adv), c(ret),
-                config, clip_eps=clip_eps, ent_coef=ent_coef)
-            grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
-                                 grads, params)
-            loss, aux = jax.tree.map(
-                lambda x: x.astype(jnp.float32), (loss, aux))
-        else:
-            (loss, aux), grads = jax.value_and_grad(
-                ppo_loss, argnums=1, has_aux=True)(
-                apply_fn, params, mb, adv, ret,
-                config, clip_eps=clip_eps, ent_coef=ent_coef)
-        state = apply_grads(state, grads)
+        with jax.named_scope(scopes.LOSS_GRAD):
+            if config.bf16_update:
+                c = lambda t: update_engine.cast_floating(t, jnp.bfloat16)
+                (loss, aux), grads = jax.value_and_grad(
+                    ppo_loss, argnums=1, has_aux=True)(
+                    apply_fn, c(params), c(mb), c(adv), c(ret),
+                    config, clip_eps=clip_eps, ent_coef=ent_coef)
+                grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
+                                     grads, params)
+                loss, aux = jax.tree.map(
+                    lambda x: x.astype(jnp.float32), (loss, aux))
+            else:
+                (loss, aux), grads = jax.value_and_grad(
+                    ppo_loss, argnums=1, has_aux=True)(
+                    apply_fn, params, mb, adv, ret,
+                    config, clip_eps=clip_eps, ent_coef=ent_coef)
+        with jax.named_scope(scopes.APPLY):
+            state = apply_grads(state, grads)
         return state, (loss, *aux)
 
     return grad_step
 
 
+@scopes.scoped(scopes.UPDATE)
 def run_ppo_epochs(apply_fn: PolicyApply, config: PPOConfig, state,
                    tr: Transition, advantages: jax.Array,
                    returns: jax.Array, key: jax.Array, apply_grads,

@@ -16,6 +16,7 @@ import jax
 
 from ..env import env as env_lib
 from ..env.env import EnvParams, EnvState
+from ..obs import scopes
 from . import action_dist
 
 # (net_params, obs, mask) -> (masked_logits, value[E]). obs/mask/logits may
@@ -91,6 +92,7 @@ def make_rollout_step(apply_fn: PolicyApply, env_params: EnvParams,
     return rollout_step
 
 
+@scopes.scoped(scopes.ROLLOUT)
 def rollout(apply_fn: PolicyApply, net_params, env_params: EnvParams,
             traces, carry: RolloutCarry, n_steps: int, faults=None,
             ) -> tuple[RolloutCarry, Transition, jax.Array]:
@@ -106,11 +108,13 @@ def rollout(apply_fn: PolicyApply, net_params, env_params: EnvParams,
     fresh = env_lib.vec_reset(env_params, traces, faults)
 
     def step(c: RolloutCarry, _):
-        logits, value = apply_fn(net_params, c.obs, c.mask)
-        key, sub = jax.random.split(c.key)
-        action, log_prob = action_dist.sample(sub, logits)
-        env_state, ts = env_lib.vec_step(env_params, c.env_state, traces,
-                                         action, fresh, faults)
+        with jax.named_scope(scopes.POLICY_FORWARD):
+            logits, value = apply_fn(net_params, c.obs, c.mask)
+            key, sub = jax.random.split(c.key)
+            action, log_prob = action_dist.sample(sub, logits)
+        with jax.named_scope(scopes.ENV_STEP):
+            env_state, ts = env_lib.vec_step(env_params, c.env_state,
+                                             traces, action, fresh, faults)
         t = Transition(obs=c.obs, action=action, log_prob=log_prob,
                        value=value, reward=ts.reward, done=ts.done,
                        mask=c.mask, env_steps_dt=ts.info.dt)
@@ -124,5 +128,6 @@ def rollout(apply_fn: PolicyApply, net_params, env_params: EnvParams,
     # when no mesh is bound (single-device / legacy dp paths).
     from ..parallel.sharding import DATA_AXIS, constrain_tree
     transitions = constrain_tree(transitions, None, DATA_AXIS)
-    _, last_value = apply_fn(net_params, carry.obs, carry.mask)
+    with jax.named_scope(scopes.POLICY_FORWARD):
+        _, last_value = apply_fn(net_params, carry.obs, carry.mask)
     return carry, transitions, last_value

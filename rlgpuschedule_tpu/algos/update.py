@@ -38,6 +38,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from ..obs import scopes
+
 # grad_step(state, minibatch_data) -> (state, stats): one optimizer
 # update on one minibatch. ``stats`` is any pytree of scalars; the engine
 # stacks it to [n_epochs, n_minibatches, ...].
@@ -147,7 +149,6 @@ def run_minibatch_epochs(grad_step: GradStep, state: Any, data: Any,
         state, key = state_and_key
         key, sub = jax.random.split(key)
         if n_mb > 1:
-            perm = jax.random.permutation(sub, B)
             # ONE whole-batch gather per epoch, then scan over contiguous
             # [n_mb, mb, ...] blocks — identical minibatch contents to
             # gathering x[perm[i]] inside the scan body (same perm, same
@@ -155,8 +156,11 @@ def run_minibatch_epochs(grad_step: GradStep, state: Any, data: Any,
             # contiguous dynamic-slice instead of issuing a fresh
             # row-gather per minibatch (the update scan is the measured
             # hot stage — BASELINE.md "where the time goes").
-            blocks = jax.tree.map(
-                lambda x: x[perm].reshape(n_mb, _mb, *x.shape[1:]), data)
+            with jax.named_scope(scopes.SHUFFLE):
+                perm = jax.random.permutation(sub, B)
+                blocks = jax.tree.map(
+                    lambda x: x[perm].reshape(n_mb, _mb, *x.shape[1:]),
+                    data)
         else:
             # full-batch epochs: a permutation would only reorder a mean —
             # skip the gather (the swept fewer-larger-minibatch fast path)

@@ -102,6 +102,7 @@ from .algos.ppo import make_learn_step as make_ppo_learn_step
 from .algos.rollout import make_rollout_step
 from .analysis.sentinels import no_implicit_transfers
 from .obs.telemetry import AsyncGauges, OverlapMeter
+from .obs.scopes import TRAIN_ITERATION
 from .obs.trace import tracer_of
 from .parallel.dp import put_carry
 from .parallel.groups import DeviceGroups
@@ -447,8 +448,8 @@ class AsyncRunner:
                 self._actor_idle_s += gated
                 # barrier-park may have replaced the carry (resample)
                 carry = exp.carry
-                with tracer.span("actor", iteration=i), \
-                        self.overlap.span("actor"), sections("actor"), \
+                with tracer.phase(sections, "actor", meter=self.overlap,
+                                  iteration=i), \
                         no_implicit_transfers(), self._dispatch_lock:
                     carry, tr, last_value = self._rollout(
                         params, carry, exp.traces, self._faults)
@@ -531,92 +532,94 @@ class AsyncRunner:
             for k in range(iterations):
                 b = k  # hook-facing iteration index, as in Experiment.run
                 i = base + k
-                if telemetry is not None:
-                    telemetry.begin_iteration(b)
-                with sections("queue_wait"), \
-                        tracer.span("queue_pop_wait"):
-                    item, waited = self.queue.get()  # jsan: disable=hung-future -- TrajectoryQueue.get is bounded by construction (stall timeout + abort wakes every waiter)
-                self._learner_idle_s += waited
-                if item.index != i:
-                    raise RuntimeError(
-                        f"queue order violation: expected batch {i}, "
-                        f"got {item.index}")
-                staleness = item.index - item.version
-                if staleness > self.staleness_bound:
-                    raise StalenessError(
-                        f"batch {item.index} was collected at policy "
-                        f"version {item.version} — {staleness} versions "
-                        f"behind, bound is {self.staleness_bound}")
-                self._staleness_last = staleness
-                self._staleness_max = max(self._staleness_max, staleness)
-                self._staleness_sum += staleness
-                self._consumed += 1
-                guard = (telemetry.dispatch(b) if telemetry is not None
-                         else contextlib.nullcontext())
-                tr, last_value = item.batch
-                with tracer.span("learner", iteration=b), \
-                        self.overlap.span("learner"), \
-                        sections("learner"), guard, self._dispatch_lock:
-                    # the sync loop's per-iteration split, in the same order
-                    exp.key, sub = jax.random.split(exp.key)
-                    state, metrics = self._learn(exp.train_state, tr,
-                                                 last_value, sub)
-                    params_a = jax.device_put(state.params, self._arep)
-                    jax.block_until_ready(params_a)
-                exp.train_state = state
-                self._slot.publish(params_a, i + 1)
+                with jax.profiler.StepTraceAnnotation(
+                        TRAIN_ITERATION, step_num=b):
+                    if telemetry is not None:
+                        telemetry.begin_iteration(b)
+                    with tracer.phase(sections, "queue_wait",
+                                      span="queue_pop_wait"):
+                        item, waited = self.queue.get()  # jsan: disable=hung-future -- TrajectoryQueue.get is bounded by construction (stall timeout + abort wakes every waiter)
+                    self._learner_idle_s += waited
+                    if item.index != i:
+                        raise RuntimeError(
+                            f"queue order violation: expected batch {i}, "
+                            f"got {item.index}")
+                    staleness = item.index - item.version
+                    if staleness > self.staleness_bound:
+                        raise StalenessError(
+                            f"batch {item.index} was collected at policy "
+                            f"version {item.version} — {staleness} versions "
+                            f"behind, bound is {self.staleness_bound}")
+                    self._staleness_last = staleness
+                    self._staleness_max = max(self._staleness_max, staleness)
+                    self._staleness_sum += staleness
+                    self._consumed += 1
+                    guard = (telemetry.dispatch(b) if telemetry is not None
+                             else contextlib.nullcontext())
+                    tr, last_value = item.batch
+                    with tracer.phase(sections, "learner",
+                                      meter=self.overlap, iteration=b), \
+                            guard, self._dispatch_lock:
+                        # the sync loop's per-iteration split, same order
+                        exp.key, sub = jax.random.split(exp.key)
+                        state, metrics = self._learn(exp.train_state, tr,
+                                                     last_value, sub)
+                        params_a = jax.device_put(state.params, self._arep)
+                        jax.block_until_ready(params_a)
+                    exp.train_state = state
+                    self._slot.publish(params_a, i + 1)
 
-                want_log = bool(log_every) and (b % log_every == 0
-                                                or b == iterations - 1)
-                m = None
-                if want_log:
-                    with sections("sync"), tracer.span("sync"), \
-                            self._dispatch_lock:
-                        m = {k2: float(v) for k2, v in
-                             jax.device_get(metrics)._asdict().items()}
-                    if "rho_mean" in m:
-                        self._rho_last = m["rho_mean"]
-                        self._rho_max_seen = max(self._rho_max_seen,
-                                                 m["rho_max"])
-                    history.append({"iteration": b, **m})
-                    if logger is not None:
-                        logger(b, m)
-                    if gauges is not None:
-                        gauges.publish(
-                            queue_depth=len(self.queue),
-                            staleness=self._staleness_last,
-                            actor_idle_s=self._actor_idle_s,
-                            learner_idle_s=self._learner_idle_s,
-                            overlap_s=self.overlap.overlap_s,
-                            importance_ratio_mean=self._rho_last,
-                            importance_ratio_max=self._rho_max_seen)
-                if eval_fn is not None and eval_every and \
-                        ((b + 1) % eval_every == 0 or b == iterations - 1):
-                    with sections("eval"), tracer.span("eval"), \
-                            self._dispatch_lock:
-                        em = dict(eval_fn(b))
-                    eval_history.append({"iteration": b, **em})
-                    if eval_logger is not None:
-                        eval_logger(b, em)
-                # drained-queue barrier work (actor is parked past i)
-                if is_ckpt(b):
-                    with sections("ckpt"), tracer.span("ckpt"):
-                        exp.save_checkpoint(
-                            ckpt, meta={"iteration": b,
-                                        "async_iteration": i,
-                                        "staleness_bound":
-                                            self.staleness_bound})
-                if is_resample(b):
-                    with sections("resample"), tracer.span("resample"):
-                        self._resample()
-                if is_ckpt(b) or is_resample(b):
-                    self._complete_barrier()
-                if telemetry is not None:
-                    telemetry.end_iteration(
-                        b, m if want_log else None,
-                        exp.steps_per_iteration)
-                if self._failure is not None:
-                    raise self._failure
+                    want_log = bool(log_every) and (b % log_every == 0
+                                                    or b == iterations - 1)
+                    m = None
+                    if want_log:
+                        with tracer.phase(sections, "sync"), \
+                                self._dispatch_lock:
+                            m = {k2: float(v) for k2, v in
+                                 jax.device_get(metrics)._asdict().items()}
+                        if "rho_mean" in m:
+                            self._rho_last = m["rho_mean"]
+                            self._rho_max_seen = max(self._rho_max_seen,
+                                                     m["rho_max"])
+                        history.append({"iteration": b, **m})
+                        if logger is not None:
+                            logger(b, m)
+                        if gauges is not None:
+                            gauges.publish(
+                                queue_depth=len(self.queue),
+                                staleness=self._staleness_last,
+                                actor_idle_s=self._actor_idle_s,
+                                learner_idle_s=self._learner_idle_s,
+                                overlap_s=self.overlap.overlap_s,
+                                importance_ratio_mean=self._rho_last,
+                                importance_ratio_max=self._rho_max_seen)
+                    if eval_fn is not None and eval_every and \
+                            ((b + 1) % eval_every == 0 or b == iterations - 1):
+                        with tracer.phase(sections, "eval"), \
+                                self._dispatch_lock:
+                            em = dict(eval_fn(b))
+                        eval_history.append({"iteration": b, **em})
+                        if eval_logger is not None:
+                            eval_logger(b, em)
+                    # drained-queue barrier work (actor is parked past i)
+                    if is_ckpt(b):
+                        with tracer.phase(sections, "ckpt"):
+                            exp.save_checkpoint(
+                                ckpt, meta={"iteration": b,
+                                            "async_iteration": i,
+                                            "staleness_bound":
+                                                self.staleness_bound})
+                    if is_resample(b):
+                        with tracer.phase(sections, "resample"):
+                            self._resample()
+                    if is_ckpt(b) or is_resample(b):
+                        self._complete_barrier()
+                    if telemetry is not None:
+                        telemetry.end_iteration(
+                            b, m if want_log else None,
+                            exp.steps_per_iteration)
+                    if self._failure is not None:
+                        raise self._failure
         except BaseException as e:
             self._abort(e)
             actor.join(timeout=30)
@@ -922,8 +925,8 @@ class AsyncPopulationRunner:
                 roll_args = (params, carries, pexp.traces)
                 if pexp.faults is not None:
                     roll_args = roll_args + (pexp.faults,)
-                with tracer.span("actor", iteration=i), \
-                        self.overlap.span("actor"), sections("actor"), \
+                with tracer.phase(sections, "actor", meter=self.overlap,
+                                  iteration=i), \
                         no_implicit_transfers(), self._dispatch_lock:
                     carries, tr, last_value = self._rollout(*roll_args)
                     batch = (jax.device_put(tr, self._lrep),
@@ -1010,143 +1013,146 @@ class AsyncPopulationRunner:
             for k in range(iterations):
                 b = k
                 i = base + k
-                if telemetry is not None:
-                    telemetry.begin_iteration(b)
-                with sections("queue_wait"), \
-                        tracer.span("queue_pop_wait"):
-                    item, waited = self.queue.get()  # jsan: disable=hung-future -- TrajectoryQueue.get is bounded by construction (stall timeout + abort wakes every waiter)
-                self._learner_idle_s += waited
-                if item.index != i:
-                    raise RuntimeError(
-                        f"queue order violation: expected batch {i}, "
-                        f"got {item.index}")
-                staleness = item.index - item.version
-                if staleness > self.staleness_bound:
-                    raise StalenessError(
-                        f"batch {item.index} was collected at policy "
-                        f"version {item.version} — {staleness} versions "
-                        f"behind, bound is {self.staleness_bound}")
-                self._staleness_last = staleness
-                self._staleness_max = max(self._staleness_max, staleness)
-                self._staleness_sum += staleness
-                self._consumed += 1
-                for p in range(pexp.n_pop):
-                    self._stale_last_pm[p] = staleness
-                    self._stale_max_pm[p] = max(self._stale_max_pm[p],
-                                                staleness)
-                guard = (telemetry.dispatch(b) if telemetry is not None
-                         else contextlib.nullcontext())
-                tr, last_value = item.batch
-                with tracer.span("learner", iteration=b), \
-                        self.overlap.span("learner"), \
-                        sections("learner"), guard, self._dispatch_lock:
-                    # the sync population loop's per-iteration split,
-                    # same program and order
-                    both = self._split_all(pexp.keys)
-                    keys2, subs = both[:, 0], both[:, 1]
-                    states, metrics = self._learn(
-                        pexp.states, tr, last_value, subs, pexp.hparams)
-                    params_a = jax.device_put(states.params, self._arep)
-                    jax.block_until_ready(params_a)
-                pexp.keys = keys2
-                pexp.states = states
-                self._slot.publish(params_a, i + 1)
-
-                # PBT bookkeeping every iteration, as in the sync loop:
-                # record is a device-array append (no sync), maybe_update
-                # fires only at the barrier-predicted iterations — if it
-                # ever fires off-schedule the actor is NOT parked, so
-                # fail loudly rather than race the weight copy
-                ctrl.record(metrics.mean_reward)
-                out = ctrl.maybe_update(i, pexp.states, pexp.hparams)
-                if (out is not None) != is_exploit(b):
-                    raise RuntimeError(
-                        f"PBT exploit fired off the predicted barrier "
-                        f"schedule at iteration {b} (window={window}, "
-                        f"ready_iters={ready}) — controller state was "
-                        f"mutated outside the runner")
-                if out is not None:
-                    states2, hparams2, decision = out
-                    with sections("pbt"), tracer.span("pbt_exploit"), \
-                            self._dispatch_lock:
-                        # the exploit gather pins its outputs to the
-                        # input (learner) shardings; the host-side
-                        # explore hands back fresh uncommitted arrays
-                        pexp.states = states2
-                        pexp.hparams = put_global(hparams2, self._lrep)
-                        params_a = jax.device_put(pexp.states.params,
-                                                  self._arep)
-                        jax.block_until_ready(params_a)
-                    # re-publish the exploited weights under the SAME
-                    # version: the parked actor then collects batch i+1
-                    # with post-exploit params, exactly like the sync loop
-                    self._slot.publish(params_a, i + 1)
-                    exploited = [bool(x) for x in decision.exploited]
-                    for p, ex in enumerate(exploited):
-                        if ex:
-                            self._stale_last_pm[p] = 0
+                with jax.profiler.StepTraceAnnotation(
+                        TRAIN_ITERATION, step_num=b):
                     if telemetry is not None:
-                        telemetry.emit(
-                            "pbt_exploit", iteration=b,
-                            exploited=int(sum(exploited)),
-                            src=[int(s) for s in decision.src])
+                        telemetry.begin_iteration(b)
+                    with tracer.phase(sections, "queue_wait",
+                                      span="queue_pop_wait"):
+                        item, waited = self.queue.get()  # jsan: disable=hung-future -- TrajectoryQueue.get is bounded by construction (stall timeout + abort wakes every waiter)
+                    self._learner_idle_s += waited
+                    if item.index != i:
+                        raise RuntimeError(
+                            f"queue order violation: expected batch {i}, "
+                            f"got {item.index}")
+                    staleness = item.index - item.version
+                    if staleness > self.staleness_bound:
+                        raise StalenessError(
+                            f"batch {item.index} was collected at policy "
+                            f"version {item.version} — {staleness} versions "
+                            f"behind, bound is {self.staleness_bound}")
+                    self._staleness_last = staleness
+                    self._staleness_max = max(self._staleness_max, staleness)
+                    self._staleness_sum += staleness
+                    self._consumed += 1
+                    for p in range(pexp.n_pop):
+                        self._stale_last_pm[p] = staleness
+                        self._stale_max_pm[p] = max(self._stale_max_pm[p],
+                                                    staleness)
+                    guard = (telemetry.dispatch(b) if telemetry is not None
+                             else contextlib.nullcontext())
+                    tr, last_value = item.batch
+                    with tracer.phase(sections, "learner",
+                                      meter=self.overlap, iteration=b), \
+                            guard, self._dispatch_lock:
+                        # the sync population loop's per-iteration split,
+                        # same program and order
+                        both = self._split_all(pexp.keys)
+                        keys2, subs = both[:, 0], both[:, 1]
+                        states, metrics = self._learn(
+                            pexp.states, tr, last_value, subs, pexp.hparams)
+                        params_a = jax.device_put(states.params, self._arep)
+                        jax.block_until_ready(params_a)
+                    pexp.keys = keys2
+                    pexp.states = states
+                    self._slot.publish(params_a, i + 1)
 
-                want_log = bool(log_every) and (b % log_every == 0
-                                                or b == iterations - 1)
-                m = None
-                if want_log:
-                    # ONE batched device_get for the whole [P]-metrics
-                    # tuple, flattened to suffixed scalar columns + _mean
-                    # (same CSV schema as the sync population loop)
-                    m = {}
-                    with sections("sync"), tracer.span("sync"), \
-                            self._dispatch_lock:
-                        got = jax.device_get(metrics)._asdict()
-                    for k2, v in got.items():
-                        vals = [float(x) for x in v]
-                        m.update({f"{k2}_{p}": x
-                                  for p, x in enumerate(vals)})
-                        m[f"{k2}_mean"] = sum(vals) / len(vals)
-                    if "rho_mean_mean" in m:
-                        self._rho_last = m["rho_mean_mean"]
-                        self._rho_max_seen = max(
-                            self._rho_max_seen,
-                            max(float(x) for x in got["rho_max"]))
-                    history.append({"iteration": b, **m})
-                    if logger is not None:
-                        logger(b, m)
-                    if gauges is not None:
-                        gauges.publish(
-                            queue_depth=len(self.queue),
-                            staleness=self._staleness_last,
-                            actor_idle_s=self._actor_idle_s,
-                            learner_idle_s=self._learner_idle_s,
-                            overlap_s=self.overlap.overlap_s,
-                            importance_ratio_mean=self._rho_last,
-                            importance_ratio_max=self._rho_max_seen)
-                if eval_fn is not None and eval_every and \
-                        ((b + 1) % eval_every == 0 or b == iterations - 1):
-                    with sections("eval"), tracer.span("eval"), \
-                            self._dispatch_lock:
-                        em = dict(eval_fn(b))
-                    eval_history.append({"iteration": b, **em})
-                    if eval_logger is not None:
-                        eval_logger(b, em)
-                if is_ckpt(b):
-                    with sections("ckpt"), tracer.span("ckpt"):
-                        pexp.save_checkpoint(
-                            ckpt, meta={"iteration": b,
-                                        "async_iteration": i,
-                                        "staleness_bound":
-                                            self.staleness_bound})
-                if is_ckpt(b) or is_exploit(b):
-                    self._complete_barrier()
-                if telemetry is not None:
-                    telemetry.end_iteration(
-                        b, m if want_log else None,
-                        pexp.steps_per_iteration)
-                if self._failure is not None:
-                    raise self._failure
+                    # PBT bookkeeping every iteration, as in the sync loop:
+                    # record is a device-array append (no sync), maybe_update
+                    # fires only at the barrier-predicted iterations — if it
+                    # ever fires off-schedule the actor is NOT parked, so
+                    # fail loudly rather than race the weight copy
+                    ctrl.record(metrics.mean_reward)
+                    out = ctrl.maybe_update(i, pexp.states, pexp.hparams)
+                    if (out is not None) != is_exploit(b):
+                        raise RuntimeError(
+                            f"PBT exploit fired off the predicted barrier "
+                            f"schedule at iteration {b} (window={window}, "
+                            f"ready_iters={ready}) — controller state was "
+                            f"mutated outside the runner")
+                    if out is not None:
+                        states2, hparams2, decision = out
+                        with tracer.phase(sections, "pbt",
+                                          span="pbt_exploit"), \
+                                self._dispatch_lock:
+                            # the exploit gather pins its outputs to the
+                            # input (learner) shardings; the host-side
+                            # explore hands back fresh uncommitted arrays
+                            pexp.states = states2
+                            pexp.hparams = put_global(hparams2, self._lrep)
+                            params_a = jax.device_put(pexp.states.params,
+                                                      self._arep)
+                            jax.block_until_ready(params_a)
+                        # re-publish the exploited weights under the SAME
+                        # version: the parked actor then collects batch i+1
+                        # with post-exploit params, exactly like the sync loop
+                        self._slot.publish(params_a, i + 1)
+                        exploited = [bool(x) for x in decision.exploited]
+                        for p, ex in enumerate(exploited):
+                            if ex:
+                                self._stale_last_pm[p] = 0
+                        if telemetry is not None:
+                            telemetry.emit(
+                                "pbt_exploit", iteration=b,
+                                exploited=int(sum(exploited)),
+                                src=[int(s) for s in decision.src])
+
+                    want_log = bool(log_every) and (b % log_every == 0
+                                                    or b == iterations - 1)
+                    m = None
+                    if want_log:
+                        # ONE batched device_get for the whole [P]-metrics
+                        # tuple, flattened to suffixed scalar columns + _mean
+                        # (same CSV schema as the sync population loop)
+                        m = {}
+                        with tracer.phase(sections, "sync"), \
+                                self._dispatch_lock:
+                            got = jax.device_get(metrics)._asdict()
+                        for k2, v in got.items():
+                            vals = [float(x) for x in v]
+                            m.update({f"{k2}_{p}": x
+                                      for p, x in enumerate(vals)})
+                            m[f"{k2}_mean"] = sum(vals) / len(vals)
+                        if "rho_mean_mean" in m:
+                            self._rho_last = m["rho_mean_mean"]
+                            self._rho_max_seen = max(
+                                self._rho_max_seen,
+                                max(float(x) for x in got["rho_max"]))
+                        history.append({"iteration": b, **m})
+                        if logger is not None:
+                            logger(b, m)
+                        if gauges is not None:
+                            gauges.publish(
+                                queue_depth=len(self.queue),
+                                staleness=self._staleness_last,
+                                actor_idle_s=self._actor_idle_s,
+                                learner_idle_s=self._learner_idle_s,
+                                overlap_s=self.overlap.overlap_s,
+                                importance_ratio_mean=self._rho_last,
+                                importance_ratio_max=self._rho_max_seen)
+                    if eval_fn is not None and eval_every and \
+                            ((b + 1) % eval_every == 0 or b == iterations - 1):
+                        with tracer.phase(sections, "eval"), \
+                                self._dispatch_lock:
+                            em = dict(eval_fn(b))
+                        eval_history.append({"iteration": b, **em})
+                        if eval_logger is not None:
+                            eval_logger(b, em)
+                    if is_ckpt(b):
+                        with tracer.phase(sections, "ckpt"):
+                            pexp.save_checkpoint(
+                                ckpt, meta={"iteration": b,
+                                            "async_iteration": i,
+                                            "staleness_bound":
+                                                self.staleness_bound})
+                    if is_ckpt(b) or is_exploit(b):
+                        self._complete_barrier()
+                    if telemetry is not None:
+                        telemetry.end_iteration(
+                            b, m if want_log else None,
+                            pexp.steps_per_iteration)
+                    if self._failure is not None:
+                        raise self._failure
         except BaseException as e:
             self._abort(e)
             actor.join(timeout=30)

@@ -19,6 +19,7 @@ from typing import Any, Literal, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import scopes
 from ..sim import core
 from ..sim.core import SimParams, SimState, Trace, StepInfo
 from ..sim.faults import FaultRegime, FaultSchedule
@@ -122,6 +123,7 @@ def build_obs(params: EnvParams, sim: SimState, trace: Trace,
     return obs
 
 
+@scopes.scoped(scopes.OBSERVE)
 def _observe(params: EnvParams, sim: SimState, trace: Trace,
              faults: FaultSchedule | None = None,
              ) -> tuple[jax.Array, jax.Array]:
@@ -162,19 +164,22 @@ def step(params: EnvParams, state: EnvState, trace: Trace,
          faults: FaultSchedule | None = None) -> tuple[EnvState, TimeStep]:
     sim_before = state.sim
     sim, info = core.rl_step(params.sim, sim_before, trace, action, faults)
-    if params.reward_kind == "fair":
-        reward = reward_lib.reward_fair(sim_before, trace, info,
-                                        params.n_tenants, params.reward_scale)
-    else:
-        reward = reward_lib.reward_jct(info, params.reward_scale,
-                                       params.place_bonus)
-    # the anti-stall preemption charge is a property of the ACTION SPACE
-    # (any preemptive config can generate zero-dt actions forever — the
-    # pause-the-game exploit, rewards.preempt_charge), not of one reward
-    # function, so it applies after whichever reward branch ran
-    if params.preempt_cost:
-        reward = reward + reward_lib.preempt_charge(info,
-                                                    params.preempt_cost)
+    with jax.named_scope(scopes.REWARD):
+        if params.reward_kind == "fair":
+            reward = reward_lib.reward_fair(sim_before, trace, info,
+                                            params.n_tenants,
+                                            params.reward_scale)
+        else:
+            reward = reward_lib.reward_jct(info, params.reward_scale,
+                                           params.place_bonus)
+        # the anti-stall preemption charge is a property of the ACTION
+        # SPACE (any preemptive config can generate zero-dt actions
+        # forever — the pause-the-game exploit, rewards.preempt_charge),
+        # not of one reward function, so it applies after whichever
+        # reward branch ran
+        if params.preempt_cost:
+            reward = reward + reward_lib.preempt_charge(info,
+                                                        params.preempt_cost)
     t = state.t + 1
     done = info.done | (t >= params.horizon)
     new_state = EnvState(sim=sim, t=t)
@@ -184,6 +189,7 @@ def step(params: EnvParams, state: EnvState, trace: Trace,
     return new_state, ts
 
 
+@scopes.scoped(scopes.AUTO_RESET)
 def auto_reset(stepped_state, ts: TimeStep, fresh_state, fresh_ts: TimeStep,
                ) -> tuple[Any, TimeStep]:
     """Blend a stepped (state, timestep) with a fresh reset on episode end

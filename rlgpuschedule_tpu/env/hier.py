@@ -36,6 +36,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import scopes
 from ..sim import core
 from ..sim.core import (DONE, INF, PACK, PENDING, RUNNING, SimParams,
                         SimState, Trace)
@@ -291,6 +292,7 @@ def action_mask(params: HierParams, state: HierState, trace: Trace,
     return {"top": top, "pods": pod_masks}
 
 
+@scopes.scoped(scopes.OBSERVE)
 def _observe(params: HierParams, state: HierState, trace: Trace,
              ) -> tuple[dict, dict]:
     """(obs, mask), computing each pod's pending queue once and sharing it
@@ -364,8 +366,9 @@ def step(params: HierParams, state: HierState, trace: Trace,
                     preempted=jnp.bool_(False), first_placed=acted_ok)
     # same JCT integrand + placement shaping as the flat env (ADVICE r1:
     # place_bonus was silently dropped for hierarchical configs)
-    reward = reward_lib.reward_jct(info, params.reward_scale,
-                                   params.place_bonus)
+    with jax.named_scope(scopes.REWARD):
+        reward = reward_lib.reward_jct(info, params.reward_scale,
+                                       params.place_bonus)
     done = info.done | (new_state.t >= params.horizon)
     obs, mask = _observe(params, new_state, trace)
     ts = TimeStep(obs=obs, reward=reward, done=done, action_mask=mask,

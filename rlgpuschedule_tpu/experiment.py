@@ -631,6 +631,7 @@ class Experiment:
         # telemetry spans: with no telemetry attached, a throwaway timer
         # keeps the section sites branch-free (its cost is two
         # perf_counter reads per section — noise next to a dispatch)
+        from .obs.scopes import TRAIN_ITERATION
         from .obs.trace import tracer_of
         from .utils.profiling import SectionTimer
         sections = (telemetry.sections if telemetry is not None
@@ -663,76 +664,79 @@ class Experiment:
             # cadence form (b % L == 0) would never fire there; the (b+1)
             # form is the same cadence shifted to boundary-aligned phase
             b = i + stride - 1
-            if telemetry is not None:
-                telemetry.begin_iteration(b)
-            guard = (telemetry.dispatch(b) if telemetry is not None
-                     else contextlib.nullcontext())
-            # "step" is the async dispatch only — the device work it
-            # enqueues materializes in the "sync" span's device_get
-            if fused_chunk > 1:
-                with sections("step"), tracer.span("step"), guard:
-                    metrics = self.run_fused(fused_chunk)
-            else:
-                self.key, sub = jax.random.split(self.key)
-                if key_rep is not None:
-                    sub = jax.device_put(sub, key_rep)
-                with sections("step"), tracer.span("step"), guard:
-                    self.train_state, self.carry, metrics = self.train_step(
-                        self.train_state, self.carry, self.traces, sub,
-                        self.faults)
-            if injector is not None:
-                metrics = injector.poison_nan(self, b, metrics)
-            log_hit = log_every and (
-                (b + 1) % log_every == 0 if fused_chunk > 1
-                else b % log_every == 0)
-            want_log = bool(log_every) and (log_hit or b == iterations - 1)
-            # host consumers (watchdog + logger + telemetry) share ONE
-            # batched device_get: per-field float() is a separate
-            # blocking transfer each, and the watchdog path pays it every
-            # iteration (jsan host-sync review, PR 3)
-            m = None
-            if watchdog is not None or want_log:
-                with sections("sync"), tracer.span("sync"):
-                    m = {k: float(v) for k, v in
-                         jax.device_get(metrics)._asdict().items()}
-            if watchdog is not None:
-                reason = watchdog.check(m)
-                if reason is not None:
-                    event = watchdog.rollback(self, ckpt, b, reason)
-                    if telemetry is not None:
-                        # the retry's LR rescale rebinds tx and re-traces
-                        # the step — a legitimate compile, not an alarm
-                        telemetry.iteration_aborted(
-                            b, f"rollback: {reason}")
-                    i = event.resume_iteration
-                    continue
-            if want_log:
-                history.append({"iteration": b, **m})
-                if logger is not None:
-                    logger(b, m)
-            if eval_fn is not None and eval_every and \
-                    ((b + 1) % eval_every == 0 or b == iterations - 1):
-                with sections("eval"), tracer.span("eval"):
-                    em = dict(eval_fn(b))
-                eval_history.append({"iteration": b, **em})
-                if eval_logger is not None:
-                    eval_logger(b, em)
-            if ckpt is not None and ckpt_every and \
-                    ((b + 1) % ckpt_every == 0 or b == iterations - 1):
-                with sections("ckpt"), tracer.span("ckpt"):
-                    self.save_checkpoint(ckpt, meta={"iteration": b})
+            with jax.profiler.StepTraceAnnotation(TRAIN_ITERATION,
+                                                  step_num=b):
+                if telemetry is not None:
+                    telemetry.begin_iteration(b)
+                guard = (telemetry.dispatch(b) if telemetry is not None
+                         else contextlib.nullcontext())
+                # "step" is the async dispatch only — the device work it
+                # enqueues materializes in the "sync" span's device_get
+                if fused_chunk > 1:
+                    with tracer.phase(sections, "step"), guard:
+                        metrics = self.run_fused(fused_chunk)
+                else:
+                    self.key, sub = jax.random.split(self.key)
+                    if key_rep is not None:
+                        sub = jax.device_put(sub, key_rep)
+                    with tracer.phase(sections, "step"), guard:
+                        self.train_state, self.carry, metrics = \
+                            self.train_step(
+                                self.train_state, self.carry, self.traces,
+                                sub, self.faults)
                 if injector is not None:
-                    injector.corrupt_after_save(ckpt, b)
-            if self.cfg.resample_every and \
-                    (b + 1) % self.cfg.resample_every == 0 and \
-                    b != iterations - 1:
-                with sections("resample"), tracer.span("resample"):
-                    self.advance_windows()
-            if telemetry is not None:
-                telemetry.end_iteration(
-                    b, m if want_log else None,
-                    stride * self.steps_per_iteration)
-            i += stride
+                    metrics = injector.poison_nan(self, b, metrics)
+                log_hit = log_every and (
+                    (b + 1) % log_every == 0 if fused_chunk > 1
+                    else b % log_every == 0)
+                want_log = bool(log_every) and (log_hit or b == iterations - 1)
+                # host consumers (watchdog + logger + telemetry) share ONE
+                # batched device_get: per-field float() is a separate
+                # blocking transfer each, and the watchdog path pays it every
+                # iteration (jsan host-sync review, PR 3)
+                m = None
+                if watchdog is not None or want_log:
+                    with tracer.phase(sections, "sync"):
+                        m = {k: float(v) for k, v in
+                             jax.device_get(metrics)._asdict().items()}
+                if watchdog is not None:
+                    reason = watchdog.check(m)
+                    if reason is not None:
+                        event = watchdog.rollback(self, ckpt, b, reason)
+                        if telemetry is not None:
+                            # the retry's LR rescale rebinds tx and re-traces
+                            # the step — a legitimate compile, not an alarm
+                            telemetry.iteration_aborted(
+                                b, f"rollback: {reason}")
+                        i = event.resume_iteration
+                        continue
+                if want_log:
+                    history.append({"iteration": b, **m})
+                    if logger is not None:
+                        logger(b, m)
+                if eval_fn is not None and eval_every and \
+                        ((b + 1) % eval_every == 0 or b == iterations - 1):
+                    with tracer.phase(sections, "eval"):
+                        em = dict(eval_fn(b))
+                    eval_history.append({"iteration": b, **em})
+                    if eval_logger is not None:
+                        eval_logger(b, em)
+                if ckpt is not None and ckpt_every and \
+                        ((b + 1) % ckpt_every == 0 or b == iterations - 1):
+                    with tracer.phase(sections, "ckpt"):
+                        self.save_checkpoint(ckpt, meta={"iteration": b})
+                    if injector is not None:
+                        injector.corrupt_after_save(ckpt, b)
+                if self.cfg.resample_every and \
+                        (b + 1) % self.cfg.resample_every == 0 and \
+                        b != iterations - 1:
+                    with tracer.phase(sections, "resample"):
+                        self.advance_windows()
+                if telemetry is not None:
+                    telemetry.end_iteration(
+                        b, m if want_log else None,
+                        stride * self.steps_per_iteration)
+                i += stride
         jax.block_until_ready(self.train_state.params)
         wall = time.monotonic() - t0
         total_env_steps = iterations * self.steps_per_iteration
@@ -1065,6 +1069,7 @@ class PopulationExperiment:
         history = []
         eval_history = []
         t0 = time.monotonic()
+        from .obs.scopes import TRAIN_ITERATION
         from .obs.trace import tracer_of
         from .utils.profiling import SectionTimer
         sections = (telemetry.sections if telemetry is not None
@@ -1080,83 +1085,86 @@ class PopulationExperiment:
             self.save_checkpoint(ckpt, meta={"iteration": -1})
         i = 0
         while i < iterations:
-            if telemetry is not None:
-                telemetry.begin_iteration(i)
-            guard = (telemetry.dispatch(i) if telemetry is not None
-                     else contextlib.nullcontext())
-            both = split_all(self.keys)
-            self.keys, subs = both[:, 0], both[:, 1]
-            step_args = (self.states, self.carries, self.traces, subs,
-                         self.hparams)
-            if self.faults is not None:
-                step_args = step_args + (self.faults,)
-            with sections("step"), tracer.span("step"), guard:
-                self.states, self.carries, metrics = self.pop_step(
-                    *step_args)
-            if injector is not None:
-                metrics = injector.poison_nan_member(self, i, metrics)
-            fitness = metrics.mean_reward
-            if watchdog is not None:
-                reason = watchdog.check_population(fitness)
-                if reason is not None:
-                    event = watchdog.rollback(self, ckpt, i, reason)
-                    if telemetry is not None:
-                        telemetry.iteration_aborted(
-                            i, f"rollback: {reason}")
-                    i = event.resume_iteration
-                    continue
-            self.controller.record(fitness)
-            out = self.controller.maybe_update(i, self.states, self.hparams)
-            if out is not None:
-                self.states, self.hparams, decision = out
-                if self.mesh is not None:
-                    # the exploit gather + host-side explore hand back
-                    # arrays without the pop-axis commitment; re-pin them
-                    # HERE — outside the next dispatch's transfer guard —
-                    # or the jit replicates them with an implicit
-                    # device-to-device copy (transfer alarm)
-                    self.states = jax.device_put(self.states,
-                                                 self.state_sharding)
-                    self.hparams = jax.device_put(self.hparams,
-                                                  self.hparam_sharding)
+            with jax.profiler.StepTraceAnnotation(TRAIN_ITERATION,
+                                                  step_num=i):
                 if telemetry is not None:
-                    telemetry.emit(
-                        "pbt_exploit", iteration=i,
-                        exploited=int(decision.exploited.sum()),
-                        src=[int(s) for s in decision.src])
-            m = None
-            if log_every and (i % log_every == 0 or i == iterations - 1):
-                # flatten per-member values to suffixed scalar columns so
-                # the CSV stays pandas/TensorBoard-ingestible (ADVICE r1).
-                # ONE batched device_get for the whole [P]-metrics tuple:
-                # per-element float() was n_fields x P separate blocking
-                # transfers per logged iteration (jsan host-sync review)
-                m = {}
-                with sections("sync"), tracer.span("sync"):
-                    got = jax.device_get(metrics)._asdict()
-                for k, v in got.items():
-                    vals = [float(x) for x in v]
-                    m.update({f"{k}_{p}": x for p, x in enumerate(vals)})
-                    m[f"{k}_mean"] = sum(vals) / len(vals)
-                history.append({"iteration": i, **m})
-                if logger is not None:
-                    logger(i, m)
-            if eval_fn is not None and eval_every and \
-                    ((i + 1) % eval_every == 0 or i == iterations - 1):
-                with sections("eval"), tracer.span("eval"):
-                    em = dict(eval_fn(i))
-                eval_history.append({"iteration": i, **em})
-                if eval_logger is not None:
-                    eval_logger(i, em)
-            if ckpt is not None and ckpt_every and \
-                    ((i + 1) % ckpt_every == 0 or i == iterations - 1):
-                with sections("ckpt"), tracer.span("ckpt"):
-                    self.save_checkpoint(ckpt, meta={"iteration": i})
+                    telemetry.begin_iteration(i)
+                guard = (telemetry.dispatch(i) if telemetry is not None
+                         else contextlib.nullcontext())
+                both = split_all(self.keys)
+                self.keys, subs = both[:, 0], both[:, 1]
+                step_args = (self.states, self.carries, self.traces, subs,
+                             self.hparams)
+                if self.faults is not None:
+                    step_args = step_args + (self.faults,)
+                with tracer.phase(sections, "step"), guard:
+                    self.states, self.carries, metrics = self.pop_step(
+                        *step_args)
                 if injector is not None:
-                    injector.corrupt_after_save(ckpt, i)
-            if telemetry is not None:
-                telemetry.end_iteration(i, m, self.steps_per_iteration)
-            i += 1
+                    metrics = injector.poison_nan_member(self, i, metrics)
+                fitness = metrics.mean_reward
+                if watchdog is not None:
+                    reason = watchdog.check_population(fitness)
+                    if reason is not None:
+                        event = watchdog.rollback(self, ckpt, i, reason)
+                        if telemetry is not None:
+                            telemetry.iteration_aborted(
+                                i, f"rollback: {reason}")
+                        i = event.resume_iteration
+                        continue
+                self.controller.record(fitness)
+                out = self.controller.maybe_update(i, self.states,
+                                                   self.hparams)
+                if out is not None:
+                    self.states, self.hparams, decision = out
+                    if self.mesh is not None:
+                        # the exploit gather + host-side explore hand
+                        # back arrays without the pop-axis commitment;
+                        # re-pin them HERE — outside the next dispatch's
+                        # transfer guard — or the jit replicates them with
+                        # an implicit device-to-device copy (transfer alarm)
+                        self.states = jax.device_put(self.states,
+                                                     self.state_sharding)
+                        self.hparams = jax.device_put(self.hparams,
+                                                      self.hparam_sharding)
+                    if telemetry is not None:
+                        telemetry.emit(
+                            "pbt_exploit", iteration=i,
+                            exploited=int(decision.exploited.sum()),
+                            src=[int(s) for s in decision.src])
+                m = None
+                if log_every and (i % log_every == 0 or i == iterations - 1):
+                    # flatten per-member values to suffixed scalar columns so
+                    # the CSV stays pandas/TensorBoard-ingestible (ADVICE r1).
+                    # ONE batched device_get for the whole [P]-metrics tuple:
+                    # per-element float() was n_fields x P separate blocking
+                    # transfers per logged iteration (jsan host-sync review)
+                    m = {}
+                    with tracer.phase(sections, "sync"):
+                        got = jax.device_get(metrics)._asdict()
+                    for k, v in got.items():
+                        vals = [float(x) for x in v]
+                        m.update({f"{k}_{p}": x for p, x in enumerate(vals)})
+                        m[f"{k}_mean"] = sum(vals) / len(vals)
+                    history.append({"iteration": i, **m})
+                    if logger is not None:
+                        logger(i, m)
+                if eval_fn is not None and eval_every and \
+                        ((i + 1) % eval_every == 0 or i == iterations - 1):
+                    with tracer.phase(sections, "eval"):
+                        em = dict(eval_fn(i))
+                    eval_history.append({"iteration": i, **em})
+                    if eval_logger is not None:
+                        eval_logger(i, em)
+                if ckpt is not None and ckpt_every and \
+                        ((i + 1) % ckpt_every == 0 or i == iterations - 1):
+                    with tracer.phase(sections, "ckpt"):
+                        self.save_checkpoint(ckpt, meta={"iteration": i})
+                    if injector is not None:
+                        injector.corrupt_after_save(ckpt, i)
+                if telemetry is not None:
+                    telemetry.end_iteration(i, m, self.steps_per_iteration)
+                i += 1
         jax.block_until_ready(self.states.params)
         wall = time.monotonic() - t0
         total_env_steps = iterations * self.steps_per_iteration

@@ -1,0 +1,94 @@
+"""The names a profile of this program is read by, in one place.
+
+Two kinds. **Device scopes** are ``jax.named_scope`` names put on the
+jitted program's own layer boundaries by the function that owns each
+boundary; they reach the compiled program as metadata only (the HLO
+``op_name`` path of every operation traced under them), so the code the
+compiler emits is the same with or without them
+(tests/test_scopes.py). **Host annotations** are the obs tracer's span
+names as they appear in the profiler's trace: ``ANNOTATION_PREFIX`` +
+the span name (:mod:`.trace`), and one step annotation per train
+iteration.
+
+A reader matches a scope as a path component, so the ``vmap(...)`` and
+``while/body`` wrappers jax adds around them do not matter. The tree::
+
+    rollout                 algos.rollout.rollout
+      policy_forward        apply_fn + action_dist.sample
+      env_step              env.vec_step
+        sim_step            sim.core.rl_step
+          sim_queue         pending_queue / running_queue
+          sim_place         try_place (selected and forced), preempt
+          sim_advance       next_event_time, advance_to
+          sim_select        the branchless pick, StepInfo
+        reward              env.rewards.*
+        observe             env._observe: build_obs + action_mask
+        auto_reset          env.auto_reset
+    advantage               ppo.compute_advantages / a2c's GAE
+    update                  ppo.run_ppo_epochs / a2c.run_a2c_update
+      shuffle               permutation and minibatch gather
+      loss_grad             value_and_grad of the loss
+      apply                 clipping and the optimizer step
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+
+ROLLOUT = "rollout"
+POLICY_FORWARD = "policy_forward"
+ENV_STEP = "env_step"
+SIM_STEP = "sim_step"
+SIM_QUEUE = "sim_queue"
+SIM_PLACE = "sim_place"
+SIM_ADVANCE = "sim_advance"
+SIM_SELECT = "sim_select"
+REWARD = "reward"
+OBSERVE = "observe"
+AUTO_RESET = "auto_reset"
+ADVANTAGE = "advantage"
+UPDATE = "update"
+SHUFFLE = "shuffle"
+LOSS_GRAD = "loss_grad"
+APPLY = "apply"
+
+# every scope as its path from the program's top, parents first
+TREE = (
+    (ROLLOUT,),
+    (ROLLOUT, POLICY_FORWARD),
+    (ROLLOUT, ENV_STEP),
+    (ROLLOUT, ENV_STEP, SIM_STEP),
+    (ROLLOUT, ENV_STEP, SIM_STEP, SIM_QUEUE),
+    (ROLLOUT, ENV_STEP, SIM_STEP, SIM_PLACE),
+    (ROLLOUT, ENV_STEP, SIM_STEP, SIM_ADVANCE),
+    (ROLLOUT, ENV_STEP, SIM_STEP, SIM_SELECT),
+    (ROLLOUT, ENV_STEP, REWARD),
+    (ROLLOUT, ENV_STEP, OBSERVE),
+    (ROLLOUT, ENV_STEP, AUTO_RESET),
+    (ADVANTAGE,),
+    (UPDATE,),
+    (UPDATE, SHUFFLE),
+    (UPDATE, LOSS_GRAD),
+    (UPDATE, APPLY),
+)
+
+# host side: the profiler's trace shows span "step" as "rlsched:step"
+ANNOTATION_PREFIX = "rlsched:"
+TRAIN_ITERATION = ANNOTATION_PREFIX + "train_iteration"
+
+
+def scoped(name: str):
+    """Decorator: trace the function's body under
+    ``jax.named_scope(name)``, a fresh context each call
+    (``jax.named_scope`` used as a decorator keeps ONE context object
+    for every call, and its saved state is not per thread: the actor
+    and an evaluator tracing the same function at once would swap
+    name stacks)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap

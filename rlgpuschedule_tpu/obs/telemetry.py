@@ -201,8 +201,8 @@ class RunTelemetry:
         self.bus = EventBus(obs_dir, rank=rank, name=name)
         self.registry = Registry()
         self.sections = SectionTimer()
-        # the span-tracing flight recorder (obs.trace): disabled it is a
-        # shared no-op context per span — the run loops thread it
+        # the span-tracing flight recorder (obs.trace): disabled, a span
+        # is only the profiler's annotation — the run loops thread it
         # unconditionally, so --trace costs nothing when off
         self.tracer = Tracer(self.bus, enabled=trace)
         self._clock = clock

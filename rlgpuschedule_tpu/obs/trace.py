@@ -16,10 +16,20 @@ Design constraints, in order:
   only — never a device value. The device_get-counting test in
   tests/test_obs.py runs with tracing ON and still counts exactly one
   batched ``device_get`` per *logged* iteration.
+- **One clock with the device.** Every span entry point — enabled or
+  not, thread track or lane — also opens a
+  ``jax.profiler.TraceAnnotation`` named ``rlsched:<span>``
+  (:data:`.scopes.ANNOTATION_PREFIX`), so a profile of the normal path
+  (``--profile-dir`` with no ``--obs-dir``) shows the host's phases on
+  the profiler's own timeline, where an idle gap of the device can be
+  laid against them. The profiler being active is the only switch;
+  with it off an annotation is a ~0.3 us C++ no-op. No attributes go
+  to the annotation (an attribute costs more than the span on the
+  serve path).
 - **Near-zero overhead when disabled.** ``span()`` on a disabled tracer
-  returns one shared reusable no-op context — no generator, no
-  allocation, no lock. Run loops thread a :data:`NULL_TRACER` when no
-  telemetry is attached, so the hot path never branches on ``None``.
+  returns the bare annotation — no bus write, no lock, no Python-level
+  context. Run loops thread a :data:`NULL_TRACER` when no telemetry is
+  attached, so the hot path never branches on ``None``.
 - **Thread-aware.** The async engine's actor thread and the learner
   (caller) thread emit on one rank's bus concurrently; the bus write is
   serialized by :class:`.events.EventBus`'s emit lock, and each thread
@@ -33,10 +43,14 @@ closes it at the track's last timestamp with ``"torn": true``.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
-from typing import Any, Iterable
+from typing import Any, Iterable, Iterator
+
+from jax.profiler import TraceAnnotation
 
 from .events import RESERVED_FIELDS, EventBus, merge_events
+from .scopes import ANNOTATION_PREFIX
 
 # the bus kinds the tracer owns
 SPAN_BEGIN = "span_begin"
@@ -45,38 +59,34 @@ SPAN_POINT = "span_point"
 SPAN_KINDS = (SPAN_BEGIN, SPAN_END, SPAN_POINT)
 
 
+def _annotation(name: str) -> TraceAnnotation:
+    """The span as the profiler's trace shows it: ``rlsched:<name>``."""
+    return TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
 class _Span:
-    """One live span: begin on enter, end on exit. Exceptions propagate
-    (the end event still lands — a failed span is still an extent)."""
+    """One live span of an enabled tracer or lane (``owner``): the
+    profiler's annotation, then begin on enter; end on exit. Exceptions
+    propagate (the end event still lands — a failed span is still an
+    extent)."""
 
-    __slots__ = ("_tracer", "_name", "_attrs")
+    __slots__ = ("_owner", "_name", "_attrs", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
-        self._tracer = tracer
+    def __init__(self, owner: "Tracer | TracerLane", name: str,
+                 attrs: dict):
+        self._owner = owner
         self._name = name
         self._attrs = attrs
+        self._annotation = _annotation(name)
 
     def __enter__(self) -> "_Span":
-        self._tracer._begin(self._name, self._attrs)
+        self._annotation.__enter__()
+        self._owner._begin(self._name, self._attrs)
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer._end(self._name)
-
-
-class _NullSpan:
-    """Shared reusable no-op context for the disabled path."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
+        self._owner._end(self._name)
+        self._annotation.__exit__(*exc)
 
 
 class Tracer:
@@ -117,11 +127,28 @@ class Tracer:
         return stack
 
     def span(self, name: str, **attrs: Any) -> Any:
-        """Context manager for one span; no-op (one shared object, no
-        allocation) when the tracer is disabled."""
+        """Context manager for one span: always the profiler's
+        ``rlsched:<name>`` annotation, and the bus's begin/end pair when
+        the tracer is enabled."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _annotation(name)
         return _Span(self, name, attrs)
+
+    @contextlib.contextmanager
+    def phase(self, sections, name: str, *, span: str | None = None,
+              meter=None, **attrs: Any) -> Iterator[None]:
+        """One boundary of a run loop, named once: its wall time adds to
+        ``sections[name]`` (the ``SectionTimer`` that
+        ``RunTelemetry._section_delta`` and the report's phase table
+        read), it is a span (named ``span`` where the stream's name for
+        the boundary differs from the section's), and with ``meter`` (an
+        ``OverlapMeter``) it is that meter's busy lane ``name``."""
+        with sections(name), self.span(span or name, **attrs):
+            if meter is None:
+                yield
+            else:
+                with meter.span(name):
+                    yield
 
     def instant(self, name: str, **attrs: Any) -> None:
         """A zero-duration mark on this thread's track (Chrome ``i``
@@ -193,8 +220,8 @@ class TracerLane:
 
     def span(self, name: str, **attrs: Any) -> Any:
         if not self._tracer.enabled:
-            return _NULL_SPAN
-        return _LaneSpan(self, name, attrs)
+            return _annotation(name)
+        return _Span(self, name, attrs)
 
     def instant(self, name: str, **attrs: Any) -> None:
         if not self._tracer.enabled:
@@ -222,24 +249,6 @@ class TracerLane:
                               depth=depth)
 
 
-class _LaneSpan:
-    """One live span on a virtual lane (same contract as :class:`_Span`)."""
-
-    __slots__ = ("_lane", "_name", "_attrs")
-
-    def __init__(self, lane: TracerLane, name: str, attrs: dict):
-        self._lane = lane
-        self._name = name
-        self._attrs = attrs
-
-    def __enter__(self) -> "_LaneSpan":
-        self._lane._begin(self._name, self._attrs)
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self._lane._end(self._name)
-
-
 class _NullLane:
     """Shared no-op lane for disabled tracers."""
 
@@ -249,7 +258,7 @@ class _NullLane:
     tid = 0
 
     def span(self, name: str, **attrs: Any) -> Any:
-        return _NULL_SPAN
+        return _annotation(name)
 
     def instant(self, name: str, **attrs: Any) -> None:
         pass

@@ -1380,21 +1380,16 @@ class PolicyServer:
             ring.recycle(blk)
             return 0
         try:
-            if self.tracer is NULL_TRACER:   # span-free hot path
-                obs, mask, stall = self._arena_views(blk, bucket)
+            # with no tracer attached each span is the profiler's bare
+            # annotation: six a batch, ~2 us of a 1.66 ms dispatch
+            with self.tracer.span("serve_batch", n=n_live):
+                with self.tracer.span("arena_seal"):
+                    obs, mask, stall = self._arena_views(blk, bucket)
                 out, bucket = self.engine.decide(obs, mask, stall)
                 actions, blp, bval = self._split_capture(out)
                 now = self._clock()
-                per_req = self._scatter_arena(blk, actions, n_live)
-            else:
-                with self.tracer.span("serve_batch", n=n_live):
-                    with self.tracer.span("arena_seal"):
-                        obs, mask, stall = self._arena_views(blk, bucket)
-                    out, bucket = self.engine.decide(obs, mask, stall)
-                    actions, blp, bval = self._split_capture(out)
-                    now = self._clock()
-                    with self.tracer.span("scatter"):
-                        per_req = self._scatter_arena(blk, actions, n_live)
+                with self.tracer.span("scatter"):
+                    per_req = self._scatter_arena(blk, actions, n_live)
             lats = [now - t for t in t_subs]
             if self._flight_log is not None:
                 # tap point: the slab views stay valid until ring.recycle

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import scopes
 from ..traces.records import ArrayTrace
 from .faults import (FaultSchedule, effective_free, job_stretch, next_transition,
                      node_up, validate_fault_schedule)
@@ -432,6 +433,7 @@ def action_mask(params: SimParams, state: SimState, trace: Trace,
 
 # ---- the RL decision-point step --------------------------------------------
 
+@scopes.scoped(scopes.SIM_STEP)
 def rl_step(params: SimParams, state: SimState, trace: Trace,
             action: jax.Array, faults: "FaultSchedule | None" = None,
             ) -> tuple[SimState, StepInfo]:
@@ -453,20 +455,25 @@ def rl_step(params: SimParams, state: SimState, trace: Trace,
     reuses the compiled program (CompileCounter-asserted)."""
     K, P, R = params.queue_len, params.n_placements, params.preempt_len
     n_place = K * P
-    queue = pending_queue(params, state)
+    with jax.named_scope(scopes.SIM_QUEUE):
+        queue = pending_queue(params, state)
     is_place = action < n_place
     k = jnp.clip(action // P, 0, K - 1)
     mode = action % P
     j = jnp.where(is_place, queue[k], -1)
 
-    placed_state, placed = try_place(params, state, trace, j, mode, faults)
+    with jax.named_scope(scopes.SIM_PLACE):
+        placed_state, placed = try_place(params, state, trace, j, mode,
+                                         faults)
 
     if R:
-        run_q = running_queue(params, state, trace)
+        with jax.named_scope(scopes.SIM_QUEUE):
+            run_q = running_queue(params, state, trace)
         is_pre = ~is_place & (action < n_place + R)
         r = jnp.clip(action - n_place, 0, R - 1)
-        pre_state, preempted = preempt(
-            state, jnp.where(is_pre, run_q[r], -1), params.max_jobs)
+        with jax.named_scope(scopes.SIM_PLACE):
+            pre_state, preempted = preempt(
+                state, jnp.where(is_pre, run_q[r], -1), params.max_jobs)
     else:
         preempted = jnp.bool_(False)
     progress = placed | preempted
@@ -479,41 +486,44 @@ def rl_step(params: SimParams, state: SimState, trace: Trace,
     # horizon additionally implies no transition is pending, so any still-
     # drained node is drained FOREVER; a job that no longer fits the
     # surviving capacity makes forced_ok False the same way.
-    t_next = next_event_time(state, trace, faults)
-    has_event = jnp.isfinite(t_next)
-    n_before = in_system(state)
-    advanced_state = advance_to(state, trace, t_next, faults)
-    forced_state, forced_ok = try_place(params, state, trace, queue[0],
-                                        jnp.int32(PACK), faults)
+    with jax.named_scope(scopes.SIM_ADVANCE):
+        t_next = next_event_time(state, trace, faults)
+        has_event = jnp.isfinite(t_next)
+        n_before = in_system(state)
+        advanced_state = advance_to(state, trace, t_next, faults)
+    with jax.named_scope(scopes.SIM_PLACE):
+        forced_state, forced_ok = try_place(params, state, trace, queue[0],
+                                            jnp.int32(PACK), faults)
 
-    if R:
-        def pick(a, p, b, c):
-            # placed ? a : preempted ? p : (has_event ? b : c)
-            return jnp.where(placed, a, jnp.where(
-                preempted, p, jnp.where(has_event, b, c)))
+    with jax.named_scope(scopes.SIM_SELECT):
+        if R:
+            def pick(a, p, b, c):
+                # placed ? a : preempted ? p : (has_event ? b : c)
+                return jnp.where(placed, a, jnp.where(
+                    preempted, p, jnp.where(has_event, b, c)))
 
-        new_state = jax.tree.map(pick, placed_state, pre_state,
-                                 advanced_state, forced_state)
-    else:
-        def pick(a, b, c):  # placed ? a : (has_event ? b : c)
-            return jnp.where(placed, a, jnp.where(has_event, b, c))
+            new_state = jax.tree.map(pick, placed_state, pre_state,
+                                     advanced_state, forced_state)
+        else:
+            def pick(a, b, c):  # placed ? a : (has_event ? b : c)
+                return jnp.where(placed, a, jnp.where(has_event, b, c))
 
-        new_state = jax.tree.map(pick, placed_state, advanced_state,
-                                 forced_state)
-    dt = jnp.where(progress | ~has_event, 0.0, t_next - state.clock)
-    # "first" = the job had never run before this step (start still +inf);
-    # try_place keeps the original start on re-placement, so this reads the
-    # pre-step state
-    never_ran = ~jnp.isfinite(state.start)
-    first_sel = never_ran[jnp.clip(j, 0, params.max_jobs - 1)]
-    first_head = never_ran[jnp.clip(queue[0], 0, params.max_jobs - 1)]
-    forced_fire = ~progress & ~has_event & forced_ok
-    info = StepInfo(placed=placed | forced_fire,
-                    dt=dt, in_system_before=n_before,
-                    done=all_done(new_state, trace),
-                    preempted=preempted,
-                    first_placed=(placed & first_sel)
-                    | (forced_fire & first_head))
+            new_state = jax.tree.map(pick, placed_state, advanced_state,
+                                     forced_state)
+        dt = jnp.where(progress | ~has_event, 0.0, t_next - state.clock)
+        # "first" = the job had never run before this step (start still
+        # +inf); try_place keeps the original start on re-placement, so
+        # this reads the pre-step state
+        never_ran = ~jnp.isfinite(state.start)
+        first_sel = never_ran[jnp.clip(j, 0, params.max_jobs - 1)]
+        first_head = never_ran[jnp.clip(queue[0], 0, params.max_jobs - 1)]
+        forced_fire = ~progress & ~has_event & forced_ok
+        info = StepInfo(placed=placed | forced_fire,
+                        dt=dt, in_system_before=n_before,
+                        done=all_done(new_state, trace),
+                        preempted=preempted,
+                        first_placed=(placed & first_sel)
+                        | (forced_fire & first_head))
     return new_state, info
 
 

@@ -38,12 +38,21 @@ def enable_compile_cache() -> str:
     would switch jax's file cache to its LRU layout (``-cache`` +
     ``-atime`` pairs) and make a directory shared with any process that
     did not set the same cap unwritable. Whoever places the directory
-    bounds it. Returns the directory."""
+    bounds it. Returns the directory.
+
+    The cache key includes each operation's metadata. jax's default key
+    strips it, so a program that differs from a cached one only in its
+    ``jax.named_scope`` names (``obs.scopes``) would be handed the
+    cached executable with the OTHER program's ``op_name`` paths, and a
+    profile of it would show none of its own scopes. The price: an
+    entry is good for one version of the traced source lines, not for
+    every program with the same arithmetic."""
     directory = cache_dir()
     import jax
 
     jax.config.update("jax_compilation_cache_dir", directory)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return directory
 
 

@@ -55,10 +55,14 @@ class TestTracer:
         assert events[0]["attrs"] == {"iteration": 7}
         assert all(e["tid"] == 0 for e in events)
 
-    def test_disabled_tracer_is_shared_noop(self, tmp_path):
-        # the hot-path contract: no allocation, no emission when off
-        assert NULL_TRACER.span("x") is NULL_TRACER.span("y")
-        assert not NULL_TRACER.enabled
+    def test_disabled_tracer_writes_to_no_bus(self, tmp_path):
+        # the hot-path contract: off, a span is the profiler's bare
+        # annotation (ISSUE 27) and nothing is emitted, to any bus
+        from jax.profiler import TraceAnnotation
+        assert isinstance(NULL_TRACER.span("x"), TraceAnnotation)
+        assert NULL_TRACER.bus is None and not NULL_TRACER.enabled
+        with NULL_TRACER.span("x"), NULL_TRACER.lane("l").span("y"):
+            NULL_TRACER.instant("mark")
         with EventBus(str(tmp_path), rank=0) as bus:
             t = Tracer(bus, enabled=False)
             with t.span("a"):
