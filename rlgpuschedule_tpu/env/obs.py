@@ -123,6 +123,21 @@ def grid_obs(params: SimParams, state: SimState, trace: Trace,
                        service demand painted on the bar.
     preempt rows (preemptive configs): ch0 = demand bar of running-queue
                        slots; ch1 = normalized remaining service on the bar.
+
+    The waterfall is built densely, in G rounds over the ``[J, N]``
+    allocation table (a job holds at least one GPU on a node it is on, so
+    a node hosts at most G jobs and G rounds reach them all). Round r
+    finds on every node the largest remaining-value below round r-1's (a
+    masked max over J), adds up the GPUs of the jobs that hold exactly
+    that value (a masked sum over J) and paints the value on that many
+    slots after those already painted. Two reductions over ``[J, N]`` a
+    round: O(G·J·N) compares and selects, linear in J, nothing kept
+    beyond one ``[J, N]`` table, and no sort, search or data-dependent
+    gather, which a TPU runs three orders of magnitude slower than dense
+    compare-and-reduce at this size (PERF.md §6, PR 28). Jobs with EQUAL
+    values on a node are taken in the same round and paint the same value
+    on the sum of their GPUs, so the image cannot depend on how ties
+    would be ordered.
     """
     N, G, K = params.n_nodes, params.gpus_per_node, params.queue_len
     used = (params.gpus_per_node - state.free).astype(jnp.float32)    # [N]
@@ -130,15 +145,21 @@ def grid_obs(params: SimParams, state: SimState, trace: Trace,
     occ = (slots[None, :] < used[:, None]).astype(jnp.float32)        # [N,G]
     running = (state.status == RUNNING).astype(jnp.float32)
     val = running * jnp.tanh(state.remaining / time_scale)            # [J]
-    order = jnp.argsort(-val)                                         # [J]
-    # slot s of node n belongs to the first job (longest-remaining-first)
-    # whose cumulative GPU count on n exceeds s
-    cum = jnp.cumsum(state.alloc.astype(jnp.int32)[order, :], axis=0)  # [J,N]
-    sidx = jnp.arange(G, dtype=cum.dtype)
-    idx = jax.vmap(lambda c: jnp.searchsorted(c, sidx, side="right"))(
-        cum.T)                                                        # [N,G]
-    J = params.max_jobs
-    rem_img = val[order][jnp.clip(idx, 0, J - 1)] * (idx < J)         # [N,G]
+    alloc = state.alloc                                               # [J,N]
+    # -inf where a job holds nothing on the node: below every value, and
+    # equal to a round's max only once a node's jobs are all painted
+    key = jnp.where(alloc > 0, val[:, None], -jnp.inf)                # [J,N]
+    islots = jnp.arange(G, dtype=jnp.int32)[None, :]                  # [1,G]
+    rem_img = jnp.zeros((N, G), jnp.float32)
+    painted = jnp.zeros((N,), jnp.int32)
+    top = jnp.full((N,), jnp.inf, jnp.float32)
+    for _ in range(G):
+        top = jnp.max(jnp.where(key < top[None, :], key, -jnp.inf), axis=0)
+        upto = painted + jnp.sum(
+            jnp.where(key == top[None, :], alloc, 0), axis=0)         # [N]
+        turn = (islots >= painted[:, None]) & (islots < upto[:, None])
+        rem_img = jnp.where(turn, top[:, None], rem_img)              # [N,G]
+        painted = upto
     cluster = jnp.stack([occ, occ * rem_img], axis=-1)                # [N,G,2]
 
     if queue is None:

@@ -185,6 +185,238 @@ class TestResetStep:
         assert rewards[0] < rewards[1] < 0.0
 
 
+_TIME_SCALE = 100.0
+# jitted with the scale a constant, as in grid_obs: XLA then multiplies by
+# its reciprocal, which an eager divide does not (2 ulp apart)
+_squash = jax.jit(lambda x: jnp.tanh(x / _TIME_SCALE))
+
+
+def _queues(sim, state, trace):
+    from rlgpuschedule_tpu.sim import core
+    return (core.pending_queue(sim, state),
+            core.running_queue(sim, state, trace) if sim.preempt_len else None)
+
+
+def _grid_reference(sim, state, trace, queue, run_queue):
+    """``grid_obs``'s docstring, slot by slot in numpy: per node, the
+    resident jobs longest-remaining first, each painting its value on the
+    slots it holds. Only the squashing of a time is jax's, so that painted
+    values carry the same bits; who owns which slot is worked out here."""
+    from rlgpuschedule_tpu.sim.core import RUNNING
+    J, N, G = sim.max_jobs, sim.n_nodes, sim.gpus_per_node
+    K, R = sim.queue_len, sim.preempt_len
+    status, alloc, free = (np.asarray(x) for x in
+                           (state.status, state.alloc, state.free))
+    remaining = np.asarray(_squash(state.remaining))
+    val = np.where(status == RUNNING, remaining, np.float32(0.0))
+    img = np.zeros((N + K + R, G, 2), np.float32)
+    for n in range(N):
+        used = G - int(free[n])
+        img[n, :used, 0] = 1.0
+        residents = sorted((j for j in range(J) if alloc[j, n] > 0),
+                           key=lambda j: -float(val[j]))
+        s = 0
+        for j in residents:
+            for _ in range(int(alloc[j, n])):
+                assert s < used, "a job holds a slot the node counts free"
+                img[n, s, 1] = val[j]
+                s += 1
+    gpus, service = np.asarray(trace.gpus), np.asarray(_squash(trace.duration))
+    rows = [(N + k, int(j), service) for k, j in enumerate(np.asarray(queue))]
+    if R:
+        rows += [(N + K + r, int(j), remaining)
+                 for r, j in enumerate(np.asarray(run_queue))]
+    for row, j, painted in rows:
+        if j >= 0:
+            d = min(int(gpus[j]), G)
+            img[row, :d, 0] = 1.0
+            img[row, :d, 1] = painted[j]
+    return img
+
+
+def _sim_params(J=16, N=4, G=4, K=4, R=0):
+    return SimParams(n_nodes=N, gpus_per_node=G, max_jobs=J, queue_len=K,
+                     preempt_len=R)
+
+
+def _busy_trace(J, seed, sizes=(1, 2, 4)):
+    """Arrivals far faster than service: nodes fill and stay full."""
+    tr = gen_poisson_trace(rate=2.0, n_jobs=J, seed=seed, max_jobs=J,
+                           mean_duration=300.0, gpu_sizes=sizes,
+                           gpu_probs=(0.5, 0.3, 0.2))
+    return Trace.from_array_trace(tr)
+
+
+def _held_state(sim, jobs, capacity=None):
+    """A SimState in which ``jobs`` = [(row, remaining, {node: gpus})] run
+    and every other row is done."""
+    from rlgpuschedule_tpu.sim.core import SimState, RUNNING, DONE, INF
+    J, N, G = sim.max_jobs, sim.n_nodes, sim.gpus_per_node
+    status = np.full(J, DONE, np.int32)
+    remaining = np.zeros(J, np.float32)
+    alloc = np.zeros((J, N), np.int32)
+    for j, rem, held in jobs:
+        status[j], remaining[j] = RUNNING, rem
+        for n, g in held.items():
+            alloc[j, n] = g
+    cap = np.full(N, G) if capacity is None else np.asarray(capacity)
+    free = (cap - alloc.sum(axis=0)).astype(np.int32)
+    assert (free >= 0).all()
+    return SimState(
+        clock=jnp.float32(100.0), status=jnp.asarray(status),
+        remaining=jnp.asarray(remaining), start=jnp.zeros(J, jnp.float32),
+        finish=jnp.full(J, INF, jnp.float32), alloc=jnp.asarray(alloc),
+        free=jnp.asarray(free))
+
+
+def _random_held(sim, seed, tie_share=0.4):
+    """Nodes filled at random up to G, a share of the jobs tied."""
+    rng = np.random.default_rng(seed)
+    J, N, G = sim.max_jobs, sim.n_nodes, sim.gpus_per_node
+    free, jobs = np.full(N, G), []
+    for j in rng.permutation(J)[:(3 * J) // 4]:
+        held = {}
+        for n in rng.permutation(N)[:rng.integers(1, 4)]:
+            if free[n] > 0:
+                held[int(n)] = int(rng.integers(1, free[n] + 1))
+                free[n] -= held[int(n)]
+        if held:
+            rem = (float(rng.integers(0, 4)) * 40.0
+                   if rng.random() < tie_share else float(rng.random() * 300))
+            jobs.append((int(j), rem, held))
+    return _held_state(sim, jobs)
+
+
+_SYNTHETIC = {
+    # name: (sim params, the state's builder)
+    # two jobs of equal remaining share node 0 with a shorter third
+    "ties": (dict(), lambda sim: _held_state(sim, [
+        (0, 50.0, {0: 1}), (1, 20.0, {0: 1}), (2, 50.0, {0: 2}),
+        (3, 50.0, {1: 1}), (4, 50.0, {1: 1})])),
+    "full_node": (dict(), lambda sim: _held_state(sim, [
+        (5, 10.0, {1: 1}), (2, 70.0, {1: 1}), (9, 30.0, {1: 1}),
+        (15, 90.0, {1: 1}), (0, 5.0, {3: 4})])),
+    "empty_cluster": (dict(), lambda sim: _held_state(sim, [])),
+    "gang_over_nodes": (dict(), lambda sim: _held_state(sim, [
+        (0, 40.0, {0: 4, 1: 3, 2: 1}), (1, 90.0, {2: 2}),
+        (7, 15.0, {1: 1, 2: 1})])),
+    "running_with_remaining_0": (dict(), lambda sim: _held_state(sim, [
+        (0, 0.0, {0: 2}), (1, 30.0, {0: 1}), (2, 0.0, {2: 1})])),
+    "J20_G8_random": (dict(J=20, N=3, G=8), lambda sim: _random_held(sim, 1)),
+    "J37_G8_random_ties": (dict(J=37, N=5, G=8, K=6),
+                           lambda sim: _random_held(sim, 2, 0.9)),
+    "shrunken_node": (dict(), lambda sim: _held_state(sim, [
+        (0, 60.0, {0: 1, 1: 2}), (3, 80.0, {1: 1}), (4, 10.0, {0: 1})],
+        capacity=[2, 3, 0, 4])),
+}
+
+_ROLLOUTS = {
+    # name: (sim params, per-node capacity of a domains schedule or None)
+    "rehearsal_shape": (dict(), None),
+    "J20_G8": (dict(J=20, N=3, G=8), None),
+    "preempt_len_2": (dict(R=2), None),
+    "domains_shrunken_node": (dict(), [4, 2, 4, 3]),
+}
+
+
+def _image_check(sim, trace):
+    """check(state): ``grid_obs``, jitted once for this shape, equals the
+    slot-by-slot reference bit for bit."""
+    from rlgpuschedule_tpu.env.obs import grid_obs
+    built = jax.jit(
+        lambda st, q, rq: grid_obs(sim, st, trace, _TIME_SCALE, q, rq))
+
+    def check(state):
+        queue, run_queue = _queues(sim, state, trace)
+        got = np.asarray(built(state, queue, run_queue))
+        want = _grid_reference(sim, state, trace, queue, run_queue)
+        assert got.dtype == np.float32 and got.shape == want.shape
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+    return check
+
+
+class TestGridObsDenseWaterfall:
+    """ISSUE 28: the per-slot waterfall is built by dense rounds of
+    compare/select/reduce. The image must equal, bit for bit, one painted
+    slot by slot from the docstring, and the program must stay free of
+    the sort / search / gather-over-[J, N] operations it replaced."""
+
+    @pytest.mark.parametrize("case", sorted(_SYNTHETIC))
+    def test_equals_slot_by_slot_reference_on_built_states(self, case):
+        kw, build = _SYNTHETIC[case]
+        sim = _sim_params(**kw)
+        trace = make_trace(seed=3, n_jobs=sim.max_jobs - 2,
+                           max_jobs=sim.max_jobs)
+        _image_check(sim, trace)(build(sim))
+
+    @pytest.mark.parametrize("case", sorted(_ROLLOUTS))
+    def test_equals_slot_by_slot_reference_along_a_rollout(self, case):
+        """States every third step of a real ``env.step`` rollout under a
+        random (masked) policy on an overloaded window, until nodes are
+        full and shared."""
+        from rlgpuschedule_tpu.domains import DomainSchedule
+        from rlgpuschedule_tpu.sim.faults import no_faults
+        kw, capacity = _ROLLOUTS[case]
+        sim = _sim_params(**kw)
+        params = EnvParams(sim=sim, obs_kind="grid", time_scale=_TIME_SCALE,
+                           reward_scale=100.0, horizon=64)
+        trace = _busy_trace(sim.max_jobs, seed=5,
+                            sizes=(1, 2, 4) if capacity is None else (1, 2, 3))
+        faults = (None if capacity is None else DomainSchedule(
+            *no_faults(sim.n_nodes), capacity=np.asarray(capacity, np.int32)))
+        rng = np.random.default_rng(11)
+        jstep = jax.jit(lambda s, a: step(params, s, trace, a, faults))
+        state, ts = reset(params, trace, faults)
+        check = _image_check(sim, trace)
+        saw_full = saw_shared = False
+        for t in range(48):
+            valid = np.flatnonzero(np.asarray(ts.action_mask))
+            # placements over the no-op two times in three: fill the nodes
+            place = valid[valid != params.n_actions - 1]
+            act = (rng.choice(place) if len(place) and rng.random() < 0.67
+                   else rng.choice(valid))
+            state, ts = jstep(state, jnp.int32(act))
+            if bool(ts.done):
+                break
+            if t % 3 == 0:
+                check(state.sim)
+                alloc = np.asarray(state.sim.alloc)
+                saw_full |= bool((np.asarray(state.sim.free) == 0).any())
+                saw_shared |= bool(((alloc > 0).sum(axis=0) >= 2).any())
+        assert saw_full and saw_shared, "the rollout never loaded a node"
+
+    @pytest.mark.parametrize("preempt_len", [0, 2])
+    def test_program_has_no_sort_search_or_table_gather(self, preempt_len):
+        """What keeps the 2.6 s binary search (PERF.md §6, PR 28) from
+        coming back through a refactor: no sort, loop, running sum or
+        window reduction anywhere in ``grid_obs``'s jaxpr, and no gather
+        larger than the queue and preempt rows' K + R trace look-ups."""
+        from rlgpuschedule_tpu.env.obs import grid_obs
+        sim = _sim_params(R=preempt_len)
+        trace = make_trace()
+        state = _held_state(sim, [(0, 40.0, {0: 2}), (1, 10.0, {0: 1})])
+        closed = jax.make_jaxpr(
+            lambda st, tr, q, rq: grid_obs(sim, st, tr, _TIME_SCALE, q, rq)
+        )(state, trace, *_queues(sim, state, trace))
+
+        def equations(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from equations(sub)
+
+        eqns = list(equations(closed.jaxpr))
+        names = {e.primitive.name for e in eqns}
+        banned = ("sort", "while", "scan", "cum", "reduce_window", "top_k")
+        assert not [n for n in names if any(b in n for b in banned)], names
+        # the walk did reach inside the jitted jnp calls
+        assert "reduce_max" in names and "reduce_sum" in names
+        biggest = max(int(np.prod(e.outvars[0].aval.shape))
+                      for e in eqns if e.primitive.name == "gather")
+        assert biggest <= sim.queue_len + sim.preempt_len
+
+
 class TestEmptyWindow:
     @pytest.mark.parametrize("obs_kind", ["flat", "grid", "graph"])
     def test_all_padding_trace_obs_finite(self, obs_kind):
