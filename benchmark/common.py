@@ -119,6 +119,11 @@ class Checks:
     def correct(self) -> bool:
         return bool(self.rows) and all(r["ok"] for r in self.rows)
 
+    def compared(self) -> dict:
+        """``{name: {"value", "limit"}}``, in the order compared."""
+        return {r["check"]: {"value": r["value"], "limit": r["limit"]}
+                for r in self.rows}
+
     def emit(self) -> None:
         for r in self.rows:
             log(**r)

@@ -156,16 +156,21 @@ def make_behaviour(obs_kind: str, block: int, quant=None):
 
 class Follower:
     """Follows the program's iterations: holds its OWN params and Adam
-    state from the seeded start and advances them on each trajectory."""
+    state from the seeded start (a copy: the caller's stay the caller's) and
+    advances them on each trajectory. The update takes them DONATED, so
+    that the scans' carries alias the arguments and the follower's peak is
+    its state once, not twice; ``release`` frees them."""
 
     def __init__(self, obs_kind: str, hp: Hyper, params, block: int,
                  quant=None, fault=None):
         self.hp = hp
-        self.params = params
-        self.adam = adam_init(params)
+        self.params = jax.tree.map(jnp.array, params)
+        self.adam = adam_init(self.params)
+        self.memory: dict | None = None
         self._behaviour = jax.jit(make_behaviour(obs_kind, block, quant))
         self._update = jax.jit(make_update(obs_kind, hp, block, quant,
-                                           fault))
+                                           fault), donate_argnums=(0, 1))
+        self._compiled = None
 
     def step(self, traj: dict, key) -> dict:
         """``traj``: obs[T,E,...], mask[T,E,A], action[T,E], reward[T,E],
@@ -185,18 +190,65 @@ class Follower:
         adv_n = gae_ref.normalize(adv)
         data = (obs, mask, action, logp, value,
                 jnp.asarray(adv_n.reshape(-1)), jnp.asarray(ret.reshape(-1)))
-        self.params, self.adam, losses = self._update(self.params, self.adam,
-                                                      data, key)
+        if self._compiled is None:
+            self._compiled = self._update.lower(self.params, self.adam, data,
+                                                key).compile()
+            self.memory = self._memory(data)
+        self.params, self.adam, losses = self._compiled(
+            self.params, self.adam, data, key)
         return {"loss": float(jnp.mean(losses)),
                 "log_prob": np.asarray(logp).reshape(T, E),
                 "value": value_te}
 
+    def _memory(self, data) -> dict:
+        """What the first update is about to cost, read as it starts: what
+        the device holds, and the compiler's account of the update at the
+        cell's own shape (``peak`` = arguments + outputs + temporaries -
+        aliased), split in two. ``param_shaped_bytes``: what arguments and
+        outputs hold of the follower's state (once where it is aliased,
+        12 B a parameter) and the gradient accumulator (4 B): it grows with
+        the policy by construction. ``remainder_bytes``: the rest of the
+        peak, i.e. the data and its shuffled copy, a block's activations,
+        and a block's gradients and the optimizer's temporaries, which
+        grow with the policy too (PERF.md section 4 has the slope measured
+        on the chip, ``benchmark/follower_memory.py``)."""
+        n_params = sum(x.size for x in jax.tree.leaves(self.params))
+        m = self._compiled.memory_analysis()
+        update = {"argument": m.argument_size_in_bytes,
+                  "output": m.output_size_in_bytes,
+                  "temp": m.temp_size_in_bytes,
+                  "alias": m.alias_size_in_bytes}
+        update["peak"] = (update["argument"] + update["output"]
+                          + update["temp"] - update["alias"])
+        nbytes = lambda tree: sum(x.nbytes for x in jax.tree.leaves(tree))
+        state = nbytes((self.params, self.adam))
+        shaped = 2 * state - update["alias"] + nbytes(self.params)
+        stats = jax.local_devices()[0].memory_stats() or {}
+        return {"param_count": n_params,
+                "bytes_in_use": stats.get("bytes_in_use"),
+                "update": update, "data_bytes": nbytes(data),
+                "alias_bytes_per_param": update["alias"] / n_params,
+                "param_shaped_bytes": shaped,
+                "remainder_bytes": update["peak"] - shaped}
 
-def worst_leaf_gap(program_norms, reference_norms) -> float:
-    """Largest |program - reference| over leaves, each measured against
-    the reference's norm of that leaf or of the median leaf, whichever is
-    larger (some leaves' gradients are all but zero)."""
+    @property
+    def released(self) -> bool:
+        return all(x.is_deleted()
+                   for x in jax.tree.leaves((self.params, self.adam)))
+
+    def release(self) -> None:
+        """Free the follower's device state (its readings are the
+        caller's, on the host)."""
+        for x in jax.tree.leaves((self.params, self.adam)):
+            x.delete()
+
+
+def leaf_gaps(program_norms, reference_norms) -> np.ndarray:
+    """|program - reference| leaf by leaf, each measured against the
+    reference's norm of that leaf or of the median leaf, whichever is
+    larger (some leaves' gradients are all but zero). The driver reads the
+    worst and the median of them; it holds neither to a limit."""
     p = np.asarray(program_norms, np.float64)
     r = np.asarray(reference_norms, np.float64)
     floor = max(float(np.median(r)), 1e-30)
-    return float(np.max(np.abs(p - r) / np.maximum(r, floor)))
+    return np.abs(p - r) / np.maximum(r, floor)

@@ -7,9 +7,10 @@ One process: find the cell's files by the names in ``BENCHMARK.json``,
 require the TPU (no CPU fallback), place the compile cache, build and warm
 the cell's own shapes (set-up), measure for ``--seconds``, check what the
 timed path produced against the plain references, print. The LAST line of
-stdout is the contract's JSON object; everything else (compile split,
-every number compared beside its limit) is on earlier lines, one JSON
-object each.
+stdout is the contract's JSON object, with every number compared beside
+its limit under ``checks``, its last key (they are the last lines of stderr
+too); everything else (compile split, the check's memory, each comparison's
+detail) is on earlier lines, one JSON object each.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` a short
 profiled window and the cell's per-layer metrics (each read by the reader
@@ -176,6 +177,10 @@ def main(argv: "list[str] | None" = None) -> int:
         return 5
     line, _ = execute(args, loaded, device)
     print(json.dumps(line), file=sys.__stdout__, flush=True)
+    # the last lines of stderr: every number compared beside its limit
+    for name, c in line["checks"].items():
+        print(f"benchmark: compared {name} = {c['value']!r} "
+              f"(limit {c['limit']!r})", file=sys.stderr, flush=True)
     return 0
 
 
@@ -230,6 +235,7 @@ def execute(args, loaded: dict, device: dict) -> "tuple[dict, object]":
             line["breakdown"] = {"device_ops": reduced["device_ops"][:10],
                                  "idle_gaps": reduced["idle_gaps"][:10]}
         line["metrics"] = per_layer(ctx, result, reduced)
+    line["checks"] = checks.compared()      # last in the line
     return line, checks
 
 

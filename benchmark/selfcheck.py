@@ -13,7 +13,8 @@
    against its learn step in float32;
 3. ``run.py --rehearse-cpu`` for every cell of ``BENCHMARK.json``, both
    ``--trace`` values: a well-formed last line, ``correct: false``, the
-   CPU named.
+   CPU named, and a ``check_memory`` line that shows none of the program's
+   state on the device while the references ran.
 
 Prints one line per check and ``SELFCHECK ok`` / ``SELFCHECK FAILED``; no
 part of the repo's tier-1 count.
@@ -176,7 +177,8 @@ def check_rehearsals() -> None:
             try:
                 line = json.loads(p.stdout.strip().splitlines()[-1])
                 ok = (set(line) - {"breakdown"} == {
-                    "correct", "attempted", "failed", "metrics", "device"}
+                    "correct", "attempted", "failed", "metrics", "device",
+                    "checks"} and list(line)[-1] == "checks"
                     and line["correct"] is False
                     and line["device"]["platform"] == "cpu"
                     and line["attempted"] > 0 and line["metrics"]
@@ -189,6 +191,13 @@ def check_rehearsals() -> None:
                 report(name, ok, f"metrics {sorted(line['metrics'])}"
                        + (f" checks not holding at the tiny shape: "
                           f"{failing}" if failing else ""))
+                memory = [json.loads(l) for l in p.stdout.splitlines()
+                          if l.startswith('{"phase": "check_memory"')]
+                report(f"{name}: the references ran with the program's "
+                       f"state parked", bool(memory) and all(
+                           m["program_state_bytes_on_device"] == 0
+                           and m["followers_alive"] == 1 for m in memory),
+                       f"check_memory lines {len(memory)}")
             except (ValueError, KeyError, IndexError) as e:
                 report(name, False, f"bad last line: {e}")
 

@@ -10,10 +10,10 @@ from benchmark import run as bench_run
 CELL = "philly512-cnn.train"
 
 
-def _execute(workload: str = CELL, seed: int = 5):
+def _execute(workload: str = CELL, seed: int = 5, trace: int = 0):
     from rlgpuschedule_tpu.utils.platform import device_record
     args = argparse.Namespace(workload=workload, seed=seed, seconds=0.5,
-                              trace=0, rehearse_cpu=True)
+                              trace=trace, rehearse_cpu=True)
     line, checks = bench_run.execute(args, common.load_cell(workload),
                                      device_record())
     return line, {r["check"]: r for r in checks.rows}

@@ -93,13 +93,21 @@ def test_recorded_heaviest_operation_is_under_one_leaf(recorded):
     assert src.endswith("rlgpuschedule_tpu/env/obs.py:138")
 
 
+# no metric any more (ISSUE 29: it read where 2 ms sat), but the reader keeps
+# the argument and the ``host_gaps`` line the reading: the case stays
+RETIRED = {"idle_explained_share.train": {
+    "reader": "program_spans", "args": {"idle_explained": True}}}
+
+
 def test_recorded_metrics_through_the_readers(recorded, monkeypatch):
     events, expected = recorded
     probe = probe_of(events, monkeypatch)
     for name, want in expected["metrics"].items():
-        with open(os.path.join(os.path.dirname(FIXTURES), "layer_metrics",
-                               name + ".json")) as f:
-            m = json.load(f)
+        m = RETIRED.get(name)
+        if m is None:
+            with open(os.path.join(os.path.dirname(FIXTURES),
+                                   "layer_metrics", name + ".json")) as f:
+                m = json.load(f)
         reader = {"scope_time": scope_time,
                   "program_spans": program_spans}[m["reader"]]
         assert reader.read(probe, m["args"]) == pytest.approx(want), name

@@ -10,9 +10,10 @@ of those intervals, so nesting and overlap count once; an operation's time
 in the table is its SELF time (its span minus the spans of operations
 nested in it). The window is the span from the first to the last device
 event of the chips used. Host spans come from the benchmark's own
-``jax.profiler.TraceAnnotation`` names (the traffic file's ``host_spans``)
-on the host plane's lines; a gap is attributed to the one that covers most
-of it.
+``jax.profiler.TraceAnnotation`` names and the program's ``rlsched:`` spans
+(the traffic file's ``host_spans``) on the host plane's lines; a gap is
+attributed to the INNERMOST span that covers it: of the spans that cover
+most of it, the shortest.
 """
 from __future__ import annotations
 
@@ -101,12 +102,12 @@ def reduce_events(events: dict, chips: int = 1) -> dict:
     n = max(len(busy), 1)
     gap_rows = []
     for gs, ge in sorted(gaps_all, key=lambda g: g[0] - g[1])[:10]:
-        best, best_cover = "unattributed", 0.0
-        for name, s, d in events["host"]:
-            cover = min(ge, s + d) - max(gs, s)
-            if cover > best_cover:
-                best, best_cover = name, cover
-        gap_rows.append([best, (ge - gs) * 1e-9])
+        # (cover, -duration): the largest cover, then the shortest span
+        best = max(((min(ge, s + d) - max(gs, s), -d, name)
+                    for name, s, d in events["host"]),
+                   default=(0.0, 0.0, "unattributed"))
+        gap_rows.append([best[2] if best[0] > 0 else "unattributed",
+                         (ge - gs) * 1e-9])
     top = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
     return {"busy_s": sum(busy) / n * 1e-9,
             "window_s": sum(window) / n * 1e-9,
