@@ -108,6 +108,22 @@ class PPOMetrics(NamedTuple):
     rho_max: jax.Array
 
 
+# :class:`PPOMetrics` and the expert layers' counters, for a policy whose
+# apply has a ``counted`` form (``models.trunk``); read in the update's own
+# loss forward, over its minibatches:
+# - moe_assignments_held: assignments to the experts held here, a
+#   minibatch, summed over the expert layers (mean over the minibatches);
+# - moe_expert_load_max_over_mean: the fullest held expert's load over the
+#   mean held load (largest of the layers and minibatches);
+# - moe_dropped_assignments: assignments to held experts that were not
+#   computed (summed; 0 by construction: the sorted buffer has a row for
+#   every assignment).
+MOE_COUNTERS = ("moe_assignments_held", "moe_expert_load_max_over_mean",
+                "moe_dropped_assignments")
+MoEPPOMetrics = NamedTuple("MoEPPOMetrics", [
+    *((f, jax.Array) for f in PPOMetrics._fields + MOE_COUNTERS)])
+
+
 class RewardNormState(NamedTuple):
     """Welford running moments of the raw reward stream (fp32 scalars),
     carried in :class:`NormTrainState` when ``reward_norm`` is on."""
@@ -170,7 +186,14 @@ def ppo_loss(apply_fn: PolicyApply, net_params, batch: Transition,
     (``parallel.population``) without recompilation."""
     clip_eps = config.clip_eps if clip_eps is None else clip_eps
     ent_coef = config.ent_coef if ent_coef is None else ent_coef
-    logits, value = apply_fn(net_params, batch.obs, batch.mask)
+    counted = getattr(apply_fn, "counted", None)
+    if counted is None:
+        logits, value = apply_fn(net_params, batch.obs, batch.mask)
+        counters = ()
+    else:       # the same forward, the expert layers' counters read out
+        (logits, value), counters = counted(net_params, batch.obs,
+                                            batch.mask)
+        counters = (counters,)
     log_prob = action_dist.log_prob(logits, batch.action)
     ratio = jnp.exp(log_prob - batch.log_prob)
     pg1 = ratio * advantages
@@ -186,7 +209,8 @@ def ppo_loss(apply_fn: PolicyApply, net_params, batch: Transition,
     approx_kl = jnp.mean(batch.log_prob - log_prob)
     clip_frac = jnp.mean((jnp.abs(ratio - 1.0) > clip_eps)
                          .astype(jnp.float32))
-    return total, (pg_loss, v_loss, entropy, approx_kl, clip_frac)
+    return total, (pg_loss, v_loss, entropy, approx_kl, clip_frac,
+                   *counters)
 
 
 def normalize_advantages(advantages: jax.Array,
@@ -324,6 +348,14 @@ def run_ppo_epochs(apply_fn: PolicyApply, config: PPOConfig, state,
         approx_kl=jnp.mean(stats[4]), clip_frac=jnp.mean(stats[5]),
         mean_reward=jnp.mean(tr.reward), mean_value=jnp.mean(tr.value),
         rho_mean=rho_mean, rho_max=rho_max)
+    if len(stats) > 6:
+        c = stats[6]
+        metrics = MoEPPOMetrics(
+            *metrics,
+            moe_assignments_held=jnp.mean(c["moe_assignments_held"]),
+            moe_expert_load_max_over_mean=jnp.max(
+                c["moe_expert_load_max_over_mean"]),
+            moe_dropped_assignments=jnp.sum(c["moe_dropped_assignments"]))
     return state, metrics
 
 
@@ -389,7 +421,13 @@ def make_train_state(net, key: jax.Array, example_obs: jax.Array,
     ``reward_norm`` swaps in :class:`NormTrainState` carrying the
     streaming reward moments (different pytree — checkpoints are not
     interchangeable with the default state, by design)."""
-    params = net.init(key, example_obs, *extra_apply_args, example_mask)
+    # init under ONE program, not an eager one per operation of the
+    # policy's forward pass (which init runs and the compiler drops here:
+    # only the leaves come out): the token policy's init was 66 s of a
+    # cold start on the chip, eagerly (PERF.md section 6, PR 30). The
+    # leaves are bit-equal.
+    params = jax.jit(net.init)(key, example_obs, *extra_apply_args,
+                               example_mask)
     if reward_norm:
         state = NormTrainState.create(apply_fn=net.apply, params=params,
                                       tx=tx,

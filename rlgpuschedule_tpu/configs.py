@@ -44,7 +44,11 @@ class ExperimentConfig:
     n_placements: int = 1
     preempt_len: int = 0                # >0 = preemptive RL action space
     n_pods: int = 1                     # >1 = hierarchical env (config 5)
-    obs_kind: Literal["flat", "grid", "graph"] = "flat"
+    obs_kind: Literal["flat", "grid", "graph", "tokens"] = "flat"
+    # obs_kind "tokens": which whole set of trunk sizes
+    # (models.trunk.TRUNKS); "published" is the source model's widths,
+    # "tiny" the CPU tests' shape. No flag sets a single width.
+    trunk: Literal["published", "tiny"] = "published"
     reward_kind: Literal["jct", "fair"] = "jct"
     n_tenants: int = 1
     nodes_per_rack: int | None = None   # graph topology granularity
@@ -120,6 +124,15 @@ PPO_CNN_PHILLY512 = _register(ExperimentConfig(
     name="ppo-cnn-philly512", algo="ppo", n_nodes=64, gpus_per_node=8,
     trace="philly-proxy", n_envs=8, obs_kind="grid", window_jobs=128,
     queue_len=16, horizon=1024))
+
+# Config 2's cluster, trace, reward and PPO numbers under a token policy:
+# one token per node and per job of the window through sparse-expert
+# transformer blocks at a public model's widths (models.trunk; one chip's
+# share of a 16-chip expert layout). The policy reads the whole backlog,
+# not its queue_len oldest jobs; the action space is still the queue
+# view's. Train on a CPU host with --trunk tiny.
+PPO_TRINITY_PHILLY512 = _register(dataclasses.replace(
+    PPO_CNN_PHILLY512, name="ppo-trinity-philly512", obs_kind="tokens"))
 
 # 3. A2C multi-actor on Alibaba PAI trace, multi-tenant fairness reward.
 # Same proxy arrangement as config 2 (PAI-statistics preset).
@@ -307,6 +320,7 @@ def repro_tuple(cfg: ExperimentConfig, ckpt_dir: str | None = None,
             "n_nodes": cfg.n_nodes, "gpus_per_node": cfg.gpus_per_node,
             "window_jobs": cfg.window_jobs, "queue_len": cfg.queue_len,
             "horizon": cfg.horizon, "obs_kind": cfg.obs_kind,
+            "trunk": cfg.trunk,
             "drain_frac": cfg.drain_frac, "faults": cfg.faults,
             "domains": cfg.domains,
             "ckpt_dir": ckpt_dir, "ckpt_step": ckpt_step}

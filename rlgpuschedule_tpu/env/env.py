@@ -32,7 +32,7 @@ from . import rewards as reward_lib
 class EnvParams:
     """Static env configuration (hashable; closed over by jit)."""
     sim: SimParams
-    obs_kind: Literal["flat", "grid", "graph"] = "flat"
+    obs_kind: Literal["flat", "grid", "graph", "tokens"] = "flat"
     reward_kind: Literal["jct", "fair"] = "jct"
     n_tenants: int = 1
     time_scale: float = 600.0     # normalizes times in observations
@@ -85,6 +85,8 @@ class EnvParams:
             return (s.n_nodes + 4 * k + 4 * r + 2 + n_health + n_geom,)
         if self.obs_kind == "grid":
             return (s.n_nodes + k + r, s.gpus_per_node, 2)
+        if self.obs_kind == "tokens":
+            return (s.n_nodes + s.max_jobs, obs_lib.TOKEN_FEATURES)
         return (s.n_nodes + k + r, obs_lib.GRAPH_FEATURES)
 
 
@@ -105,6 +107,9 @@ def build_obs(params: EnvParams, sim: SimState, trace: Trace,
               queue: jax.Array | None = None,
               run_queue: jax.Array | None = None,
               faults: FaultSchedule | None = None) -> jax.Array:
+    if params.obs_kind == "tokens":     # node rows carry health, geometry
+        return obs_lib.token_obs(params.sim, sim, trace, params.time_scale,
+                                 queue, run_queue, faults)
     fn = {"flat": obs_lib.flat_obs, "grid": obs_lib.grid_obs,
           "graph": obs_lib.graph_obs}[params.obs_kind]
     obs = fn(params.sim, sim, trace, params.time_scale, queue, run_queue)

@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from ..sim.core import (SimParams, SimState, Trace, pending_queue,
-                        running_queue, RUNNING, in_system, utilization)
+                        running_queue, PENDING, RUNNING, in_system,
+                        utilization)
 from ..sim.faults import FaultSchedule, node_up
 
 
@@ -239,3 +240,72 @@ def graph_obs(params: SimParams, state: SimState, trace: Trace,
         parts.append(jnp.stack([rf[:, 0], rf[:, 1], rf[:, 2],
                                 rzeros, rzeros], axis=1))
     return jnp.concatenate(parts, axis=0)                      # [N+K+R,5]
+
+
+TOKEN_FEATURES = 11
+
+
+def token_obs(params: SimParams, state: SimState, trace: Trace,
+              time_scale: float, queue: jax.Array | None = None,
+              run_queue: jax.Array | None = None,
+              faults: FaultSchedule | None = None) -> jax.Array:
+    """One token per cluster node and per job of the window,
+    ``[N + J, 11]``: the N node rows first, then the J job rows in
+    job-index order (the window's submit order), so a causal trunk reads
+    the cluster before the backlog and older jobs before newer ones.
+
+    node rows: [free fraction, used fraction, mean remaining of what runs
+               there, health, geometry, 0, 0, 0, 1, 0, 1]
+    job rows:  [demand / capacity, waited, service, remaining, pending,
+               running, in the K-slot queue view, its slot / K, 0, 1,
+               valid]
+
+    Times are tanh-squashed by ``time_scale``. A job is ``valid`` while
+    it is in the system (pending or running): one that has not arrived
+    yet is not the scheduler's to know, one that is done is nobody's, and
+    both rows are all zeros. The last column is what the trunk masks keys
+    and pools by. The action space is the queue view's (K slots and a
+    no-op), so each job row says whether it is in the view and where.
+
+    Job tokens built this way are nearly collinear (the flags they share
+    outweigh what tells them apart), so at seeded weights every token of
+    a row picks the same few experts: PERF.md section 6, PR 30, has what
+    that does to a chip's share of the load, and what a position code
+    among the features did about it (tried, measured, not kept).
+
+    Dense arithmetic over ``[J]``, ``[J, N]`` and ``[J, K]`` only: the
+    slot of a job is a compare of the job index against the K queue
+    entries and a masked sum, not a scatter (PERF.md section 6, PR 28);
+    the queue itself is the one ``pending_queue`` the env step already
+    builds for the mask."""
+    N, G, K, J = (params.n_nodes, params.gpus_per_node, params.queue_len,
+                  params.max_jobs)
+    squash = lambda t: jnp.tanh(t / time_scale)
+    free_frac = state.free.astype(jnp.float32) / G
+    used = (G - state.free).astype(jnp.float32)
+    running = state.status == RUNNING
+    pending = state.status == PENDING
+    rem_n = jnp.einsum("jn,j->n", state.alloc.astype(jnp.float32),
+                       running * squash(state.remaining))
+    zeros, ones = jnp.zeros((N,), jnp.float32), jnp.ones((N,), jnp.float32)
+    nodes = jnp.stack([free_frac, 1.0 - free_frac,
+                       rem_n / jnp.maximum(used, 1.0),
+                       node_health(params, state, faults),
+                       node_geometry(params, faults),
+                       zeros, zeros, zeros, ones, zeros, ones], axis=1)
+
+    if queue is None:
+        queue = pending_queue(params, state)
+    at = queue[None, :] == jnp.arange(J, dtype=queue.dtype)[:, None]  # [J,K]
+    in_view = jnp.any(at, axis=1)
+    slot = jnp.sum(at * jnp.arange(K, dtype=jnp.float32)[None, :], axis=1)
+    valid = pending | running
+    f = lambda x: jnp.where(valid, x, 0.0).astype(jnp.float32)
+    # where, not a product: a padding row's submit is +inf
+    jobs = jnp.stack([
+        f(trace.gpus.astype(jnp.float32) / params.capacity),
+        f(squash(jnp.where(valid, state.clock - trace.submit, 0.0))),
+        f(squash(trace.duration)), f(squash(state.remaining)),
+        f(pending), f(running), f(in_view), f(slot / K),
+        jnp.zeros((J,), jnp.float32), f(1.0), f(1.0)], axis=1)
+    return jnp.concatenate([nodes, jobs], axis=0)               # [N+J,11]

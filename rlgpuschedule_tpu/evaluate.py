@@ -56,10 +56,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--queue-len", type=int, default=None)
     p.add_argument("--horizon", type=int, default=None)
     p.add_argument("--obs-kind", default=None,
-                   choices=["flat", "grid", "graph"],
+                   choices=["flat", "grid", "graph", "tokens"],
                    help="must match the training run when restoring a "
                         "checkpoint (same contract as the cluster-shape "
                         "overrides)")
+    p.add_argument("--trunk", default=None, choices=["published", "tiny"],
+                   help="obs-kind tokens: the trunk sizes the checkpoint "
+                        "was trained with (train --trunk)")
     p.add_argument("--drain-frac", type=float, default=None,
                    help="evaluate on backlog-drain copies of this fraction "
                         "of the windows (all jobs at t=0) — the regime the "
@@ -251,7 +254,7 @@ def main(argv: list[str] | None = None) -> dict:
              "gpus_per_node": args.gpus_per_node,
              "window_jobs": args.window_jobs, "queue_len": args.queue_len,
              "horizon": args.horizon, "obs_kind": args.obs_kind,
-             "drain_frac": args.drain_frac,
+             "trunk": args.trunk, "drain_frac": args.drain_frac,
              "faults": args.faults,
              "domains": args.domains}.items() if v is not None}
     cfg = dataclasses.replace(cfg, **over)

@@ -158,7 +158,7 @@ def build_stack(cfg: ExperimentConfig):
     net = make_policy(cfg.obs_kind, env_params.n_actions,
                       n_cluster_nodes=cfg.n_nodes, queue_len=cfg.queue_len,
                       n_placements=cfg.n_placements,
-                      preempt_len=cfg.preempt_len)
+                      preempt_len=cfg.preempt_len, trunk=cfg.trunk)
     if cfg.obs_kind == "graph":
         adj = jnp.asarray(build_adjacency(cfg.n_nodes, cfg.queue_len,
                                           cfg.nodes_per_rack,
@@ -168,6 +168,17 @@ def build_stack(cfg: ExperimentConfig):
     else:
         apply_fn = lambda p, obs, mask: net.apply(p, obs, mask)
         extra = ()
+    if cfg.obs_kind == "tokens":
+        # the same forward with the expert layers' counters read out:
+        # the update's loss takes this one (algos.ppo.ppo_loss), so the
+        # counters ride the iteration's metrics at no extra pass
+        from .models.trunk import COUNTERS, read_counters
+
+        def counted(p, obs, mask):
+            out, sown = net.apply(p, obs, mask, mutable=[COUNTERS])
+            return out, read_counters(sown[COUNTERS])
+
+        apply_fn.counted = counted
     return env_params, windows, traces, net, apply_fn, extra, source
 
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 from .encoders import MLPEncoder, CNNEncoder, GNNEncoder
+from .trunk import TRUNKS, TokenTrunk
 
 NEG_INF = -1e9
 
@@ -85,8 +86,10 @@ class GNNActorCritic(nn.Module):
 
 def make_policy(obs_kind: str, n_actions: int, *, n_cluster_nodes: int = 0,
                 queue_len: int = 0, n_placements: int = 1,
-                preempt_len: int = 0, dtype=jnp.bfloat16) -> nn.Module:
-    """Encoder-selection factory matching EnvParams.obs_kind."""
+                preempt_len: int = 0, trunk: str = "published",
+                dtype=jnp.bfloat16) -> nn.Module:
+    """Encoder-selection factory matching EnvParams.obs_kind. ``trunk``
+    names the token trunk's sizes (``models.trunk.TRUNKS``)."""
     if obs_kind == "flat":
         return ActorCritic(MLPEncoder(dtype=dtype), n_actions)
     if obs_kind == "grid":
@@ -94,4 +97,7 @@ def make_policy(obs_kind: str, n_actions: int, *, n_cluster_nodes: int = 0,
     if obs_kind == "graph":
         return GNNActorCritic(GNNEncoder(dtype=dtype), n_cluster_nodes,
                               queue_len, n_placements, preempt_len)
-    raise ValueError(f"unknown obs_kind {obs_kind!r}")
+    if obs_kind == "tokens":
+        return ActorCritic(TokenTrunk(TRUNKS[trunk], dtype=dtype), n_actions)
+    raise ValueError(f"unknown obs_kind {obs_kind!r} (have flat, grid, "
+                     f"graph, tokens)")

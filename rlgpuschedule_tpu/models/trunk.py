@@ -1,0 +1,419 @@
+"""Token trunk (L3): sparse-expert transformer blocks over one token per
+cluster node and per job of the window (``env.obs.token_obs``).
+
+The block is the public ``afmoe`` family's (arcee-ai Trinity; widths and
+``layer_types`` as the family's ``config.json`` keys them, the rest after
+``transformers``' ``models/afmoe``). Per layer ``i``, ``x`` ``[T, d]``::
+
+    h = x + post_attn_norm(Attn_i(input_norm(x)))
+    y = h + post_mlp_norm(MLP_i(pre_mlp_norm(h)))          all RMSNorm
+
+``Attn``: no-bias q/k/v projections to ``Hq``/``Hkv``/``Hkv`` heads of
+``D``; RMSNorm over the head dim on q and k; RoPE (position = token
+index) on q and k ONLY where ``layer_types[i]`` is ``sliding_attention``;
+causal mask, cut to the last ``sliding_window`` positions on sliding
+layers; keys of ``valid = 0`` tokens masked; softmax(q k^T / sqrt(D)) v
+with each KV head serving ``Hq/Hkv`` query heads; the result times
+``sigmoid(gate_proj(input))``; ``o_proj``. ``MLP`` of the leading dense
+layers: ``down(silu(gate(x)) * up(x))``. ``MLP`` of the rest:
+``shared(x) + sum_e w_e expert_e(x)`` with ``s = sigmoid(router(x))`` in
+float32 over ALL published experts, selection = top-k of ``s +
+expert_bias`` (a ``bias`` leaf no gradient reaches: zeros), ``w`` = the
+selected ``s`` over their sum over all k selected (``route_norm``) times
+``route_scale``. After the last layer: final RMSNorm, mean over valid
+tokens. Where a policy departs from the language model: no token ids, so
+a linear map of each token's features (times sqrt(d), as the family's
+``mup_enabled`` scales its embedding) stands for the embedding, and
+``ActorCritic``'s heads for the output head.
+
+**The chip's share.** An expert layer is TOLD which experts it holds
+(``experts_held = (first, count)``), routes over all ``num_experts``
+and adds its own experts' terms only; nothing stands in for the absent
+ones (``(0, num_experts)`` is the whole layer). Token-choice and
+dropless: every assignment to a held expert is computed. Assignments are
+sorted by expert (non-held ones last) and each projection is ONE grouped
+matrix product (``jax.lax.ragged_dot``: on a TPU XLA's own grouped-matmul
+kernel, whose work follows the group sizes). The sorted buffer has a row
+for EVERY assignment (``tokens x k``): the worst case, every token's k
+choices held here, fits by construction, so ``moe_dropped_assignments``
+is 0 whatever the router does, and a row's result cannot depend on which
+rows share its batch.
+
+**Parameter leaves** end in ``kernel``, ``scale`` or ``bias``. The held
+experts' weights are three leaves a layer, laid out so that a kernel's
+fan-in is the expert's own: ``experts_gate``/``experts_up``
+``[d, count*f]`` and ``experts_down`` ``[f, count*d]``, viewed as
+``[count, d, f]``/``[count, f, d]`` in the layer; their last axis is the
+one ``parallel.sharding`` puts on the ``model`` mesh axis.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..obs import scopes
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+COUNTERS = "counters"       # the flax collection the expert layers sow
+# Rows of a batch that pass a block together: their activations (attention
+# scores, sorted expert buffers) are what is live at once. Memory, not
+# mathematics: rows do not see each other. At the published widths 4 rows
+# leave the update 6.5 GB of temporaries (2 rows 5.8, all 16 of a
+# minibatch 13.1; compiler estimates, PERF.md section 4): the most that
+# fits one chip beside TWO train states (a traced benchmark run keeps a
+# copy), and half as many passes of a block, so half as many device
+# operations in an iteration, as 2 rows.
+ROW_BLOCK = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class TrunkConfig:
+    """One trunk: the defaults are the published widths (Trinity-Mini's
+    ``config.json``) at this repo's cut: the first ``num_hidden_layers``
+    of ``layer_types`` (one leading dense layer, then one whole 3 : 1
+    period), experts 0-7 of 128 held (one chip of 16)."""
+    hidden_size: int = 2048
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    route_scale: float = 2.826
+    route_norm: bool = True
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    num_dense_layers: int = 1
+    layer_types: tuple[str, ...] = (SLIDING, SLIDING, SLIDING, FULL, SLIDING)
+    experts_held: tuple[int, int] = (0, 8)      # (first, count)
+
+    def __post_init__(self):
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(f"experts_held={self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("query heads must be a multiple of KV heads")
+        if set(self.layer_types) - {SLIDING, FULL}:
+            raise ValueError(f"unknown layer type in {self.layer_types}")
+
+    @property
+    def num_hidden_layers(self) -> int:
+        return len(self.layer_types)
+
+
+TRUNKS: dict[str, TrunkConfig] = {
+    "published": TrunkConfig(),
+    # the CPU tests' and rehearsals' shape: the window (8) is shorter
+    # than any observation, so the sliding mask bites
+    "tiny": TrunkConfig(hidden_size=64, num_attention_heads=2,
+                        num_key_value_heads=1, head_dim=32,
+                        sliding_window=8, intermediate_size=96,
+                        moe_intermediate_size=32, num_experts=8,
+                        num_experts_per_tok=2, experts_held=(0, 2)),
+}
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
+class Kernel(nn.Module):
+    """One ``kernel`` leaf of the given shape, variance 1 / shape[0]."""
+    shape: tuple[int, ...]
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape, jnp.float32)
+
+
+def rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary embedding over ``x[..., T, H, D]``, position = token index,
+    halves rotated (the ``rotate_half`` convention), in float32."""
+    T, D = x.shape[-3], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :D // 2], x32[..., D // 2:]
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * cos + rot * sin).astype(x.dtype)
+
+
+def attention_mask(valid: jax.Array, window: int | None) -> jax.Array:
+    """bool[..., T, T]: query q sees key k iff k <= q, q - k < window (on
+    a sliding layer) and key k is a valid token."""
+    T = valid.shape[-1]
+    q = jnp.arange(T)[:, None]
+    k = jnp.arange(T)[None, :]
+    seen = k <= q
+    if window is not None:
+        seen &= (q - k) < window
+    return seen & valid[..., None, :]
+
+
+def attend(q, k, v, valid, window):
+    """q ``[b, T, Hkv, G, D]``, k/v ``[b, T, Hkv, D]``, valid ``[b, T]``
+    -> ``[b, T, Hkv, G, D]``; scores and softmax in float32."""
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = s * (1.0 / math.sqrt(q.shape[-1]))
+    mask = attention_mask(valid, window)[:, None, None]
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
+
+
+class Attention(nn.Module):
+    cfg: TrunkConfig
+    sliding: bool
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array, valid: jax.Array) -> jax.Array:
+        c = self.cfg
+        B, T, _ = x.shape
+        Hq, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        proj = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
+                                        name=name)(x)
+        q = proj(Hq * D, "q_proj").reshape(B, T, Hq, D)
+        k = proj(Hkv * D, "k_proj").reshape(B, T, Hkv, D)
+        v = proj(Hkv * D, "v_proj").reshape(B, T, Hkv, D)
+        gate = proj(Hq * D, "gate_proj")
+        q = RMSNorm(c.rms_norm_eps, self.dtype, name="q_norm")(q)
+        k = RMSNorm(c.rms_norm_eps, self.dtype, name="k_norm")(k)
+        if self.sliding:
+            q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
+        with jax.named_scope(scopes.ATTN_SLIDING if self.sliding
+                             else scopes.ATTN_FULL):
+            out = attend(q.reshape(B, T, Hkv, Hq // Hkv, D), k, v, valid,
+                         c.sliding_window if self.sliding else None)
+        out = out.reshape(B, T, Hq * D) * jax.nn.sigmoid(gate)
+        return nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
+                        name="o_proj")(out)
+
+
+class GatedMLP(nn.Module):
+    width: int
+    out: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        dense = lambda n, name: nn.Dense(n, use_bias=False,
+                                         dtype=self.dtype, name=name)
+        h = nn.silu(dense(self.width, "gate")(x)) * dense(self.width,
+                                                          "up")(x)
+        return dense(self.out, "down")(h)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def take_rows(x, index, inverse, fan: int):
+    """``x[index]`` where ``index`` holds every row of ``x`` exactly
+    ``fan`` times and ``inverse`` lists, row by row of ``x``, where its
+    ``fan`` copies went: the backward pass is then a gather and a sum
+    over ``fan``, not a scatter-add."""
+    return x[index]
+
+
+def _take_rows_fwd(x, index, inverse, fan):
+    return x[index], inverse
+
+
+def _take_rows_bwd(fan, inverse, g):
+    back = g[inverse].reshape(-1, fan, g.shape[-1])
+    return back.sum(axis=1), None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def routed_experts(x, gid, weight, w_gate, w_up, w_down):
+    """The held experts' part of an expert layer for tokens ``x[n, d]``:
+    ``gid[n * k]`` is each assignment's expert (0 .. count - 1 held here,
+    ``count`` = not held), ``weight[n, k]`` its router weight (0 where not
+    held), the kernels ``[count, d, f]`` / ``[count, f, d]``. Returns the
+    weighted sum ``[n, d]`` and the held experts' loads ``[count]``.
+
+    Assignments are sorted by expert, non-held ones last; the sorted
+    buffer has a row for EVERY assignment, so none can be dropped. Each
+    projection is one grouped product over the held groups. Rows behind
+    the last group belong to no expert here: a grouped product leaves
+    them unwritten, so they are zeroed on the way in (which zeroes their
+    gradient too) and on the way out."""
+    n, d = x.shape
+    k = weight.shape[-1]
+    count = w_gate.shape[0]
+    A = n * k
+    order = jnp.argsort(gid, stable=True).astype(jnp.int32)
+    slot_of = jnp.zeros((A,), jnp.int32).at[order].set(
+        jnp.arange(A, dtype=jnp.int32), unique_indices=True)
+    sizes = jnp.sum(gid[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    in_group = (jnp.arange(A) < jnp.sum(sizes))[:, None]
+    grouped = lambda a, b: jax.lax.ragged_dot(
+        a, b, sizes, preferred_element_type=x.dtype)
+    xs = jnp.where(in_group, take_rows(x, order // k, slot_of, k), 0)
+    hid = nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+    ys = jnp.where(in_group, grouped(hid, w_down), 0)           # [A, d]
+    back = take_rows(ys, slot_of, order, 1).reshape(n, k, d)
+    return jnp.sum(back * weight.astype(x.dtype)[..., None], axis=1), sizes
+
+
+class ExpertLayer(nn.Module):
+    """``shared(x) + sum over this chip's experts`` (module docstring),
+    for ``x[B, T, d]``; sows each held expert's load."""
+    cfg: TrunkConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        c = self.cfg
+        d, f, k = c.hidden_size, c.moe_intermediate_size, \
+            c.num_experts_per_tok
+        first, count = c.experts_held
+        B, T, _ = x.shape
+        router = Kernel((d, c.num_experts), name="router")()
+        bias = self.param("bias", nn.initializers.zeros, (c.num_experts,),
+                          jnp.float32)
+        with jax.named_scope(scopes.MOE_ROUTE):
+            scores = jax.nn.sigmoid(jnp.dot(
+                x.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST))           # [B,T,E]
+            _, idx = jax.lax.top_k(
+                jax.lax.stop_gradient(scores) + bias, k)        # [B,T,k]
+            w = jnp.take_along_axis(scores, idx, axis=-1)
+            if c.route_norm:
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
+            local = idx - first
+            held = (local >= 0) & (local < count)
+            w = jnp.where(held, w * c.route_scale, 0.0)
+            # non-held assignments sort behind every held expert's
+            gid = jnp.where(held, local, count)
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            view = lambda name, i, o: Kernel((i, count * o), name=name)() \
+                .astype(self.dtype).reshape(i, count, o).transpose(1, 0, 2)
+            kernels = (view("experts_gate", d, f), view("experts_up", d, f),
+                       view("experts_down", f, d))
+            routed, sizes = routed_experts(
+                x.reshape(B * T, d).astype(self.dtype), gid.reshape(-1),
+                w.reshape(-1, k), *kernels)
+            routed = routed.reshape(B, T, d)
+        with jax.named_scope(scopes.MOE_SHARED):
+            shared = GatedMLP(f, d, self.dtype, name="shared")(x)
+        if not self.is_initializing():      # init's tree is params only
+            self.sow(COUNTERS, "held", jnp.sum(held, dtype=jnp.int32))
+            self.sow(COUNTERS, "load", sizes)
+        return shared + routed
+
+
+class Block(nn.Module):
+    cfg: TrunkConfig
+    index: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array, valid: jax.Array) -> jax.Array:
+        c = self.cfg
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
+        with jax.named_scope(scopes.TRUNK_ATTN):
+            a = Attention(c, c.layer_types[self.index] == SLIDING,
+                          self.dtype, name="attn")(norm("input_norm")(x),
+                                                   valid)
+            h = x + norm("post_attn_norm")(a)
+        z = norm("pre_mlp_norm")(h)
+        if self.index < c.num_dense_layers:
+            with jax.named_scope(scopes.TRUNK_DENSE_MLP):
+                m = GatedMLP(c.intermediate_size, c.hidden_size, self.dtype,
+                             name="mlp")(z)
+        else:
+            m = ExpertLayer(c, self.dtype, name="moe")(z)
+        return h + norm("post_mlp_norm")(m)
+
+
+class TokenTrunk(nn.Module):
+    """``obs[..., T, F]`` (last feature: ``valid``) -> float32 ``[..., d]``.
+    Each block takes the batch ``ROW_BLOCK`` rows at a time and is
+    rematerialised in the backward pass: what a minibatch keeps is each
+    block's input, and what is live is one group of rows' activations."""
+    cfg: TrunkConfig = TrunkConfig()
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, obs: jax.Array) -> jax.Array:
+        c = self.cfg
+        with jax.named_scope(scopes.TRUNK):
+            lead = obs.shape[:-2]
+            obs = obs.reshape(-1, *obs.shape[-2:])
+            B, T, _ = obs.shape
+            valid = obs[..., -1] > 0.5
+            with jax.named_scope(scopes.TRUNK_EMBED):
+                x = nn.Dense(c.hidden_size, use_bias=False,
+                             dtype=self.dtype, name="embed")(
+                    obs.astype(self.dtype))
+                x = x * jnp.asarray(math.sqrt(c.hidden_size), self.dtype)
+            r = max(b for b in range(1, min(ROW_BLOCK, B) + 1)
+                    if B % b == 0)
+            groups = lambda a: a.reshape(B // r, r, *a.shape[1:])
+            over_groups = nn.scan(
+                lambda block, carry, xv: (carry, block(*xv)),
+                variable_broadcast="params",
+                variable_axes={COUNTERS: 0, "intermediates": 0},
+                split_rngs={"params": False})
+            for i in range(c.num_hidden_layers):
+                # inside a scan nothing can merge the recomputation with
+                # the forward pass, so the barriers against it are left out
+                block = nn.remat(Block, prevent_cse=False)(
+                    c, i, self.dtype, name=f"layer_{i}")
+                _, x = over_groups(block, None, (groups(x), groups(valid)))
+                x = x.reshape(B, T, c.hidden_size)
+            with jax.named_scope(scopes.TRUNK_POOL):
+                x = RMSNorm(c.rms_norm_eps, jnp.float32,
+                            name="final_norm")(x)
+                m = valid[..., None].astype(jnp.float32)
+                pooled = jnp.sum(x * m, axis=-2) / jnp.maximum(
+                    jnp.sum(m, axis=-2), 1.0)
+            return pooled.reshape(*lead, c.hidden_size)
+
+
+def read_counters(collection: dict) -> dict:
+    """The three counters of one forward pass from what the expert layers
+    sowed (each layer: assignments to held experts, and every held
+    expert's load, one entry a group of rows): assignments held and
+    assignments dropped, each summed over the layers; the fullest held
+    expert's load over the mean held load, the largest of the layers'."""
+    held, ratio, computed = [], [], []
+    paths, _ = jax.tree_util.tree_flatten_with_path(collection)
+    for path, leaf in paths:
+        name = [p.key for p in path if hasattr(p, "key")][-1]
+        if name == "held":
+            held.append(jnp.sum(leaf))
+        else:
+            load = jnp.sum(leaf.reshape(-1, leaf.shape[-1]), axis=0)
+            computed.append(jnp.sum(load))
+            ratio.append(jnp.max(load) / jnp.maximum(jnp.mean(
+                load.astype(jnp.float32)), 1.0 / load.shape[0]))
+    held, computed = sum(held), sum(computed)
+    return {"moe_assignments_held": held.astype(jnp.float32),
+            "moe_expert_load_max_over_mean": jnp.max(jnp.stack(ratio)),
+            "moe_dropped_assignments": (held - computed).astype(
+                jnp.float32)}

@@ -29,6 +29,21 @@ A reader matches a scope as a path component, so the ``vmap(...)`` and
       shuffle               permutation and minibatch gather
       loss_grad             value_and_grad of the loss
       apply                 clipping and the optimizer step
+
+A token policy (``models.trunk``) names its own layers wherever its
+forward pass is traced, so the same subtree hangs under both
+``rollout/policy_forward`` and ``update/loss_grad`` (``TRUNK_TREE``)::
+
+    trunk                   models.trunk.TokenTrunk
+      trunk_embed           features -> hidden
+      trunk_attn            norms, projections, gate, o_proj
+        attn_sliding        scores, mask, softmax, values (window + RoPE)
+        attn_full           the same on a full-attention layer
+      trunk_dense_mlp       a leading dense layer's MLP
+      moe_route             router scores, top-k, the sort by expert
+      moe_experts           dispatch, the grouped products, combine
+      moe_shared            the shared expert
+      trunk_pool            final norm, mean over valid tokens
 """
 from __future__ import annotations
 
@@ -53,6 +68,17 @@ SHUFFLE = "shuffle"
 LOSS_GRAD = "loss_grad"
 APPLY = "apply"
 
+TRUNK = "trunk"
+TRUNK_EMBED = "trunk_embed"
+TRUNK_ATTN = "trunk_attn"
+ATTN_SLIDING = "attn_sliding"
+ATTN_FULL = "attn_full"
+TRUNK_DENSE_MLP = "trunk_dense_mlp"
+MOE_ROUTE = "moe_route"
+MOE_EXPERTS = "moe_experts"
+MOE_SHARED = "moe_shared"
+TRUNK_POOL = "trunk_pool"
+
 # every scope as its path from the program's top, parents first
 TREE = (
     (ROLLOUT,),
@@ -72,6 +98,21 @@ TREE = (
     (UPDATE, LOSS_GRAD),
     (UPDATE, APPLY),
 )
+
+# a token policy's scopes, as paths from wherever its forward is traced
+TRUNK_TREE = (
+    (TRUNK,),
+    (TRUNK, TRUNK_EMBED),
+    (TRUNK, TRUNK_ATTN),
+    (TRUNK, TRUNK_ATTN, ATTN_SLIDING),
+    (TRUNK, TRUNK_ATTN, ATTN_FULL),
+    (TRUNK, TRUNK_DENSE_MLP),
+    (TRUNK, MOE_ROUTE),
+    (TRUNK, MOE_EXPERTS),
+    (TRUNK, MOE_SHARED),
+    (TRUNK, TRUNK_POOL),
+)
+TRUNK_PARENTS = ((ROLLOUT, POLICY_FORWARD), (UPDATE, LOSS_GRAD))
 
 # host side: the profiler's trace shows span "step" as "rlsched:step"
 ANNOTATION_PREFIX = "rlsched:"

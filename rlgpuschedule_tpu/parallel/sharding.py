@@ -181,11 +181,24 @@ GRID_RULES: Rules = [
 GRAPH_RULES: Rules = FLAT_RULES
 HIER_RULES: Rules = FLAT_RULES
 
+# Token trunk (models.trunk): the held experts' three kernels a layer are
+# [d, count*f] / [f, count*d], experts contiguous along the LAST axis, so
+# that axis on ``model`` gives each shard whole experts (expert parallel);
+# everything else (attention, router, shared expert, norms, heads) is
+# replicated, as each chip of the stated deployment holds it whole.
+TOKENS_RULES: Rules = [
+    (r"experts_(gate|up|down)/kernel$", P(None, MODEL_AXIS)),
+    (r"(^|/)kernel$", P()),
+    (r"(^|/)(scale|bias)$", P()),
+    (r".*", P()),
+]
+
 RULE_TABLES: dict[str, Rules] = {
     "flat": FLAT_RULES,
     "grid": GRID_RULES,
     "graph": GRAPH_RULES,
     "hier": HIER_RULES,
+    "tokens": TOKENS_RULES,
 }
 
 

@@ -61,7 +61,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--queue-len", type=int, default=None)
     p.add_argument("--horizon", type=int, default=None)
     p.add_argument("--obs-kind", default=None,
-                   choices=["flat", "grid", "graph"])
+                   choices=["flat", "grid", "graph", "tokens"])
+    p.add_argument("--trunk", default=None, choices=["published", "tiny"],
+                   help="obs-kind tokens: the trunk sizes the checkpoint "
+                        "was trained with (train --trunk)")
     p.add_argument("--trace-load", type=float, default=None,
                    help="proxy traces: offered load of the validation "
                         "stream — match the TEST stream's load (round-5 "
@@ -83,7 +86,7 @@ def main(argv: list[str] | None = None) -> dict:
              "gpus_per_node": args.gpus_per_node,
              "window_jobs": args.window_jobs, "queue_len": args.queue_len,
              "horizon": args.horizon, "obs_kind": args.obs_kind,
-             "trace_load": args.trace_load}.items()
+             "trunk": args.trunk, "trace_load": args.trace_load}.items()
             if v is not None}
     cfg = dataclasses.replace(CONFIGS[args.config], **over)
     if cfg.trace in ("philly", "pai"):

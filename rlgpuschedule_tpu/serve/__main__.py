@@ -81,7 +81,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--queue-len", type=int, default=None)
     p.add_argument("--horizon", type=int, default=None)
     p.add_argument("--obs-kind", default=None,
-                   choices=["flat", "grid", "graph"])
+                   choices=["flat", "grid", "graph", "tokens"])
+    p.add_argument("--trunk", default=None, choices=["published", "tiny"],
+                   help="obs-kind tokens: the trunk sizes the checkpoint "
+                        "was trained with (train --trunk)")
     # bench mode
     p.add_argument("--bench", action="store_true",
                    help="latency bench: deterministic request stream "
@@ -392,7 +395,8 @@ def main(argv: "list[str] | None" = None) -> dict:
              "gpus_per_node": args.gpus_per_node,
              "window_jobs": args.window_jobs,
              "queue_len": args.queue_len, "horizon": args.horizon,
-             "obs_kind": args.obs_kind}.items() if v is not None}
+             "obs_kind": args.obs_kind,
+             "trunk": args.trunk}.items() if v is not None}
     cfg = dataclasses.replace(cfg, **over)
     from ..configs import ModeCombinationError, validate_mode_combination
     try:

@@ -46,10 +46,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "policy's visibility into the backlog)")
     p.add_argument("--horizon", type=int, default=None)
     p.add_argument("--obs-kind", default=None,
-                   choices=["flat", "grid", "graph"],
+                   choices=["flat", "grid", "graph", "tokens"],
                    help="override the preset's observation/encoder family "
                         "(e.g. train config 2's cluster on the flat MLP "
                         "encoder on a CPU host)")
+    p.add_argument("--trunk", default=None, choices=["published", "tiny"],
+                   help="obs-kind tokens: the token trunk's whole set of "
+                        "sizes (models.trunk.TRUNKS): the source model's "
+                        "published widths, or the tiny shape for a CPU "
+                        "host")
     p.add_argument("--trace", default=None,
                    choices=["synthetic", "philly", "pai", "philly-proxy",
                             "pai-proxy"],
@@ -321,6 +326,7 @@ def apply_overrides(cfg: ExperimentConfig,
               "gpus_per_node": args.gpus_per_node,
               "window_jobs": args.window_jobs, "horizon": args.horizon,
               "queue_len": args.queue_len, "obs_kind": args.obs_kind,
+              "trunk": args.trunk,
               "trace": args.trace, "trace_path": args.trace_path,
               "trace_load": args.trace_load,
               "source_jobs": args.source_jobs,

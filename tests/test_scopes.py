@@ -9,6 +9,7 @@ the spans were folded into ``Tracer.phase``.
 """
 import contextlib
 import dataclasses
+import functools
 import glob
 import re
 
@@ -36,7 +37,14 @@ SMALL = {
         CONFIGS["a2c-pai-fair"], n_envs=2, window_jobs=16, horizon=64,
         a2c=A2CConfig(n_steps=8, n_epochs=1, n_minibatches=2)),
 }
+# the token policy (ISSUE 30) at its tiny trunk: the same step, with the
+# trunk's own scopes under both places its forward is traced
+SMALL["tokens"] = dataclasses.replace(
+    CONFIGS["ppo-trinity-philly512"], trunk="tiny", n_envs=2, n_nodes=2,
+    gpus_per_node=4, window_jobs=16, queue_len=4, horizon=64,
+    ppo=PPOConfig(n_steps=8, n_epochs=1, n_minibatches=2))
 SCOPE_NAMES = tuple(path[-1] for path in scopes.TREE)
+TRUNK_SCOPE_NAMES = tuple(path[-1] for path in scopes.TRUNK_TREE)
 
 
 def lower_step(algo: str):
@@ -76,12 +84,46 @@ def test_scope_is_on_the_lowered_train_step(lowered_names, algo, scope):
     assert scope in lowered_names(algo)
 
 
+@pytest.mark.parametrize("scope", SCOPE_NAMES + TRUNK_SCOPE_NAMES)
+def test_scope_is_on_the_token_policys_train_step(lowered_names, scope):
+    assert scope in lowered_names("tokens")
+
+
+@pytest.mark.parametrize("parent", scopes.TRUNK_PARENTS,
+                         ids=lambda p: "/".join(p))
+@pytest.mark.parametrize("path", scopes.TRUNK_TREE,
+                         ids=lambda p: "/".join(p))
+def test_trunk_scope_hangs_under_both_forward_passes(path, parent):
+    """Some operation of the COMPILED step carries ``parent`` and then
+    the trunk scope's own path, in order, in its ``op_name`` (what the
+    benchmark's readers match): the rollout's forward and the update's
+    loss forward (and its transpose) both name the trunk's layers."""
+    want = [*parent, *path]
+
+    def holds(components):
+        it = iter(components)
+        return all(any(c == w for c in it) for w in want)
+
+    assert any(holds([bare(c) for c in name.split("/")])
+               for name in _tokens_op_names())
+
+
+@functools.lru_cache(maxsize=None)
+def _tokens_op_names() -> frozenset:
+    return frozenset(re.findall(
+        r'op_name="([^"]+)"', lower_step("tokens").compile().as_text()))
+
+
 def test_tree_is_parents_first_and_names_are_unique():
     assert len(set(SCOPE_NAMES)) == len(SCOPE_NAMES)
-    seen = set()
-    for path in scopes.TREE:
-        assert path[:-1] == () or path[:-1] in seen
-        seen.add(path)
+    assert len(set(SCOPE_NAMES + TRUNK_SCOPE_NAMES)) == len(
+        SCOPE_NAMES + TRUNK_SCOPE_NAMES)
+    for tree in (scopes.TREE, scopes.TRUNK_TREE):
+        seen = set()
+        for path in tree:
+            assert path[:-1] == () or path[:-1] in seen
+            seen.add(path)
+    assert all(p in scopes.TREE for p in scopes.TRUNK_PARENTS)
 
 
 def strip_metadata(hlo: str) -> str:
@@ -93,20 +135,22 @@ def strip_metadata(hlo: str) -> str:
                   count=1, flags=re.DOTALL)
 
 
-@pytest.mark.parametrize("algo", ["ppo", "a2c"])
+@pytest.mark.parametrize("algo", ["ppo", "a2c", "tokens"])
 def test_scopes_change_no_code(monkeypatch, algo):
     """The compiled step's HLO, metadata stripped, is the same text with
-    this repo's scopes patched to no-ops."""
+    this repo's scopes patched to no-ops (the token policy's: the
+    trunk's scopes too)."""
+    names = SCOPE_NAMES + (TRUNK_SCOPE_NAMES if algo == "tokens" else ())
     scoped = lower_step(algo).compile().as_text()
     real = jax.named_scope          # flax names its modules with it too
     monkeypatch.setattr(
         jax, "named_scope",
-        lambda name: (contextlib.nullcontext() if name in SCOPE_NAMES
+        lambda name: (contextlib.nullcontext() if name in names
                       else real(name)))
     plain = lower_step(algo).compile().as_text()
     op_names = r'op_name="([^"]+)"'
-    assert set(SCOPE_NAMES) <= scope_names_in(scoped, op_names)
-    assert not set(SCOPE_NAMES) & scope_names_in(plain, op_names)
+    assert set(names) <= scope_names_in(scoped, op_names)
+    assert not set(names) & scope_names_in(plain, op_names)
     assert strip_metadata(scoped) == strip_metadata(plain)
 
 

@@ -142,6 +142,24 @@ class TestFamilyCoverage:
         params = net.init(jax.random.PRNGKey(0), obs, mask)
         self._covered(shardlib.HIER_RULES, params)
 
+    def test_tokens(self):
+        """The held experts' three kernels a layer go on ``model`` by
+        their last axis (whole experts a shard); every other leaf is
+        replicated by an explicit rule."""
+        net = make_policy("tokens", 5, trunk="tiny", dtype=jnp.float32)
+        params = net.init(jax.random.PRNGKey(0), jnp.ones((1, 12, 11)),
+                          jnp.ones((1, 5), bool))
+        self._covered(shardlib.TOKENS_RULES, params)
+        specs = shardlib.match_partition_rules(shardlib.TOKENS_RULES, params)
+        flat = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
+        sharded = {n for n, s in zip(shardlib.tree_leaf_names(params), flat)
+                   if s != P()}
+        assert sharded == {
+            f"params/encoder/layer_{i}/moe/experts_{w}/kernel"
+            for i in range(1, 5) for w in ("gate", "up", "down")}
+        assert all(s == P(None, MODEL_AXIS) for s in flat if s != P())
+        assert shardlib.RULE_TABLES["tokens"] is shardlib.TOKENS_RULES
+
     def test_opt_state_shards_with_the_same_table(self):
         # Adam moments mirror param paths, so the SAME rules cover the
         # full TrainState — the zero-extra-configuration property the
@@ -488,3 +506,56 @@ def test_fused_under_mesh_isolated():
     pytest.fail(
         f"isolated fused-under-mesh run failed (rc {res.returncode}, "
         f"attempt {attempt}):\n{res.stdout[-4000:]}\n{res.stderr[-4000:]}")
+
+
+class TestTokensModelAxis:
+    """The token policy's rule table on the unified mesh: at ``model`` = 1
+    the layout is exact replication and the mesh-built step is the plain
+    step bit for bit; at ``model`` = 2 each shard holds whole experts and
+    the step computes the same update."""
+
+    def _cfg(self):
+        import dataclasses
+        from rlgpuschedule_tpu.configs import CONFIGS
+        return dataclasses.replace(
+            CONFIGS["ppo-trinity-philly512"], trunk="tiny", n_envs=2,
+            n_nodes=2, gpus_per_node=4, window_jobs=16, queue_len=4,
+            horizon=64, ppo=PPOConfig(n_steps=8, n_epochs=1,
+                                      n_minibatches=2))
+
+    def _run(self, mesh, iters=2):
+        from rlgpuschedule_tpu.experiment import Experiment
+        exp = Experiment.build(self._cfg(), mesh=mesh)
+        out = exp.run(iterations=iters, log_every=1)
+        return exp, out["history"]
+
+    def test_model_axis_1_is_exact_replication(self):
+        plain, h_plain = self._run(None)
+        mesh = make_unified_mesh(devices=jax.devices()[:1])
+        assert dict(mesh.shape) == {POP_AXIS: 1, DATA_AXIS: 1,
+                                    MODEL_AXIS: 1}
+        meshed, h_mesh = self._run(mesh)
+        for x in jax.tree.leaves(meshed.train_state.params):
+            assert x.sharding.is_fully_replicated
+        for name, a, b in zip(
+                shardlib.tree_leaf_names(plain.train_state.params),
+                jax.tree.leaves(jax.device_get(plain.train_state.params)),
+                jax.tree.leaves(jax.device_get(meshed.train_state.params))):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+        assert [h["total_loss"] for h in h_plain] == [
+            h["total_loss"] for h in h_mesh]
+
+    def test_model_axis_2_holds_whole_experts_a_shard(self):
+        plain, h_plain = self._run(None)
+        mesh = make_unified_mesh(n_model=2, devices=jax.devices()[:2])
+        meshed, h_mesh = self._run(mesh)
+        moe = meshed.train_state.params["params"]["encoder"]["layer_1"]["moe"]
+        gate = moe["experts_gate"]["kernel"]       # [d, 2 experts * f]
+        assert gate.sharding.spec == P(None, MODEL_AXIS)
+        assert {s.data.shape for s in gate.addressable_shards} == {
+            (gate.shape[0], gate.shape[1] // 2)}
+        assert moe["router"]["kernel"].sharding.is_fully_replicated
+        np.testing.assert_allclose(
+            [h["total_loss"] for h in h_mesh],
+            [h["total_loss"] for h in h_plain], rtol=1e-2, atol=1e-3)
+        assert all(h["moe_dropped_assignments"] == 0.0 for h in h_mesh)

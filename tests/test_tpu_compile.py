@@ -18,6 +18,7 @@ the test that overran. The four-chip mesh compile stays a builder's
 rehearsal (CHANGES.md, PR 24).
 """
 import contextlib
+import dataclasses
 import os
 import time
 
@@ -116,3 +117,66 @@ def test_config2_serve_policy_step_compiles(one_chip, exp):
             shapes(exp.train_state.params, one_chip), obs, mask).compile()
     assert "fusion" in compiled.as_text()
     assert device_bytes(compiled) < HBM_BYTES
+
+
+def test_token_policy_update_compiles_and_leaves_room_for_a_second_state(
+        one_chip):
+    """``ppo-trinity-philly512`` at the published widths and the
+    benchmark cell's minibatch (8 steps x 8 envs of 832 tokens, PPO 4 x 4
+    minibatches of 16 rows): the update the train step runs, donated, as
+    the benchmark's traced run jits it alone. That run keeps the
+    program's train state on the device beside the copy the update
+    consumes, so what must fit one chip's 16.9e9 bytes is the update's
+    arguments and temporaries plus a second 12 B a parameter (ISSUE 30).
+    Built from shapes: no parameter is materialised here."""
+    from rlgpuschedule_tpu.algos.ppo import (make_optimizer,
+                                              make_train_state,
+                                              run_ppo_epochs)
+    from rlgpuschedule_tpu.algos.rollout import Transition
+    from rlgpuschedule_tpu.algos.update import make_update_step
+    from rlgpuschedule_tpu.configs import CONFIGS
+    from rlgpuschedule_tpu.models import make_policy
+    from rlgpuschedule_tpu.models.trunk import COUNTERS, read_counters
+
+    cfg = CONFIGS["ppo-trinity-philly512"]
+    ppo = dataclasses.replace(cfg.ppo, n_steps=8)
+    from rlgpuschedule_tpu.env.obs import TOKEN_FEATURES as F
+    T, E, tokens, A = ppo.n_steps, 8, cfg.n_nodes + 768, 129
+    assert cfg.trunk == "published" and tokens == 832
+    net = make_policy(cfg.obs_kind, A, trunk=cfg.trunk)
+    state = jax.eval_shape(lambda: make_train_state(
+        net, jax.random.PRNGKey(0), jnp.zeros((1, tokens, F)),
+        jnp.ones((1, A), bool), make_optimizer(ppo)))
+    n_params = sum(x.size for x in jax.tree.leaves(state.params))
+    assert 401.5e6 < n_params < 402.5e6
+
+    def apply_fn(p, obs, mask):
+        return net.apply(p, obs, mask)
+
+    def counted(p, obs, mask):
+        out, sown = net.apply(p, obs, mask, mutable=[COUNTERS])
+        return out, read_counters(sown[COUNTERS])
+
+    apply_fn.counted = counted          # as experiment.build_stack does
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    f32 = lambda *shape: sds(shape, jnp.float32)
+    tr = Transition(obs=f32(T, E, tokens, F), action=sds((T, E), jnp.int32),
+                    log_prob=f32(T, E), value=f32(T, E), reward=f32(T, E),
+                    done=sds((T, E), jnp.bool_),
+                    mask=sds((T, E, A), jnp.bool_), env_steps_dt=f32(T, E))
+    update = make_update_step(
+        lambda s, tr, adv, ret, key: run_ppo_epochs(
+            apply_fn, ppo, s, tr, adv, ret, key,
+            lambda s, g: s.apply_gradients(grads=g)))
+    with time_limit(300):
+        compiled = update.lower(
+            shapes(state, one_chip), tr, f32(T, E), f32(T, E),
+            shapes(jax.random.PRNGKey(0), one_chip)).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 12 * n_params       # state in place
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + 12 * n_params)
+    assert held < 16.9e9, (held, m)
+    # XLA's own grouped-matmul kernel serves the experts' products
+    assert "ragged-dot" in compiled.as_text()
