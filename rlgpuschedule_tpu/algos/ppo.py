@@ -108,18 +108,18 @@ class PPOMetrics(NamedTuple):
     rho_max: jax.Array
 
 
-# :class:`PPOMetrics` and the expert layers' counters, for a policy whose
-# apply has a ``counted`` form (``models.trunk``); read in the update's own
-# loss forward, over its minibatches:
-# - moe_assignments_held: assignments to the experts held here, a
-#   minibatch, summed over the expert layers (mean over the minibatches);
-# - moe_expert_load_max_over_mean: the fullest held expert's load over the
-#   mean held load (largest of the layers and minibatches);
-# - moe_dropped_assignments: assignments to held experts that were not
-#   computed (summed; 0 by construction: the sorted buffer has a row for
-#   every assignment).
+# :class:`PPOMetrics` and the token trunk's counters (what each counts:
+# ``models.trunk.read_counters``), for a policy whose apply has a
+# ``counted`` form; read in the update's own loss forward, each reduced
+# over the update's minibatches as ``COUNTER_REDUCTIONS`` says (max where
+# it names none): assignments held a minibatch, the fullest held expert's
+# load over the mean, assignments dropped (0 by construction), attention
+# layers on the blocked kernel, and the share of tiles it computes.
 MOE_COUNTERS = ("moe_assignments_held", "moe_expert_load_max_over_mean",
-                "moe_dropped_assignments")
+                "moe_dropped_assignments", "attn_kernel_layers",
+                "attn_tiles_computed_share")
+COUNTER_REDUCTIONS = {"moe_assignments_held": jnp.mean,
+                      "moe_dropped_assignments": jnp.sum}
 MoEPPOMetrics = NamedTuple("MoEPPOMetrics", [
     *((f, jax.Array) for f in PPOMetrics._fields + MOE_COUNTERS)])
 
@@ -350,12 +350,12 @@ def run_ppo_epochs(apply_fn: PolicyApply, config: PPOConfig, state,
         rho_mean=rho_mean, rho_max=rho_max)
     if len(stats) > 6:
         c = stats[6]
-        metrics = MoEPPOMetrics(
-            *metrics,
-            moe_assignments_held=jnp.mean(c["moe_assignments_held"]),
-            moe_expert_load_max_over_mean=jnp.max(
-                c["moe_expert_load_max_over_mean"]),
-            moe_dropped_assignments=jnp.sum(c["moe_dropped_assignments"]))
+        # each counter over the update's minibatches: the mean of the
+        # assignments held, the sum of the dropped, the largest of the
+        # others (a constant of the trace reads itself)
+        metrics = MoEPPOMetrics(*metrics, **{
+            f: COUNTER_REDUCTIONS.get(f, jnp.max)(c[f])
+            for f in MOE_COUNTERS})
     return state, metrics
 
 

@@ -13,9 +13,10 @@ The block is the public ``afmoe`` family's (arcee-ai Trinity; widths and
 index) on q and k ONLY where ``layer_types[i]`` is ``sliding_attention``;
 causal mask, cut to the last ``sliding_window`` positions on sliding
 layers; keys of ``valid = 0`` tokens masked; softmax(q k^T / sqrt(D)) v
-with each KV head serving ``Hq/Hkv`` query heads; the result times
-``sigmoid(gate_proj(input))``; ``o_proj``. ``MLP`` of the leading dense
-layers: ``down(silu(gate(x)) * up(x))``. ``MLP`` of the rest:
+with each KV head serving ``Hq/Hkv`` query heads (two lowerings of it,
+below); the result times ``sigmoid(gate_proj(input))``; ``o_proj``.
+``MLP`` of the leading dense layers: ``down(silu(gate(x)) * up(x))``.
+``MLP`` of the rest:
 ``shared(x) + sum_e w_e expert_e(x)`` with ``s = sigmoid(router(x))`` in
 float32 over ALL published experts, selection = top-k of ``s +
 expert_bias`` (a ``bias`` leaf no gradient reaches: zeros), ``w`` = the
@@ -25,6 +26,17 @@ tokens. Where a policy departs from the language model: no token ids, so
 a linear map of each token's features (times sqrt(d), as the family's
 ``mup_enabled`` scales its embedding) stands for the embedding, and
 ``ActorCritic``'s heads for the output head.
+
+**Two lowerings of the score product**, chosen by
+:func:`attention_path` from what the build can observe and from nothing a
+user sets. On a TPU, at a head size the kernel's tiles take, in a program
+whose every device sees whole rows (one device, or a shard inside
+``parallel.dp.shard_map_train``): ``ops.attention.blocked_attend``, Pallas
+kernels that keep a tile of scores in VMEM, forward and backward, so no
+``[rows, heads, T, T]`` array exists. Everywhere else (another backend, the
+``tiny`` trunk's head size, a GSPMD build over a mesh, where XLA partitions
+no custom call): :func:`attend`, the mathematics written out, and the
+kernel's oracle in the tests. The counters say which ran.
 
 **The chip's share.** An expert layer is TOLD which experts it holds
 (``experts_held = (first, count)``), routes over all ``num_experts``
@@ -60,15 +72,18 @@ from ..obs import scopes
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 COUNTERS = "counters"       # the flax collection the expert layers sow
-# Rows of a batch that pass a block together: their activations (attention
-# scores, sorted expert buffers) are what is live at once. Memory, not
-# mathematics: rows do not see each other. At the published widths 4 rows
-# leave the update 6.5 GB of temporaries (2 rows 5.8, all 16 of a
-# minibatch 13.1; compiler estimates, PERF.md section 4): the most that
-# fits one chip beside TWO train states (a traced benchmark run keeps a
-# copy), and half as many passes of a block, so half as many device
-# operations in an iteration, as 2 rows.
+# Rows of a batch that pass a block together: their activations (the
+# sorted expert buffers, the projections; on the plain path the float32
+# attention scores too, which the kernel path never holds) are what is
+# live at once. Memory, not mathematics: rows do not see each other. At
+# the published widths on the plain path 4 rows leave the update 6.5 GB
+# of temporaries (2 rows 5.8, all 16 of a minibatch 13.1; compiler
+# estimates, PERF.md section 4): the most that fits one chip beside TWO
+# train states (a traced benchmark run keeps a copy), and half as many
+# passes of a block, so half as many device operations in an iteration,
+# as 2 rows.
 ROW_BLOCK = 4
+KERNEL, PLAIN = "kernel", "plain"       # attention_path's two answers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,13 +142,16 @@ class RMSNorm(nn.Module):
     dtype: jnp.dtype
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, gain: float = 1.0) -> jax.Array:
+        """``gain``: a constant factor applied in float32, before the one
+        cast to ``dtype``."""
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (y * scale).astype(self.dtype)
+        y = y * scale
+        return (y if gain == 1.0 else y * gain).astype(self.dtype)
 
 
 class Kernel(nn.Module):
@@ -183,6 +201,18 @@ def attend(q, k, v, valid, window):
     return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
 
 
+def attention_path(backend: str, head_dim: int, mesh_bound: bool) -> str:
+    """Which lowering of the score product a build gets (module
+    docstring): ``KERNEL`` iff the backend is a TPU, the head size is a
+    multiple of the 128 lanes the kernel's tiles are made of, and the
+    program is not one GSPMD partitions over a mesh (``mesh_bound``: the
+    step is traced under ``parallel.sharding.bind_mesh``; a Mosaic
+    custom call is not partitioned for us). Any T fits: the kernel's
+    wrapper pads to whole tiles."""
+    fits = backend == "tpu" and head_dim % 128 == 0 and not mesh_bound
+    return KERNEL if fits else PLAIN
+
+
 class Attention(nn.Module):
     cfg: TrunkConfig
     sliding: bool
@@ -190,23 +220,39 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, valid: jax.Array) -> jax.Array:
+        from ..parallel.sharding import active_mesh
         c = self.cfg
         B, T, _ = x.shape
         Hq, Hkv, D = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        window = c.sliding_window if self.sliding else None
+        kernel = attention_path(jax.default_backend(), D,
+                                active_mesh() is not None) == KERNEL
+        # the kernel takes no scale: 1/sqrt(D) goes onto q inside q_norm,
+        # where q is still float32, so that it costs q no rounding of its
+        # own (rope is linear); attend scales the scores itself
+        pre = 1.0 / math.sqrt(D) if kernel else 1.0
         proj = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                         name=name)(x)
         q = proj(Hq * D, "q_proj").reshape(B, T, Hq, D)
         k = proj(Hkv * D, "k_proj").reshape(B, T, Hkv, D)
         v = proj(Hkv * D, "v_proj").reshape(B, T, Hkv, D)
         gate = proj(Hq * D, "gate_proj")
-        q = RMSNorm(c.rms_norm_eps, self.dtype, name="q_norm")(q)
+        q = RMSNorm(c.rms_norm_eps, self.dtype, name="q_norm")(q, pre)
         k = RMSNorm(c.rms_norm_eps, self.dtype, name="k_norm")(k)
         if self.sliding:
             q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
+        q = q.reshape(B, T, Hkv, Hq // Hkv, D)
+        tiles = 0.0
         with jax.named_scope(scopes.ATTN_SLIDING if self.sliding
                              else scopes.ATTN_FULL):
-            out = attend(q.reshape(B, T, Hkv, Hq // Hkv, D), k, v, valid,
-                         c.sliding_window if self.sliding else None)
+            if kernel:
+                from ..ops import attention     # Pallas: this path only
+                out = attention.blocked_attend(q, k, v, valid, window)
+                tiles = attention.tiles_computed_share(T, window)
+            else:
+                out = attend(q, k, v, valid, window)
+        if not self.is_initializing():      # init's tree is params only
+            self.sow(COUNTERS, "attn_tiles", jnp.float32(tiles))
         out = out.reshape(B, T, Hq * D) * jax.nn.sigmoid(gate)
         return nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
                         name="o_proj")(out)
@@ -396,24 +442,35 @@ class TokenTrunk(nn.Module):
 
 
 def read_counters(collection: dict) -> dict:
-    """The three counters of one forward pass from what the expert layers
-    sowed (each layer: assignments to held experts, and every held
-    expert's load, one entry a group of rows): assignments held and
+    """The counters of one forward pass from what its layers sowed, one
+    entry a group of rows. The expert layers' (each: assignments to held
+    experts, and every held expert's load): assignments held and
     assignments dropped, each summed over the layers; the fullest held
-    expert's load over the mean held load, the largest of the layers'."""
-    held, ratio, computed = [], [], []
+    expert's load over the mean held load, the largest of the layers'.
+    The attention layers' (each: the share of its padded grid's tiles
+    that the kernel computes, a constant of the trace; 0 where the score
+    product took the plain path): the layers on the kernel, and the
+    share's mean over them (0 where none is)."""
+    held, ratio, computed, tiles = [], [], [], []
     paths, _ = jax.tree_util.tree_flatten_with_path(collection)
     for path, leaf in paths:
         name = [p.key for p in path if hasattr(p, "key")][-1]
         if name == "held":
             held.append(jnp.sum(leaf))
+        elif name == "attn_tiles":
+            tiles.append(jnp.max(leaf))
         else:
             load = jnp.sum(leaf.reshape(-1, leaf.shape[-1]), axis=0)
             computed.append(jnp.sum(load))
             ratio.append(jnp.max(load) / jnp.maximum(jnp.mean(
                 load.astype(jnp.float32)), 1.0 / load.shape[0]))
     held, computed = sum(held), sum(computed)
+    tiles = jnp.asarray(tiles, jnp.float32)
+    layers = jnp.sum(tiles > 0, dtype=jnp.float32)
     return {"moe_assignments_held": held.astype(jnp.float32),
             "moe_expert_load_max_over_mean": jnp.max(jnp.stack(ratio)),
             "moe_dropped_assignments": (held - computed).astype(
-                jnp.float32)}
+                jnp.float32),
+            "attn_kernel_layers": layers,
+            "attn_tiles_computed_share": jnp.sum(tiles) / jnp.maximum(
+                layers, 1.0)}

@@ -119,8 +119,30 @@ def test_config2_serve_policy_step_compiles(one_chip, exp):
     assert device_bytes(compiled) < HBM_BYTES
 
 
+def test_blocked_attention_compiles_at_the_cells_call_shape(one_chip):
+    """The token trunk's attention kernels (``ops.attention``), forward
+    and gradient, at one call of the benchmark cell: ``ROW_BLOCK`` rows of
+    832 tokens, 4 KV heads of 8 query heads of 128, bfloat16. Mosaic
+    takes the tiles, and no ``[.., T, T]`` array is left in the program."""
+    from rlgpuschedule_tpu.models.trunk import ROW_BLOCK
+    from rlgpuschedule_tpu.ops import attention
+    b, T, Hkv, G, D = ROW_BLOCK, 832, 4, 8, 128
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    loss = lambda q, k, v, valid: jnp.sum(attention.blocked_attend(
+        q, k, v, valid, None, interpret=False).astype(jnp.float32))
+    with time_limit(30):
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            sds(b, T, Hkv, G, D), sds(b, T, Hkv, D), sds(b, T, Hkv, D),
+            sds(b, T, dtype=jnp.bool_)).compile().as_text()
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+    padded = attention.padded_length(T)
+    assert f"{T},{T}]" not in text and f"{padded},{padded}]" not in text
+
+
+@pytest.mark.parametrize("path", ["plain", "kernel"])
 def test_token_policy_update_compiles_and_leaves_room_for_a_second_state(
-        one_chip):
+        one_chip, monkeypatch, path):
     """``ppo-trinity-philly512`` at the published widths and the
     benchmark cell's minibatch (8 steps x 8 envs of 832 tokens, PPO 4 x 4
     minibatches of 16 rows): the update the train step runs, donated, as
@@ -128,7 +150,13 @@ def test_token_policy_update_compiles_and_leaves_room_for_a_second_state(
     program's train state on the device beside the copy the update
     consumes, so what must fit one chip's 16.9e9 bytes is the update's
     arguments and temporaries plus a second 12 B a parameter (ISSUE 30).
-    Built from shapes: no parameter is materialised here."""
+    Built from shapes: no parameter is materialised here. ``plain`` is
+    what this backend traces; ``kernel`` what a TPU traces (the trunk asks
+    ``jax.default_backend()``, which sees the CPU here, so the test
+    answers for it): the attention kernels inside the whole update, and
+    no score tensor anywhere in it."""
+    if path == "kernel":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from rlgpuschedule_tpu.algos.ppo import (make_optimizer,
                                               make_train_state,
                                               run_ppo_epochs)
@@ -179,4 +207,7 @@ def test_token_policy_update_compiles_and_leaves_room_for_a_second_state(
             + 12 * n_params)
     assert held < 16.9e9, (held, m)
     # XLA's own grouped-matmul kernel serves the experts' products
-    assert "ragged-dot" in compiled.as_text()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    assert ("splash_mqa" in text) == (path == "kernel")
+    assert (f"{tokens},{tokens}]" in text) == (path == "plain")
