@@ -41,13 +41,13 @@ cd "$(dirname "$0")"
 echo "=== lint 1/3: jsan (python -m rlgpuschedule_tpu.analysis) ==="
 JSAN_CACHE="${JSAN_CACHE:-.jsan_cache}"
 python -m rlgpuschedule_tpu.analysis \
-    rlgpuschedule_tpu bench.py chip_smoke.py __graft_entry__.py \
+    rlgpuschedule_tpu chip_smoke.py __graft_entry__.py \
     --baseline jsan_baseline.json --fail-stale --cache "$JSAN_CACHE"
 
 echo "=== lint 1/3b: jsan SARIF gate (warm --cache replay) ==="
 JSAN_SARIF=$(mktemp /tmp/ci_jsan.XXXXXX.sarif)
 python -m rlgpuschedule_tpu.analysis \
-    rlgpuschedule_tpu bench.py chip_smoke.py __graft_entry__.py \
+    rlgpuschedule_tpu chip_smoke.py __graft_entry__.py \
     --baseline jsan_baseline.json --format sarif \
     --cache "$JSAN_CACHE" > "$JSAN_SARIF"
 python - "$JSAN_SARIF" <<'PY'
@@ -114,12 +114,11 @@ SERVE_JSON=$(mktemp /tmp/ci_serve.XXXXXX.json)
 SOAK_JSON=$(mktemp /tmp/ci_soak.XXXXXX.json)
 CHAOS_SOAK_JSON=$(mktemp /tmp/ci_chaos_soak.XXXXXX.json)
 TRACE_JSON=$(mktemp /tmp/ci_trace.XXXXXX.json)
-HOST_PATH_JSON=$(mktemp /tmp/ci_host_path.XXXXXX.json)
 trap 'rm -rf "$OBS_DIR" "$ASYNC_OBS_DIR" "$VTRACE_OBS_DIR" \
     "$SERVE_OBS_DIR" "$SOAK_OBS_DIR" "$CHAOS_SOAK_OBS_DIR" \
     "$CHAOS_FLOG_DIR" \
     "$CHAOS_JSON" "$SERVE_JSON" "$SOAK_JSON" "$CHAOS_SOAK_JSON" \
-    "$TRACE_JSON" "$HOST_PATH_JSON"' EXIT
+    "$TRACE_JSON"' EXIT
 # --trace-spans rides along (ISSUE 11): the flight recorder must not
 # disturb the strict-alarms gate, and the exported Chrome trace must be
 # Perfetto-valid (validated per layer below)
@@ -375,40 +374,6 @@ print("serve smoke ok:", {"p50_ms": round(b["latency_p50_ms"], 3),
                           "decisions_per_s": round(b["decisions_per_s"]),
                           "fleet_mean_jct": round(fl["mean_jct"], 1)})
 EOF
-
-echo "=== smoke: host-path data plane (arena vs legacy, stub engine) ==="
-# ISSUE 17 acceptance: the zero-copy serving data plane. Gates are
-# COUNT-BASED only (CI wall clock is noise; the recorded >= 2x
-# decisions/s lives in BENCH_r09): the arena arm's steady-state window
-# must make ZERO numpy batch-constructor calls and allocate ZERO new
-# slabs, every arm must conserve requests exactly (submitted ==
-# served + shed) and stay at ZERO post-warmup recompiles, and the
-# legacy arm's nonzero allocation count proves the gauge sees through.
-timeout -k 10 300 env JAX_PLATFORMS=cpu \
-    python -m rlgpuschedule_tpu.serve --config ppo-mlp-synth64 \
-    --host-path --bucket 8 --host-rounds 120 --pool-steps 2 \
-    --n-envs 2 --n-nodes 2 --gpus-per-node 4 --window-jobs 16 \
-    --queue-len 4 --horizon 64 > "$HOST_PATH_JSON"
-python - "$HOST_PATH_JSON" <<'PYEOF'
-import json, sys
-rep = json.load(open(sys.argv[1]))
-hp = rep["host_path"]
-arms = {a["data_plane"]: a for a in hp["arms"]}
-arena, legacy = arms["arena"], arms["legacy"]
-assert arena["alloc_calls"] == 0, arena            # zero steady-state
-assert arena["steady_state_slab_allocs"] == 0, arena
-assert legacy["alloc_calls"] > 0, legacy           # the deleted churn
-for arm in hp["arms"]:
-    assert arm["conservation_ok"], arm
-    assert arm["shed"] == 0, arm
-    assert arm["post_warmup_recompiles"] == 0, arm
-    assert arm["decisions_per_s"] > 0, arm
-assert hp["speedup"] > 0, hp
-print("host-path smoke ok:",
-      {"arena_allocs": arena["alloc_calls"],
-       "legacy_allocs_per_batch": round(legacy["allocs_per_batch"], 1),
-       "speedup_inproc": round(hp["speedup_inproc"], 2)})
-PYEOF
 
 echo "=== smoke: soak-lite (2 routed engines, deadlines + autoscale, 2 CPU devices) ==="
 # ISSUE 13 acceptance: a short multi-engine soak — 2 mesh-resolved

@@ -6,8 +6,8 @@ in preallocated slabs, padding is slice assignment into the slab tail,
 and scatter returns views into the one device-fetched actions buffer.
 An ``np.zeros``/``np.empty``/``np.concatenate``/``np.stack`` that
 creeps into code reachable from a dispatcher loop quietly reintroduces
-per-batch allocation churn — the host-path regression BENCH_r09 exists
-to measure — long before any benchmark notices.
+per-batch allocation churn — the regression the arena exists
+to prevent — long before any benchmark notices.
 
 Fires on those four constructors inside any function reachable (via the
 module's call graph) from a thread root the concurrency model knows:

@@ -8,8 +8,8 @@ that reads as a live constraint, and a CLI that exposes two refusable
 mode flags without calling ``validate_mode_combination`` silently runs
 (or silently ignores) a combination the table says must refuse — both
 drift classes existed in this tree when the rule first ran (the
-shard_map rows had no guard; ``evaluate`` and ``bench.py`` exposed
-refusable pairs unguarded).
+shard_map rows had no guard; ``evaluate`` and a root-level script
+exposed refusable pairs unguarded).
 
 Both directions are checked, each finding landing in the file whose
 edit fixes it:
@@ -105,7 +105,8 @@ def _parse_sibling(path: str) -> ast.AST | None:
 def _find_configs(path: str) -> ast.AST | None:
     """The defining module near ``path``: ``configs.py`` in the file's
     directory, up to two parents, or an immediate subdirectory (covers
-    package modules, ``serve/__main__.py``, and repo-root ``bench.py``)."""
+    package modules, ``serve/__main__.py``, and repo-root
+    ``chip_smoke.py``)."""
     d = os.path.dirname(os.path.abspath(path))
     candidates = [os.path.join(d, "configs.py"),
                   os.path.join(d, os.pardir, "configs.py"),

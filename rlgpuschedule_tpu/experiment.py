@@ -412,8 +412,8 @@ class Experiment:
         sync at zero). The per-iteration host loop of :meth:`run` pays
         one dispatch per train step, which bounds a small step's
         sustained throughput by dispatch latency, not chip time; one
-        fused dispatch removes that bound (and is how ``bench.py``
-        measures the chip rather than the host loop). No logging / eval /
+        fused dispatch removes that bound (no benchmark cell runs it:
+        the cells time :meth:`run`, PERF.md). No logging / eval /
         checkpoint / window-streaming hooks run inside — use :meth:`run`
         when you need them. Returns the LAST iteration's metrics.
 

@@ -141,8 +141,8 @@ def tree_shardings(tree: Any, rules: Rules, mesh: Mesh) -> Any:
 
 
 def rule_table_hash(rules: Rules) -> str:
-    """Stable short fingerprint of a rule table — recorded by bench.py so
-    two benchmark JSONs are comparable only when their layouts were."""
+    """Stable short fingerprint of a rule table — recorded with a run so
+    two results are comparable only when their layouts were."""
     text = "|".join(f"{pat}=>{tuple(spec)}" for pat, spec in rules)
     return hashlib.sha256(text.encode()).hexdigest()[:12]
 

@@ -49,7 +49,6 @@ from . import wire
 from .batching import (DeadlineSheddedError, Ewma, PolicyServer, Reservoir,
                        ServeResult, ServerClosedError, next_bucket,
                        pad_batch, scatter_results, stack_requests)
-from .bench import StubEngine, run_host_path
 from .engine import InferenceEngine
 from .fleet import fleet_replay, fleet_windows, sample_fleet_faults
 from .frontend import FrontendHandle, ServeFrontend, start_frontend
@@ -64,7 +63,6 @@ __all__ = [
     "SERVE_FAULT_KINDS", "ServeFaultSpec", "ServeFaultInjector",
     "InjectedEngineFault", "parse_serve_fault",
     "ServeFrontend", "FrontendHandle", "start_frontend", "wire",
-    "StubEngine", "run_host_path",
     "next_bucket", "pad_batch", "scatter_results", "stack_requests",
     "fleet_replay", "fleet_windows", "sample_fleet_faults",
 ]

@@ -17,12 +17,6 @@ Five modes, composable in one invocation:
   simulated clusters in one dispatch (optionally under a
   ``sim.faults`` regime), reporting fleet mean JCT / completion /
   decisions/s.
-- ``--host-path``: the data-plane bench (BENCH_r09) — a zero-device
-  stub engine isolates the host path (submit/coalesce/seal/scatter),
-  comparing the legacy copy-per-batch plane against the arena plane,
-  with the numpy batch-constructor count gated to ZERO in the arena
-  arm; ``--wire-requests N`` adds the socket arms (HTTP
-  connection-per-request vs framed keep-alive).
 - ``--flight-log DIR`` / ``--promote``: the data flywheel (ISSUE 19) —
   record served decisions into crc-sidecar'd shards during ``--soak``
   (exactly-once: ``rows_logged == served``), then canary-gate a
@@ -152,20 +146,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "isolated 1-engine and --engines-engine arms "
                         "serving the same stream (CPU caveat: dispatch "
                         "is serialized there)")
-    # host-path data-plane bench (PR 17)
-    p.add_argument("--host-path", action="store_true",
-                   help="data-plane bench: stub engine (zero device "
-                        "work) isolating the host path, legacy vs "
-                        "arena planes, with the steady-state "
-                        "allocation gauge (arena must be 0)")
-    p.add_argument("--host-rounds", type=int, default=300,
-                   help="host-path: measured full-bucket rounds per "
-                        "arm (plus a fixed warmup)")
-    p.add_argument("--wire-requests", type=int, default=0, metavar="N",
-                   help="host-path: also run the socket arms (HTTP "
-                        "connection-per-request vs framed keep-alive) "
-                        "with N measured requests each; the headline "
-                        "speedup becomes the wire ratio")
     # fleet mode
     p.add_argument("--fleet", type=int, default=None, metavar="N",
                    help="fleet replay: evaluate the checkpoint against "
@@ -254,11 +234,9 @@ def main(argv: "list[str] | None" = None) -> dict:
     promote_mode = (args.promote is not None
                     or args.promote_noise is not None)
     if (not args.bench and args.fleet is None and args.soak is None
-            and not args.scaleout and not args.host_path
-            and not promote_mode):
+            and not args.scaleout and not promote_mode):
         sys.exit("nothing to do: pass --bench, --soak S, --scaleout, "
-                 "--host-path, --promote/--promote-noise, and/or "
-                 "--fleet N")
+                 "--promote/--promote-noise, and/or --fleet N")
     if args.fleet is not None and args.fleet <= 0:
         sys.exit("--fleet must be a positive cluster count")
     if args.bucket <= 0 or (args.bucket & (args.bucket - 1)):
@@ -270,13 +248,6 @@ def main(argv: "list[str] | None" = None) -> dict:
                  "--engines >= 2 with it")
     if args.soak is not None and args.soak <= 0:
         sys.exit("--soak must be a positive duration in seconds")
-    if args.host_rounds <= 0:
-        sys.exit("--host-rounds must be positive")
-    if args.wire_requests < 0:
-        sys.exit("--wire-requests must be >= 0")
-    if args.wire_requests and not args.host_path:
-        sys.exit("--wire-requests adds socket arms to --host-path; "
-                 "pass --host-path with it (refusing the silent no-op)")
     if args.rate is not None and args.soak is None:
         sys.exit("--rate paces --soak submissions; pass --soak S with "
                  "it (refusing the silent no-op)")
@@ -412,8 +383,8 @@ def main(argv: "list[str] | None" = None) -> dict:
     from ..obs.trace import NULL_TRACER, Tracer
     from ..utils.platform import enable_compile_cache
     from .batching import PolicyServer
-    from .bench import (build_request_pool, run_bench, run_host_path,
-                        run_scaleout, run_soak)
+    from .bench import (build_request_pool, run_bench, run_scaleout,
+                        run_soak)
     from .engine import InferenceEngine
     from .fleet import fleet_replay, fleet_windows, sample_fleet_faults
     from .router import AutoscaleAdvisor, EngineRouter
@@ -485,7 +456,7 @@ def main(argv: "list[str] | None" = None) -> dict:
                                      tracer=tracer, capture=capture)
         pool = None
         if (args.bench or args.soak is not None or args.scaleout
-                or args.host_path or promote_mode):
+                or promote_mode):
             pool = build_request_pool(exp.apply_fn,
                                       exp.train_state.params,
                                       exp.env_params, exp.traces,
@@ -637,30 +608,6 @@ def main(argv: "list[str] | None" = None) -> dict:
                       f"{arm['per_engine_rows']}, recompiles "
                       f"{arm['per_engine_recompiles']}",
                       file=sys.stderr)
-        if args.host_path:
-            hp = run_host_path(pool, max_bucket=args.bucket,
-                               rounds=args.host_rounds,
-                               wire_requests=args.wire_requests)
-            report["host_path"] = hp
-            for arm in hp["arms"]:
-                print(f"host-path[{arm['data_plane']}]: "
-                      f"{arm['decisions_per_s']:.0f} decisions/s, "
-                      f"{arm['alloc_calls']} ndarray allocs "
-                      f"({arm['allocs_per_batch']:.1f}/batch), "
-                      f"conservation "
-                      + ("ok" if arm["conservation_ok"] else "VIOLATED"),
-                      file=sys.stderr)
-            for arm in hp.get("wire_arms", ()):
-                print(f"host-path[{arm['transport']}]: "
-                      f"{arm['decisions_per_s']:.0f} decisions/s over "
-                      f"{arm['clients']} clients, conservation "
-                      + ("ok" if arm["conservation_ok"] else "VIOLATED"),
-                      file=sys.stderr)
-            line = f"host-path speedup: {hp['speedup']:.2f}x"
-            if "wire_arms" in hp:
-                line += (" (wire; in-process "
-                         f"{hp['speedup_inproc']:.2f}x)")
-            print(line, file=sys.stderr)
         if args.fleet is not None:
             windows, traces = fleet_windows(cfg, args.fleet,
                                             source=exp.source)

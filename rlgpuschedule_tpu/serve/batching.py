@@ -15,29 +15,24 @@ front end's whole job is managing that axis on the host side:
 - **scatter**: the batched action array is split back to the submitting
   requests in FIFO order.
 
-Since ISSUE 17 the hot path is the **arena data plane**
-(``data_plane="arena"``, the default): requests land directly in
-preallocated bucket-sized slabs (one memcpy from the wire bytes into
-the slot row — ``submit`` IS the stack), ``pump`` seals a slab in place
-(tail rows neutralized by slice assignment, no ``np.concatenate``) and
-dispatches a contiguous view, and ``scatter`` hands back views into the
-single device-fetched actions buffer. Steady state allocates ZERO new
-host ndarrays per batch (asserted by test; ``serve_arena_allocs_total``
-counts slab allocations and must stay flat after warmup). The handoff
-is **lock-light**: producers take one tiny O(1) critical section to
-reserve a sequence-numbered slot (CPython's GIL rules out a true CAS
-loop, so "lock-free reservation" is not expressible — the honest
-version is a lock held for a handful of bytecodes, never across a copy
-or a dispatch), the row memcpy and the publish flag happen outside any
-lock, and the consumer side never holds the producers' lock during its
-O(batch) stacking/accounting work (the legacy plane shared ONE lock for
-all of that).
+The hot path is the **arena data plane** (ISSUE 17): requests land
+directly in preallocated bucket-sized slabs (one memcpy from the wire
+bytes into the slot row — ``submit`` IS the stack), ``pump`` seals a
+slab in place (tail rows neutralized by slice assignment, no
+``np.concatenate``) and dispatches a contiguous view, and ``scatter``
+hands back views into the single device-fetched actions buffer. Steady
+state allocates ZERO new host ndarrays per batch (asserted by test;
+``serve_arena_allocs_total`` counts slab allocations and must stay flat
+after warmup). The handoff is **lock-light**: producers take one tiny
+O(1) critical section to reserve a sequence-numbered slot (CPython's
+GIL rules out a true CAS loop, so "lock-free reservation" is not
+expressible — the honest version is a lock held for a handful of
+bytecodes, never across a copy or a dispatch), the row memcpy and the
+publish flag happen outside any lock, and the consumer side never holds
+the producers' lock during its O(batch) accounting work.
 
-The pre-arena plane survives as ``data_plane="legacy"`` — the measured
-"before" arm of ``serve.bench.run_host_path`` (BENCH_r09) and a
-fallback — via ``stack_requests``/``pad_batch``, which also remain the
-public padding utilities for non-hot-path callers (router probes,
-engine warmup).
+``stack_requests``/``pad_batch`` are the public stacking and padding
+utilities for callers off the hot path (router probes, engine warmup).
 
 Everything operates on HOST pytrees (numpy leaves, leading request
 axis); device placement is the engine's job, so the queue never holds
@@ -120,14 +115,12 @@ def next_bucket(n: int, max_bucket: int) -> int:
 
 def stack_requests(rows: "list[Any]") -> Any:
     """Stack per-request pytrees (no leading axis) into one batched host
-    pytree (leading axis = len(rows), FIFO order preserved). Legacy-
-    plane / probe utility: the arena plane never stacks — rows are
-    written into the slab at submit time."""
+    pytree (leading axis = len(rows), FIFO order preserved). Probe
+    utility: the server never stacks — rows are written into the slab
+    at submit time."""
     import jax
 
     def stack(*xs):
-        # jsan: disable=alloc-in-hot-loop -- legacy data plane (the bench
-        # before-arm) and rare router probes; the arena plane never stacks
         return np.stack([np.asarray(x) for x in xs])
 
     return jax.tree.map(stack, *rows)
@@ -164,8 +157,8 @@ def pad_batch(batch: Any, bucket: int, fill_mask_true: bool = False) -> Any:
     padded rows' logits stay finite under the ``-inf`` masking scheme
     (an all-masked row is the degenerate case the models never see in
     training). A full bucket (n == bucket) returns the input unchanged —
-    the arena plane relies on this no-op to dispatch slab views without
-    a copy."""
+    the server relies on this no-op to dispatch slab views without a
+    copy."""
     import jax
 
     def pad(x):
@@ -176,8 +169,6 @@ def pad_batch(batch: Any, bucket: int, fill_mask_true: bool = False) -> Any:
         if n == bucket:
             return x
         fill = _pad_fill(bucket - n, x.shape[1:], x.dtype, fill_mask_true)
-        # jsan: disable=alloc-in-hot-loop -- legacy data plane only: the
-        # arena plane always dispatches full-bucket views (n == bucket)
         return np.concatenate([x, fill])
 
     return jax.tree.map(pad, batch)
@@ -271,28 +262,6 @@ class Ewma:
         self.count = 0
 
 
-@dataclasses.dataclass
-class _Pending:
-    obs: Any
-    mask: Any
-    stall: int
-    t_submit: float
-    future: Future
-    deadline_s: "float | None" = None   # relative to t_submit; None = no SLO
-    req_id: int = 0                     # request-causality id (ISSUE 20)
-
-
-class _SlotRef:
-    """Read-only view of one pending arena slot for estimator scans
-    (duck-typed like :class:`_Pending` where ``_effective_wait`` needs
-    it: ``t_submit`` and ``deadline_s``)."""
-    __slots__ = ("t_submit", "deadline_s")
-
-    def __init__(self, t_submit: float, deadline_s: "float | None"):
-        self.t_submit = t_submit
-        self.deadline_s = deadline_s
-
-
 class _ArenaBlock:
     """One bucket-sized slab of the request ring: per-leaf preallocated
     host arrays (leading axis = ``capacity`` slots) plus parallel
@@ -348,7 +317,7 @@ class _ArenaRing:
     blocks (FIFO: sealed blocks first, else it force-seals the current
     one) and recycles them after scatter; a full ring back-pressures
     producers on ``cond`` until a block frees (the bounded-memory
-    contract — the legacy deque grew without bound)."""
+    contract)."""
 
     def __init__(self, obs_leaves, mask_leaves, bucket: int,
                  n_blocks: int, alloc_counter=None):
@@ -420,40 +389,14 @@ class _ArenaRing:
                     return blk.t_submit[i]
         return None
 
-    def pending_slots(self) -> "list[_SlotRef]":
-        """Snapshot of live pending slots for estimator scans."""
-        out: list[_SlotRef] = []
+    def deadlines_due(self) -> "list[float]":
+        """When each live pending slot's deadline falls due (submit time
+        + deadline), for the adaptive hold's slack scan."""
         with self.lock:
-            for blk in self.blocks():
-                for i in range(blk.claimed):
-                    if blk.published[i] and not blk.dead[i]:
-                        out.append(_SlotRef(blk.t_submit[i],
-                                            blk.deadline[i]))
-        return out
-
-
-class _RingPending:
-    """Duck-type of the legacy pending deque over the arena ring, so the
-    shared estimator code (and tests that poke ``server._pending``) see
-    one surface: ``len()``/truthiness is the live pending depth,
-    iteration yields :class:`_SlotRef` snapshots."""
-
-    def __init__(self, server: "PolicyServer"):
-        self._server = server
-
-    def __len__(self) -> int:
-        ring = self._server._ring
-        return ring.depth if ring is not None else 0
-
-    def __bool__(self) -> bool:
-        return len(self) > 0
-
-    def __iter__(self):
-        ring = self._server._ring
-        return iter(ring.pending_slots() if ring is not None else ())
-
-
-_DATA_PLANES = ("arena", "legacy")
+            return [blk.t_submit[i] + blk.deadline[i]
+                    for blk in self.blocks() for i in range(blk.claimed)
+                    if blk.published[i] and not blk.dead[i]
+                    and blk.deadline[i] is not None]
 
 
 class PolicyServer:
@@ -470,18 +413,16 @@ class PolicyServer:
     continuous batching, where a dispatch grabs whatever is pending the
     moment the previous one finishes.
 
-    **Data planes** (ISSUE 17). ``data_plane="arena"`` (default) is the
-    zero-copy hot path: ``submit`` memcpys the request row straight into
-    a preallocated slab slot (reserved under a tiny O(1) ring lock, the
+    **The arena data plane** (ISSUE 17) is a zero-copy hot path:
+    ``submit`` memcpys the request row straight into a preallocated
+    slab slot (reserved under a tiny O(1) ring lock, the
     copy itself outside any lock), ``pump`` seals and dispatches slab
     views, and steady state allocates no host ndarrays per batch. Slabs
     are sized from ``example_obs``/``example_mask`` at construction when
     given, else lazily from the first submitted request (row shapes and
     dtypes are then FIXED: later submits must match, and float inputs
     are cast to the arena dtype instead of silently promoting the
-    batch). ``data_plane="legacy"`` keeps the pre-arena
-    stack/pad/scatter path — the measured "before" arm of
-    ``serve.bench.run_host_path``.
+    batch).
 
     SLO surface (the ``registry`` gauges/counters, re-rendered by both
     the ``metrics.prom`` snapshot and the live scrape endpoint):
@@ -495,11 +436,11 @@ class PolicyServer:
     and ``serve_decisions_per_s`` (+ ``_per_chip``) via
     :meth:`slo_snapshot`, and ``serve_arena_allocs_total`` (host
     ndarrays allocated by the arena — warmup/ring-growth only; a moving
-    value in steady state is a regression and the ci.sh host-path stage
-    gates on it). Since ISSUE 20 the percentile/throughput gauges are
-    refreshed by a registry pre-scrape collector hook (scrapes are
-    never stale), ``serve_queue_wait_seconds`` buckets the
-    submit->dispatch wait separately from service time, and
+    value in steady state is a regression, gated by test). Since
+    ISSUE 20 the percentile/throughput gauges are refreshed by a
+    registry pre-scrape collector hook (scrapes are never stale),
+    ``serve_queue_wait_seconds`` buckets the submit->dispatch wait
+    separately from service time, and
     ``self.slo`` is an :class:`~..obs.slo.SLOEngine` evaluating
     availability / queue-latency / engine-health burn rates
     (``slo_burn_rate``, ``slo_error_budget_remaining``,
@@ -515,9 +456,8 @@ class PolicyServer:
 
     With a ``tracer`` attached (``serve --trace-spans``) the request
     lifecycle lands on the flight recorder: an ``enqueue`` instant per
-    submit, then ``bucket_wait`` -> ``serve_batch`` (``arena_seal`` on
-    the arena plane / ``stack`` on the legacy plane -> engine
-    ``pad``/``dispatch`` -> ``scatter``) per pump.
+    submit, then ``bucket_wait`` -> ``serve_batch`` (``arena_seal`` ->
+    engine ``pad``/``dispatch`` -> ``scatter``) per pump.
 
     When the engine exposes ``add_rewarm_listener`` (the router does),
     the server registers a callback that RESETS the learned service-time
@@ -529,7 +469,7 @@ class PolicyServer:
     def __init__(self, engine, registry=None, latency_window: int = 8192,
                  clock=time.perf_counter, max_wait_s: float | None = None,
                  tracer=None, sample_seed: int = 0,
-                 adaptive_wait: bool = False, data_plane: str = "arena",
+                 adaptive_wait: bool = False,
                  example_obs: Any = None, example_mask: Any = None,
                  arena_blocks: "int | None" = None, flight_log=None,
                  bus=None):
@@ -563,23 +503,16 @@ class PolicyServer:
         self._req_seq = itertools.count(1)
         if max_wait_s is not None and max_wait_s < 0:
             raise ValueError(f"max_wait_s must be >= 0, got {max_wait_s}")
-        if data_plane not in _DATA_PLANES:
-            raise ValueError(f"data_plane must be one of {_DATA_PLANES}, "
-                             f"got {data_plane!r}")
         if arena_blocks is not None and arena_blocks < 2:
             raise ValueError(f"arena_blocks must be >= 2, "
                              f"got {arena_blocks}")
         self.max_wait_s = max_wait_s
         self.adaptive_wait = bool(adaptive_wait)
-        self.data_plane = data_plane
         self._clock = clock
         self._lock = threading.Lock()
         self._wake = threading.Condition(self._lock)
         self._sleepers = 0          # consumers parked on _wake (under _lock)
         self._shed_lock = threading.Lock()   # serializes shed counting
-        self._pending: Any = (collections.deque()
-                              if data_plane == "legacy"
-                              else _RingPending(self))
         self._ring: "_ArenaRing | None" = None
         self._min_blocks = (arena_blocks if arena_blocks is not None
                             else max(4, min(128, 1024
@@ -647,7 +580,7 @@ class PolicyServer:
         if (example_obs is None) != (example_mask is None):
             raise ValueError("example_obs and example_mask must be given "
                              "together (the arena is sized from both)")
-        if example_obs is not None and data_plane == "arena":
+        if example_obs is not None:
             self.ensure_arena(example_obs, example_mask)
         add_listener = getattr(engine, "add_rewarm_listener", None)
         if callable(add_listener):
@@ -745,7 +678,7 @@ class PolicyServer:
         batch axis). Called from the constructor when examples are
         given, else lazily by the first :meth:`submit`; idempotent.
         Row shapes and dtypes are fixed from the example."""
-        if self.data_plane != "arena" or self._ring is not None:
+        if self._ring is not None:
             return
         import jax
         with self._lock:
@@ -771,7 +704,6 @@ class PolicyServer:
         """Arena occupancy/allocation surface for benches and CI gates."""
         ring = self._ring
         return {
-            "data_plane": self.data_plane,
             "blocks": ring.n_blocks if ring is not None else 0,
             "rows": (ring.n_blocks * ring.bucket
                      if ring is not None else 0),
@@ -827,93 +759,12 @@ class PolicyServer:
         only rejects once the service-time estimator has observations
         (a cold server admits everything rather than guessing).
 
-        On the arena plane this call performs the ONE host copy of the
-        request's life: the row lands directly in the current slab slot
-        (wire bytes -> arena when called from the frontend's
-        ``np.frombuffer`` views). Rows that don't match the arena's
-        fixed shapes raise ``ValueError`` here, at the door."""
+        This call performs the ONE host copy of the request's life: the
+        row lands directly in the current slab slot (wire bytes -> arena
+        when called from the frontend's ``np.frombuffer`` views). Rows
+        that don't match the arena's fixed shapes raise ``ValueError``
+        here, at the door."""
         req_id = self.mint_request_id() if not req_id else int(req_id)
-        if self.data_plane == "legacy":
-            return self._submit_legacy(obs, mask, stall, deadline_s,
-                                       req_id)
-        return self._submit_arena(obs, mask, stall, deadline_s, req_id)
-
-    def _submit_legacy(self, obs, mask, stall, deadline_s,
-                       req_id) -> Future:
-        now = self._clock()
-        fut: Future = Future()
-        req = _Pending(obs=obs, mask=mask, stall=int(stall),
-                       t_submit=now, future=fut,
-                       deadline_s=(None if deadline_s is None
-                                   else float(deadline_s)),
-                       req_id=req_id)
-        with self._wake:
-            if self._closed:
-                raise ServerClosedError(
-                    "PolicyServer is closed (drained for shutdown)")
-            if self._stopped:
-                raise ServerClosedError(
-                    "PolicyServer is stopped (drain in flight)")
-            self._requests.inc()
-            if self._t_prev_submit is not None:
-                self._arrival_gap.update(now - self._t_prev_submit)
-            self._t_prev_submit = now
-            svc = self._service_time.value
-            if (req.deadline_s is not None and svc is not None):
-                # dispatches ahead of this request if it joins the queue,
-                # itself included — each costs ~one learned service time
-                ahead = -(-(len(self._pending) + 1)
-                          // self.engine.max_bucket)
-                predicted = ahead * svc
-                if predicted > req.deadline_s:
-                    self._reject(fut, DeadlineSheddedError(
-                        "admission", req.deadline_s, waited_s=0.0,
-                        predicted_wait_s=predicted, req_id=req_id),
-                        reason="admission")
-                    return fut
-            self._pending.append(req)
-            self._wake.notify()
-        self.tracer.instant("enqueue", stall=int(stall), req_id=req_id)
-        return fut
-
-    def _write_row(self, blk: _ArenaBlock, i: int, obs, mask,
-                   stall: int) -> None:
-        """The one memcpy: request row -> slab slot ``i``. Shape
-        mismatches raise before any slab write (no torn rows)."""
-        if self._obs_is_leaf and isinstance(obs, np.ndarray):
-            obs_leaves = (obs,)
-        else:
-            import jax
-            obs_leaves = jax.tree.leaves(obs)
-        if self._mask_is_leaf and isinstance(mask, np.ndarray):
-            mask_leaves = (mask,)
-        else:
-            import jax
-            mask_leaves = jax.tree.leaves(mask)
-        if len(obs_leaves) != len(blk.obs):
-            raise ValueError(
-                f"obs has {len(obs_leaves)} leaves, arena expects "
-                f"{len(blk.obs)}")
-        if len(mask_leaves) != len(blk.mask):
-            raise ValueError(
-                f"mask has {len(mask_leaves)} leaves, arena expects "
-                f"{len(blk.mask)}")
-        for j, leaf in enumerate(obs_leaves):
-            if np.shape(leaf) != self._obs_row_shapes[j]:
-                raise ValueError(
-                    f"obs leaf {j} has shape {np.shape(leaf)}, arena row "
-                    f"is {self._obs_row_shapes[j]}")
-            blk.obs[j][i] = leaf
-        for j, leaf in enumerate(mask_leaves):
-            if np.shape(leaf) != self._mask_row_shapes[j]:
-                raise ValueError(
-                    f"mask leaf {j} has shape {np.shape(leaf)}, arena row "
-                    f"is {self._mask_row_shapes[j]}")
-            blk.mask[j][i] = leaf
-        blk.stall[i] = stall
-
-    def _submit_arena(self, obs, mask, stall, deadline_s,
-                      req_id) -> Future:
         if self._ring is None:
             self.ensure_arena(obs, mask)     # lazy sizing, first request
         ring = self._ring
@@ -992,6 +843,42 @@ class PolicyServer:
                                 req_id=req_id)
         return fut
 
+    def _write_row(self, blk: _ArenaBlock, i: int, obs, mask,
+                   stall: int) -> None:
+        """The one memcpy: request row -> slab slot ``i``. Shape
+        mismatches raise before any slab write (no torn rows)."""
+        if self._obs_is_leaf and isinstance(obs, np.ndarray):
+            obs_leaves = (obs,)
+        else:
+            import jax
+            obs_leaves = jax.tree.leaves(obs)
+        if self._mask_is_leaf and isinstance(mask, np.ndarray):
+            mask_leaves = (mask,)
+        else:
+            import jax
+            mask_leaves = jax.tree.leaves(mask)
+        if len(obs_leaves) != len(blk.obs):
+            raise ValueError(
+                f"obs has {len(obs_leaves)} leaves, arena expects "
+                f"{len(blk.obs)}")
+        if len(mask_leaves) != len(blk.mask):
+            raise ValueError(
+                f"mask has {len(mask_leaves)} leaves, arena expects "
+                f"{len(blk.mask)}")
+        for j, leaf in enumerate(obs_leaves):
+            if np.shape(leaf) != self._obs_row_shapes[j]:
+                raise ValueError(
+                    f"obs leaf {j} has shape {np.shape(leaf)}, arena row "
+                    f"is {self._obs_row_shapes[j]}")
+            blk.obs[j][i] = leaf
+        for j, leaf in enumerate(mask_leaves):
+            if np.shape(leaf) != self._mask_row_shapes[j]:
+                raise ValueError(
+                    f"mask leaf {j} has shape {np.shape(leaf)}, arena row "
+                    f"is {self._mask_row_shapes[j]}")
+            blk.mask[j][i] = leaf
+        blk.stall[i] = stall
+
     def _reserve_slot_locked(self, ring: _ArenaRing):
         """Claim the next slot (caller holds ``ring.lock``). Rolls the
         current block over when full; a completely full ring waits for
@@ -1018,37 +905,12 @@ class PolicyServer:
     # ---- expiry ------------------------------------------------------
 
     def _shed_expired(self, now: float) -> None:
-        if self.data_plane == "legacy":
-            self._shed_expired_legacy(now)
-        else:
-            self._shed_expired_arena(now)
-
-    def _shed_expired_legacy(self, now: float) -> None:
-        """Drop queued requests whose deadline already passed (called
-        under ``self._lock``); their futures resolve with the typed
-        rejection. Head-first scan is NOT enough: deadlines are
+        """Expiry: slots whose deadline already passed are marked dead
+        IN PLACE (their slab rows become padding at dispatch) instead of
+        being removed from a queue; the typed rejections fire outside
+        the ring lock. A head-first scan is NOT enough: deadlines are
         per-request, so a generous-deadline head can hide an expired
         tail."""
-        if not any(r.deadline_s is not None for r in self._pending):
-            return
-        keep: collections.deque[_Pending] = collections.deque()
-        for r in self._pending:
-            if (r.deadline_s is not None
-                    and now - r.t_submit > r.deadline_s):
-                self._reject(r.future, DeadlineSheddedError(
-                    "expired", r.deadline_s,
-                    waited_s=now - r.t_submit,
-                    req_id=r.req_id), reason="expired")
-            else:
-                keep.append(r)
-        self._pending = keep
-
-    def _shed_expired_arena(self, now: float) -> None:
-        """Arena expiry: expired slots are marked dead IN PLACE (their
-        slab rows become padding at dispatch) instead of being removed
-        from a queue; the typed rejections fire outside the ring lock.
-        Full scan, same reason as the legacy plane: per-request
-        deadlines mean a generous head can hide an expired tail."""
         ring = self._ring
         if ring is None:
             return
@@ -1093,56 +955,29 @@ class PolicyServer:
         waits = []
         if self.max_wait_s is not None:
             waits.append(self.max_wait_s)
+        ring = self._ring
         gap = self._arrival_gap.value
         if gap is not None:
-            free = max(self.engine.max_bucket - len(self._pending), 0)
+            free = max(self.engine.max_bucket - ring.depth, 0)
             waits.append(gap * free)
         now = self._clock()
-        slacks = [r.t_submit + r.deadline_s - now
-                  for r in self._pending if r.deadline_s is not None]
-        if slacks:
+        due = ring.deadlines_due()
+        if due:
             # keep one learned service time in hand for the dispatch
             svc = self._service_time.value or 0.0
-            waits.append(max(min(slacks) - svc, 0.0))
+            waits.append(max(min(due) - now - svc, 0.0))
         return min(waits) if waits else None
 
     # ---- pump --------------------------------------------------------
 
-    def pump(self, max_wait_s: float | None = None) -> int:
-        """Drain one coalesced batch: take up to ``engine.max_bucket``
-        pending requests (FIFO), dispatch, scatter results to their
-        futures. Returns the number of requests served (0 = queue was
-        empty). On the arena plane the "batch" is one slab: tail slots
-        are neutralized in place and the engine sees a contiguous
-        full-bucket view — no stacking, no padding copies.
-
-        ``max_wait_s`` (default: the constructor's policy; ``None`` = no
-        wait) is the batching deadline: a PARTIAL bucket holds off
-        dispatching until either the bucket fills or the batching
-        deadline passes — trading a bounded latency floor for occupancy
-        (the classic continuous-batching knob). ``0`` keeps the
-        dispatch-whatever-is-pending behavior while still being
-        explicit about it. With ``adaptive_wait`` the hold time is
-        LEARNED per pump (:meth:`_effective_wait`): the estimated
-        bucket fill time at the observed arrival rate, cut short when
-        the head-of-line deadline slack runs out — the deadline-aware
-        partial-bucket dispatch. Expired deadlines shed before and
-        after the hold (:meth:`_shed_expired`). A :meth:`stop` drain
-        cuts the wait short so shutdown never hangs on a sparse
-        queue."""
-        if self.data_plane == "legacy":
-            return self._pump_legacy(max_wait_s)
-        return self._pump_arena(max_wait_s)
-
-    def _hold_for_bucket(self, pending_depth, max_wait_s: "float | None",
-                         head_t_submit) -> None:
-        """Shared partial-bucket hold loop (caller holds ``self._lock``).
-        ``pending_depth``/``head_t_submit`` are callables so both planes
-        reuse the anchor/deadline policy. The sleep re-checks depth
-        AFTER advertising itself in ``_sleepers`` — with arena producers
-        publishing outside this lock, that ordering (producer: publish
-        then read ``_sleepers``; consumer: increment then re-check) is
-        what makes the wakeup race-free without a per-submit lock."""
+    def _hold_for_bucket(self, ring: _ArenaRing,
+                         max_wait_s: "float | None") -> None:
+        """The partial-bucket hold loop (caller holds ``self._lock``).
+        The sleep re-checks depth AFTER advertising itself in
+        ``_sleepers`` — with producers publishing outside this lock,
+        that ordering (producer: publish then read ``_sleepers``;
+        consumer: increment then re-check) is what makes the wakeup
+        race-free without a per-submit lock."""
         wait = (max_wait_s if max_wait_s is not None
                 else self._effective_wait())
         if wait is None:
@@ -1153,18 +988,18 @@ class PolicyServer:
         if max_wait_s is None and self.adaptive_wait:
             anchor = self._clock()
         else:
-            head = head_t_submit()
+            head = ring.head_t_submit()
             anchor = head if head is not None else self._clock()
         deadline = anchor + wait
         with self.tracer.span("bucket_wait"):
-            while (pending_depth() < self.engine.max_bucket
+            while (ring.depth < self.engine.max_bucket
                    and not self._stopped):
                 remaining = deadline - self._clock()
                 if remaining <= 0:
                     break
                 self._sleepers += 1
                 try:
-                    if (pending_depth() < self.engine.max_bucket
+                    if (ring.depth < self.engine.max_bucket
                             and not self._stopped):
                         self._wake.wait(timeout=remaining)
                 finally:
@@ -1203,64 +1038,6 @@ class PolicyServer:
             np.asarray(blp)[:n], np.asarray(bval)[:n],
             np.asarray(stall)[:n], outcome,
             req_id=np.asarray(req_ids, np.int64)[:n])
-
-    def _pump_legacy(self, max_wait_s: "float | None") -> int:
-        with self._lock:
-            self._shed_expired(self._clock())
-            if self._pending:
-                self._hold_for_bucket(
-                    lambda: len(self._pending), max_wait_s,
-                    lambda: (self._pending[0].t_submit
-                             if self._pending else None))
-                self._shed_expired(self._clock())
-            batch = [self._pending.popleft()
-                     for _ in range(min(len(self._pending),
-                                        self.engine.max_bucket))]
-            self._depth.set(len(self._pending))
-        if not batch:
-            return 0
-        n = len(batch)
-        rids = [r.req_id for r in batch]
-        t_disp = self._clock()
-        try:
-            with self.tracer.span("serve_batch", n=n):
-                with self.tracer.span("stack"):
-                    obs = stack_requests([r.obs for r in batch])
-                    mask = stack_requests([r.mask for r in batch])
-                    stall = np.asarray([r.stall for r in batch], np.int32)
-                out, bucket = self.engine.decide(obs, mask, stall)
-                actions, blp, bval = self._split_capture(out)
-                now = self._clock()
-                with self.tracer.span("scatter"):
-                    per_req = scatter_results(actions, n)
-            lats = [now - r.t_submit for r in batch]
-            if self._flight_log is not None:
-                # inside the try: the dispatcher loop's no-silent-drop
-                # invariant is that a raising pump has already resolved
-                # its batch's futures — a failing flight-log append must
-                # fail the batch loudly, never strand it
-                self._log_rows(obs, mask, stall, actions, blp, bval, n,
-                               lats, [r.deadline_s for r in batch],
-                               rids)
-        except BaseException as e:
-            for r in batch:
-                if not r.future.cancelled():
-                    r.future.set_exception(e)
-            if self.tracer is not NULL_TRACER:
-                self.tracer.instant("dispatch_failed", req_ids=rids,
-                                    error=type(e).__name__)
-            raise
-        t_subs = [r.t_submit for r in batch]
-        self._account_dispatch(now, t_disp, n, bucket, lats, t_subs, rids)
-        for r, a, lat in zip(batch, per_req, lats):
-            r.future.set_result(ServeResult(action=a, latency_s=lat,
-                                            req_id=r.req_id))
-        if self.tracer is not NULL_TRACER:
-            self.tracer.instant(
-                "served", bucket=bucket, req_ids=rids,
-                wait_ms=[round((t_disp - t) * 1e3, 3) for t in t_subs],
-                lat_ms=[round(l * 1e3, 3) for l in lats])
-        return n
 
     def _seal_block(self, blk: _ArenaBlock):
         """Turn a taken block into a dispatchable contiguous prefix:
@@ -1333,7 +1110,7 @@ class PolicyServer:
                 self._mask_treedef, [l[:bucket] for l in blk.mask])
         return obs, mask, blk.stall[:bucket]
 
-    def _scatter_arena(self, blk: _ArenaBlock, actions: Any, n_live: int):
+    def _scatter(self, blk: _ArenaBlock, actions: Any, n_live: int):
         """Per-request action views into the single device-fetched
         actions buffer. If the engine echoed its INPUT back (host-stub
         engines do), the buffer aliases the slab we are about to
@@ -1355,15 +1132,35 @@ class PolicyServer:
         return [jax.tree.unflatten(treedef, [l[i] for l in safe])
                 for i in range(n_live)]
 
-    def _pump_arena(self, max_wait_s: "float | None") -> int:
+    def pump(self, max_wait_s: float | None = None) -> int:
+        """Drain one coalesced batch: take up to ``engine.max_bucket``
+        pending requests (FIFO), dispatch, scatter results to their
+        futures. Returns the number of requests served (0 = queue was
+        empty). The "batch" is one slab: tail slots are neutralized in
+        place and the engine sees a contiguous full-bucket view — no
+        stacking, no padding copies.
+
+        ``max_wait_s`` (default: the constructor's policy; ``None`` = no
+        wait) is the batching deadline: a PARTIAL bucket holds off
+        dispatching until either the bucket fills or the batching
+        deadline passes — trading a bounded latency floor for occupancy
+        (the classic continuous-batching knob). ``0`` keeps the
+        dispatch-whatever-is-pending behavior while still being
+        explicit about it. With ``adaptive_wait`` the hold time is
+        LEARNED per pump (:meth:`_effective_wait`): the estimated
+        bucket fill time at the observed arrival rate, cut short when
+        the head-of-line deadline slack runs out — the deadline-aware
+        partial-bucket dispatch. Expired deadlines shed before and
+        after the hold (:meth:`_shed_expired`). A :meth:`stop` drain
+        cuts the wait short so shutdown never hangs on a sparse
+        queue."""
         ring = self._ring
         if ring is None:
             return 0
         with self._lock:
             self._shed_expired(self._clock())
             if ring.depth > 0:
-                self._hold_for_bucket(lambda: ring.depth, max_wait_s,
-                                      ring.head_t_submit)
+                self._hold_for_bucket(ring, max_wait_s)
                 self._shed_expired(self._clock())
             blk = ring.take_block()
             self._depth.set(ring.depth)
@@ -1389,7 +1186,7 @@ class PolicyServer:
                 actions, blp, bval = self._split_capture(out)
                 now = self._clock()
                 with self.tracer.span("scatter"):
-                    per_req = self._scatter_arena(blk, actions, n_live)
+                    per_req = self._scatter(blk, actions, n_live)
             lats = [now - t for t in t_subs]
             if self._flight_log is not None:
                 # tap point: the slab views stay valid until ring.recycle
@@ -1458,8 +1255,6 @@ class PolicyServer:
     # ---- live dispatcher thread --------------------------------------
 
     def _has_work(self) -> bool:
-        if self.data_plane == "legacy":
-            return bool(self._pending)
         ring = self._ring
         return ring is not None and ring.depth > 0
 
@@ -1564,9 +1359,6 @@ class PolicyServer:
     def queue_depth(self) -> int:
         """Requests currently queued (the frontend's backpressure
         signal — sampled, so momentarily stale values are fine)."""
-        if self.data_plane == "legacy":
-            with self._lock:
-                return len(self._pending)
         ring = self._ring
         return ring.depth if ring is not None else 0
 

@@ -299,36 +299,22 @@ def run_soak(server, pool: "list[tuple[Any, Any]]", *,
 
 
 class StubEngine:
-    """Zero-device-work engine for the host-path bench: ``decide``
-    returns a view of ONE preallocated action buffer (never a fresh
-    ndarray, never an alias of the caller's obs/mask — so the arena's
-    zero-copy scatter needs no defensive copy and the steady-state
-    allocation gate measures the data plane alone). With device work
-    gone, decisions/s isolates exactly the host path this rig can
-    honestly measure: submit → coalesce → pad/seal → scatter."""
+    """Zero-device-work engine for the arena's allocation gate:
+    ``decide`` returns a view of ONE preallocated action buffer (never
+    a fresh ndarray, never an alias of the caller's obs/mask — so the
+    arena's zero-copy scatter needs no defensive copy and the
+    steady-state allocation count is the data plane's alone)."""
 
     def __init__(self, max_bucket: int = 8):
         self.max_bucket = int(max_bucket)
-        self.dispatches = 0
-        self.rows = 0
-        self.post_warmup_recompiles = 0     # nothing compiles, ever
-        self.warmed_buckets: "tuple[int, ...]" = ()
-
         self._actions = np.zeros(self.max_bucket, dtype=np.int32)
 
     def bucket_for(self, n: int) -> int:
         from .batching import next_bucket
         return next_bucket(n, self.max_bucket)
 
-    def warmup(self, example_obs: Any, example_mask: Any,
-               buckets: "tuple[int, ...]" = ()) -> "tuple[int, ...]":
-        self.warmed_buckets = tuple(buckets)
-        return self.warmed_buckets
-
     def decide(self, obs: Any, mask: Any, stall=None):
         n = int(np.asarray(jax.tree.leaves(obs)[0]).shape[0])
-        self.dispatches += 1
-        self.rows += n
         return self._actions[:n], self.bucket_for(n)
 
 
@@ -336,8 +322,7 @@ class _AllocCounter:
     """Context manager counting calls to the numpy batch constructors
     the hot path must not touch in steady state (the same four the jsan
     ``alloc-in-hot-loop`` rule polices). Wraps the module-level
-    functions, so every caller in-process is counted — including the
-    legacy plane's ``stack_requests``/``pad_batch``."""
+    functions, so every caller in-process is counted."""
 
     TRACKED = ("zeros", "empty", "concatenate", "stack")
 
@@ -361,255 +346,6 @@ class _AllocCounter:
             setattr(np, name, fn)
         self._orig.clear()
         return False
-
-
-def _run_wire_arm(pool: "list[tuple[Any, Any]]", *, bucket: int,
-                  framed: bool, n_requests: int, clients: int = 8,
-                  warmup: int = 64) -> dict:
-    """One transport arm over a LIVE stack (dispatcher thread + asyncio
-    frontend + real sockets): the pre-PR shape is one HTTP connection
-    per request over the legacy plane; the post-PR shape is one framed
-    keep-alive connection per client over the arena. ``clients``
-    concurrent client threads keep the batcher fed so dispatches
-    coalesce. Client and server share one interpreter, so the number is
-    the whole host path — wire parse included, the part the framed mode
-    exists to amortize."""
-    import socket
-    import threading
-
-    from ..obs import Registry
-    from . import wire
-    from .batching import PolicyServer
-    from .frontend import start_frontend
-
-    plane = "arena" if framed else "legacy"
-    obs0, mask0 = pool[0]
-    reg = Registry()
-    engine = StubEngine(bucket)
-    server = PolicyServer(engine, registry=reg, data_plane=plane,
-                          example_obs=obs0, example_mask=mask0)
-    server.start(dispatchers=1)
-    handle = start_frontend(server, obs0, mask0, registry=reg)
-    addr = ("127.0.0.1", handle.port)
-    per_client = max(n_requests // clients, 1)
-    warm_per_client = max(warmup // clients, 1)
-    ok = [0] * clients
-    barrier = threading.Barrier(clients + 1)
-
-    def http_request(obs, mask):
-        body = (np.ascontiguousarray(obs).tobytes()
-                + np.ascontiguousarray(mask).tobytes())
-        return (f"POST /v1/decide HTTP/1.1\r\nHost: bench\r\n"
-                f"Content-Length: {len(body)}\r\n"
-                f"Connection: close\r\n\r\n").encode() + body
-
-    def run_http(k: int) -> None:
-        obs, mask = pool[k % len(pool)]
-        req = http_request(obs, mask)
-        for phase, n in (("warm", warm_per_client),
-                         ("measure", per_client)):
-            if phase == "measure":
-                barrier.wait()
-            for _ in range(n):
-                with socket.create_connection(addr) as s:
-                    s.sendall(req)
-                    buf = b""
-                    while True:         # Connection: close -> read to EOF
-                        c = s.recv(65536)
-                        if not c:
-                            break
-                        buf += c
-                if phase == "measure" and buf.startswith(b"HTTP/1.1 200"):
-                    ok[k] += 1
-
-    def run_framed(k: int) -> None:
-        obs, mask = pool[k % len(pool)]
-        frame = wire.pack_request(obs, mask)
-        with socket.create_connection(addr) as s:
-            for phase, n in (("warm", warm_per_client),
-                             ("measure", per_client)):
-                if phase == "measure":
-                    barrier.wait()
-                for _ in range(n):
-                    s.sendall(frame)
-                    kind, _, _, _, _, _ = wire.recv_frame(s)
-                    if phase == "measure" and kind == wire.KIND_RESP:
-                        ok[k] += 1
-
-    target = run_framed if framed else run_http
-    threads = [threading.Thread(target=target, args=(k,), daemon=True)
-               for k in range(clients)]
-    for t in threads:
-        t.start()
-    barrier.wait()
-    t0 = time.perf_counter()
-    for t in threads:
-        t.join()
-    wall = time.perf_counter() - t0
-    occupancy = reg.gauge("serve_batch_occupancy").value
-    handle.close()
-    served = sum(ok)
-    return {
-        "transport": ("framed keep-alive" if framed
-                      else "http connection-per-request"),
-        "data_plane": plane,
-        "clients": clients,
-        "requests": per_client * clients,
-        "served": served,
-        "conservation_ok": served == per_client * clients,
-        "decisions_per_s": served / wall,
-        "wall_s": wall,
-        "last_batch_occupancy": float(occupancy),
-        "post_warmup_recompiles": engine.post_warmup_recompiles,
-    }
-
-
-def run_host_path(pool: "list[tuple[Any, Any]]", *, max_bucket: int = 8,
-                  rounds: int = 300, warmup_rounds: int = 12,
-                  fit=None, seed: int = 0,
-                  rate_hz: "float | None" = None,
-                  wire_requests: int = 0, clients: int = 8,
-                  planes: "tuple[str, ...]" = ("legacy", "arena")) -> dict:
-    """Host-path decisions/s, pre-PR vs post-PR data plane (BENCH_r09).
-
-    Two in-process arms isolate the BATCHING layer: one arm per plane
-    (fresh registry + :class:`StubEngine` + server, inline-pumped so
-    every dispatch is exactly ``max_bucket`` rows), same request
-    stream. When ``fit`` and ``rate_hz`` are given, submissions are
-    replay-paced with :func:`fit_paced_gaps` — pass a rate above
-    saturation so the trace contributes burstiness, not a rate ceiling.
-    The measured window wraps the four numpy batch constructors
-    (:class:`_AllocCounter`): the legacy arm's count is the churn being
-    deleted, the arena arm's must be ZERO — and the arena's
-    slab-allocation counter must stay flat (both CI-gated).
-
-    When ``wire_requests > 0`` two further arms measure the WHOLE data
-    plane through real sockets (:func:`_run_wire_arm`): the pre-PR
-    shape (one HTTP connection per request, legacy batching) vs the
-    post-PR shape (framed keep-alive, arena batching). The headline
-    ``speedup`` is the wire-arm ratio when present — that is the plane
-    this PR replaced end to end — with the batching-only ratio kept as
-    ``speedup_inproc``."""
-    from ..obs import Registry
-    from .batching import PolicyServer
-
-    if rounds <= 0 or warmup_rounds < 1:
-        raise ValueError(f"need rounds > 0 and warmup_rounds >= 1, got "
-                         f"{rounds} / {warmup_rounds}")
-    if not pool:
-        raise ValueError("empty request pool")
-    bucket = int(max_bucket)
-    obs0, mask0 = pool[0]
-    n_requests = rounds * bucket
-    gaps = None
-    if fit is not None:
-        if rate_hz is None or rate_hz <= 0:
-            raise ValueError("replay pacing needs rate_hz > 0")
-        gaps = fit_paced_gaps(fit, n_requests, seed=(seed, 0x405B),
-                              rate_hz=rate_hz)
-
-    arms: dict[str, dict] = {}
-    for plane in planes:
-        reg = Registry()
-        engine = StubEngine(bucket)
-        server = PolicyServer(engine, registry=reg, data_plane=plane,
-                              example_obs=obs0, example_mask=mask0)
-        slab_allocs = reg.counter("serve_arena_allocs_total")
-
-        cursor = 0
-
-        # inline pump resolves every future before submit of the next
-        # round, so the bench counts served rows off pump()'s return and
-        # drops the futures immediately — accumulating 10k+ live futures
-        # would measure the GC scanning the bench's own garbage, not the
-        # data plane (both arms flatline identically under that load)
-        def one_round() -> int:
-            nonlocal cursor
-            for _ in range(bucket):
-                obs, mask = pool[cursor % len(pool)]
-                server.submit(obs, mask)
-                cursor += 1
-            return server.pump()
-
-        # warmup: slab ring growth, pad-fill cache, estimator warm —
-        # after this ANY allocation in the arena arm is a regression
-        for _ in range(warmup_rounds):
-            one_round()
-        allocs_before = int(slab_allocs.value)
-        requests_before = int(reg.counter("serve_requests_total").value)
-
-        served = 0
-        counter = _AllocCounter()
-        t0 = time.perf_counter()
-        next_t = t0
-        with counter:
-            if gaps is None:
-                for r in range(rounds):
-                    served += one_round()
-            else:
-                for r in range(rounds):
-                    for _ in range(bucket):
-                        obs, mask = pool[cursor % len(pool)]
-                        server.submit(obs, mask)
-                        next_t += gaps[cursor % len(gaps)]
-                        sleep = next_t - time.perf_counter()
-                        if sleep > 0:
-                            time.sleep(sleep)
-                        cursor += 1
-                    served += server.pump()
-        wall = time.perf_counter() - t0
-        submitted = (int(reg.counter("serve_requests_total").value)
-                     - requests_before)
-        shed = int(reg.counter("serve_shed_total").value)
-        server.close()
-        arms[plane] = {
-            "data_plane": plane,
-            "requests": submitted,
-            "served": served,
-            "shed": shed,
-            "conservation_ok": submitted == served + shed,
-            "decisions_per_s": served / wall,
-            "wall_s": wall,
-            "dispatches": engine.dispatches,
-            "alloc_calls": counter.calls,
-            "allocs_per_batch": counter.calls / rounds,
-            "steady_state_slab_allocs":
-                int(slab_allocs.value) - allocs_before,
-            "post_warmup_recompiles": engine.post_warmup_recompiles,
-            "arena": server.arena_stats() if plane == "arena" else None,
-        }
-
-    out = {
-        "bucket": bucket,
-        "rounds": rounds,
-        "warmup_rounds": warmup_rounds,
-        "requests_per_arm": n_requests,
-        "paced": gaps is not None,
-        "arrival_fit": getattr(fit, "name", None) if fit is not None
-        else None,
-        "rate_hz": rate_hz,
-        "caveat": ("stub engine, zero device work: decisions/s is the "
-                   "HOST path only (submit/coalesce/seal/scatter) — the "
-                   "number this serialized-dispatch CPU rig can honestly "
-                   "measure; device-inclusive numbers await the real-pod "
-                   "item"),
-        "arms": [arms[p] for p in planes],
-    }
-    if "legacy" in arms and "arena" in arms:
-        base = arms["legacy"]["decisions_per_s"]
-        out["speedup_inproc"] = (arms["arena"]["decisions_per_s"] / base
-                                 if base > 0 else None)
-        out["speedup"] = out["speedup_inproc"]
-    if wire_requests > 0:
-        before = _run_wire_arm(pool, bucket=bucket, framed=False,
-                               n_requests=wire_requests, clients=clients)
-        after = _run_wire_arm(pool, bucket=bucket, framed=True,
-                              n_requests=wire_requests, clients=clients)
-        out["wire_arms"] = [before, after]
-        base = before["decisions_per_s"]
-        out["speedup"] = (after["decisions_per_s"] / base
-                          if base > 0 else None)
-    return out
 
 
 def fit_paced_gaps(fit, n: int, seed, rate_hz: float) -> np.ndarray:

@@ -206,9 +206,10 @@ class ServeFrontend:
             # A connection that finished its TCP handshake but is not
             # yet a transport when the listener closes is silently
             # orphaned — the client hangs on a dead socket. Two windows:
-            # (a) accepted by the selector, accept-task still queued: on
-            #     3.10 Server.close() makes Server._attach assert, the
-            #     error is swallowed and the socket leaks;
+            # (a) accepted by the selector, accept-task still queued:
+            #     Server.close() makes Server._attach assert (3.12
+            #     still does), the error is swallowed and the socket
+            #     leaks;
             # (b) still in the kernel accept queue: the listener close
             #     strands it (Linux does NOT reset queued connections).
             # Close both: stop the accept reader FIRST, tick the loop so
@@ -217,6 +218,15 @@ class ServeFrontend:
             # listening sockets (the accept queue lives on the shared
             # file description), close the listener, and hand every
             # still-queued connection to the normal handler.
+            #
+            # Server.wait_closed() is NOT awaited: close() has already
+            # closed the listening sockets, and since 3.12.1 what
+            # wait_closed() waits for is every open CONNECTION to end.
+            # An idle keep-alive client is left connected on purpose
+            # (its next request gets the typed refusal), so that wait
+            # belongs to the clients, not to the drain: it held the
+            # drain until the handle's timeout. What the drain owes is
+            # below: no request in flight (_idle), within the grace.
             loop = asyncio.get_running_loop()
             for ts in self._tcp.sockets:
                 try:
@@ -227,7 +237,6 @@ class ServeFrontend:
             await asyncio.sleep(0)
             backlog = [ts.dup() for ts in self._tcp.sockets]
             self._tcp.close()
-            await self._tcp.wait_closed()
             await self._refuse_backlog(backlog)
         if self._gate is not None:
             # wake paused readers: their next request gets a typed 503

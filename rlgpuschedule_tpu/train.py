@@ -116,9 +116,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--minibatch-size", type=int, default=None,
                    help="explicit minibatch size (overrides "
                         "--n-minibatches; must tile n_steps * n_envs — "
-                        "the fewer-larger-minibatch throughput lever, "
-                        "sweepable via profile_breakdown "
-                        "--sweep-minibatch)")
+                        "the fewer-larger-minibatch throughput lever: "
+                        "what it buys on the chip is for the benchmark "
+                        "to say, PERF.md)")
     p.add_argument("--bf16-update", action="store_true", default=None,
                    help="bf16-compute / fp32-optimizer-state update path "
                         "(NOT bit-identical to the fp32 default)")

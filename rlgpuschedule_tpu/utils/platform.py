@@ -1,9 +1,9 @@
 """Device selection and compile-cache placement, one helper each.
 
 ``force_cpu`` pins N virtual CPU devices before any jax backend
-initializes: the test suite (tests/conftest.py), the ``--cpu`` opt-ins of
-``bench.py`` / ``profile_breakdown`` and ``__graft_entry__.dryrun_multichip``
-all need it (SURVEY.md §4 "Distributed without a real cluster").
+initializes: the test suite (tests/conftest.py) and
+``__graft_entry__.dryrun_multichip``
+need it (SURVEY.md §4 "Distributed without a real cluster").
 ``require_tpu`` is its opposite for the chip entry points: no TPU is an
 error, never a quiet CPU run. ``enable_compile_cache`` places jax's
 persistent compilation cache where whoever runs the program said, else at
@@ -31,7 +31,7 @@ def cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Point jax's persistent compilation cache at :func:`cache_dir` and
     cache every compile (floor 0). Every entry point that compiles calls
-    this before its first compile — the CLIs, ``bench.py``,
+    this before its first compile — the CLIs, ``benchmark/run.py``,
     ``chip_smoke.py`` and the test conftest — so all of them write ONE
     on-disk format into a shared directory. It sets the directory and the
     compile-time floor and nothing else: in particular no size cap, which

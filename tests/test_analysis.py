@@ -144,7 +144,7 @@ class TestCLI:
     def test_shipped_tree_is_clean(self):
         """Acceptance gate: the analyzer exits 0 over the shipped
         package + top-level scripts (everything fixed or suppressed)."""
-        r = _cli("rlgpuschedule_tpu", "bench.py", "chip_smoke.py",
+        r = _cli("rlgpuschedule_tpu", "chip_smoke.py",
                  "__graft_entry__.py")
         assert r.returncode == 0, r.stdout + r.stderr
 
@@ -243,7 +243,7 @@ class TestRepoBaselineFile:
         assert data["version"] == 1
         current = {f.baseline_key for f in analyze_paths(
             [os.path.join(REPO, "rlgpuschedule_tpu"),
-             os.path.join(REPO, "bench.py"),
+             os.path.join(REPO, "chip_smoke.py"),
              os.path.join(REPO, "__graft_entry__.py")])}
         stale = [e for e in data["entries"]
                  if (e["rule"], e["path"], e["snippet"]) not in current]
@@ -254,7 +254,7 @@ class TestRepoBaselineFile:
         the committed baseline is EMPTY, nothing is grandfathered."""
         findings = analyze_paths(
             [os.path.join(REPO, "rlgpuschedule_tpu"),
-             os.path.join(REPO, "bench.py"),
+             os.path.join(REPO, "chip_smoke.py"),
              os.path.join(REPO, "__graft_entry__.py")])
         assert findings == [], [f"{f.path}:{f.line} [{f.rule}]"
                                 for f in findings]

@@ -128,13 +128,18 @@ class TestCompileCache:
 
 
 class TestNoHiddenFallback:
-    """The chip entry points fail without a TPU; --cpu / --tiny are the
-    only ways onto the CPU and both label their output."""
+    """The chip entry points fail without a TPU: the one yardstick of
+    speed (``BENCHMARK.json``'s command) and the bring-up smoke. Their
+    rehearsals (--rehearse-cpu, --tiny) are the only ways onto the CPU
+    and both label their output."""
 
-    @pytest.mark.parametrize("script", ["bench.py", "chip_smoke.py"])
+    @pytest.mark.parametrize("argv", [
+        ("benchmark/run.py", "--workload", "philly512-cnn.train",
+         "--seed", "1", "--seconds", "1", "--trace", "0"),
+        ("chip_smoke.py",)], ids=["benchmark/run.py", "chip_smoke.py"])
     def test_chip_script_without_a_tpu_fails_and_prints_no_result(
-            self, script):
-        r = _run_script(script)
+            self, argv):
+        r = _run_script(*argv)
         assert r.returncode != 0
         assert r.stdout == "", r.stdout       # no metric, no result line
         assert "TPU" in r.stderr.strip().splitlines()[-1]
@@ -151,23 +156,68 @@ class TestNoHiddenFallback:
         phases = {ln["phase"]: ln for ln in lines[:-1]}
         assert phases["serve"]["post_warmup_recompiles"] == 0
         assert phases["evaluate"]["policy_completion"] == 1.0
-        # bench.py, the CLIs and this script share one cache directory
-        # and one on-disk format
+        # the CLIs, the benchmark and this script share one cache
+        # directory and one on-disk format
         assert "Error writing persistent compilation cache" not in r.stderr
 
-    @pytest.mark.parametrize("kind,peak", [
-        ("TPU v5 lite", 197e12),    # what a v5e chip reports: NOT the
-        ("TPU v5e", 197e12),        # "v5" (v5p) row a substring would hit
-        ("TPU v5", 459e12), ("TPU v5p", 459e12), ("TPU v6 lite", 918e12)])
-    def test_mfu_peak_lookup_is_exact_on_device_kind(self, kind, peak):
-        from rlgpuschedule_tpu.profile_breakdown import bf16_peak
-        assert bf16_peak("tpu", kind) == peak
-        assert bf16_peak("cpu", "cpu") is None
 
-    def test_unknown_tpu_kind_is_an_error_not_a_null_mfu(self):
-        from rlgpuschedule_tpu.profile_breakdown import bf16_peak
-        with pytest.raises(SystemExit, match="TPU v9"):
-            bf16_peak("tpu", "TPU v9")
+def _readme_commands():
+    """Every ``python -m rlgpuschedule_tpu.<cli> ...`` command in
+    README.md's fenced blocks, continuation lines joined, as
+    ``(cli, arguments, README line)``."""
+    import re
+    cli = re.compile(r"python3? -m rlgpuschedule_tpu\."
+                     r"(train|evaluate|serve|select_checkpoint)\b(.*)")
+    out, fenced, joined, start = [], False, "", 0
+    with open(os.path.join(REPO, "README.md")) as f:
+        for n, line in enumerate(f, 1):
+            line = line.rstrip("\n")
+            if line.lstrip().startswith("```"):
+                fenced = not fenced
+                continue
+            if not fenced:
+                continue
+            if not joined:
+                start = n
+            joined += line
+            if joined.endswith("\\"):
+                joined = joined[:-1] + " "
+                continue
+            m = cli.search(joined)
+            if m:
+                out.append((m.group(1), m.group(2), start))
+            joined = ""
+    return out
+
+
+README_COMMANDS = _readme_commands()
+
+
+class TestReadmeCommands:
+    """README's commands are held to the parsers they name: a flag that
+    is renamed or deleted takes its README line with it."""
+
+    @pytest.mark.parametrize(
+        "cli,arguments", [c[:2] for c in README_COMMANDS],
+        ids=[f"{c[0]}-L{c[2]}" for c in README_COMMANDS])
+    def test_command_parses(self, cli, arguments):
+        import importlib
+        import shlex
+        name = "serve.__main__" if cli == "serve" else cli
+        parser = importlib.import_module(
+            f"rlgpuschedule_tpu.{name}").build_parser()
+        try:
+            parser.parse_args(shlex.split(arguments, comments=True))
+        except SystemExit as e:
+            pytest.fail(f"README: python -m rlgpuschedule_tpu.{cli}"
+                        f"{arguments} does not parse (exit {e.code})")
+
+    def test_the_readme_still_has_its_commands(self):
+        # a README whose fences moved would leave the test above with
+        # no cases and no failure
+        assert {"train", "evaluate", "serve"} <= {
+            c[0] for c in README_COMMANDS}
+        assert len(README_COMMANDS) >= 30
 
 
 class TestTrainCLI:
@@ -526,52 +576,6 @@ class TestEvaluateCLI:
         with pytest.raises(SystemExit):
             train_cli.main(["--config", "ppo-mlp-synth64", *FAST,
                             "--domains", "mixed", "--pbt"])
-
-
-class TestMinibatchSweep:
-    """profile_breakdown --sweep-minibatch: the automated geometry lever
-    sweep must emit a ranked artifact that bench.py can consume."""
-
-    def test_sweep_artifact_ranked_and_written(self, tmp_path, capsys):
-        from rlgpuschedule_tpu import profile_breakdown
-        out_path = str(tmp_path / "sweep.json")
-        art = profile_breakdown.main(
-            ["--n-envs", "2", "--n-steps", "8", "--repeats", "1",
-             "--iters-per-repeat", "1", "--sweep-minibatch",
-             "--sweep-out", out_path])
-        capsys.readouterr()
-        assert art["sweep"] == "minibatch-geometry"
-        assert art["batch_per_iteration"] == 16
-        times = [r["update_s_per_iteration"] for r in art["results"]]
-        assert times == sorted(times), "results must rank fastest-first"
-        assert art["best"] == art["results"][0]
-        # grid covers the epochs axis and every tiling minibatch count
-        geoms = {(r["n_epochs"], r["n_minibatches"])
-                 for r in art["results"]}
-        assert {(1, 1), (1, 16), (2, 8)} <= geoms
-        for r in art["results"]:
-            assert r["minibatch_size"] * r["n_minibatches"] == 16
-            assert r["update_env_steps_per_sec"] > 0
-            assert "mfu_update" in r          # null off-TPU, present always
-            assert r["speedup_vs_default"] > 0
-        default = next(r for r in art["results"]
-                       if (r["n_epochs"], r["n_minibatches"]) == (2, 8))
-        assert default["speedup_vs_default"] == pytest.approx(1.0)
-        # the artifact on disk is the same object bench.py --sweep reads
-        with open(out_path) as f:
-            on_disk = json.load(f)
-        assert on_disk["best"] == art["best"]
-        import bench
-        e, m = bench.geometry_from_sweep(out_path)
-        assert (e, m) == (art["best"]["n_epochs"],
-                          art["best"]["n_minibatches"])
-
-    def test_bench_refuses_non_sweep_artifact(self, tmp_path):
-        import bench
-        bad = tmp_path / "not_a_sweep.json"
-        bad.write_text(json.dumps({"metric": "x"}))
-        with pytest.raises(SystemExit):
-            bench.geometry_from_sweep(str(bad))
 
 
 class TestStallGuardEngage:

@@ -58,9 +58,11 @@ def example(seed=0):
 
 
 @contextlib.contextmanager
-def serving_stack(cost_s=0.0, max_bucket=8, **fe_kw):
+def serving_stack(cost_s=0.0, max_bucket=8, clock=time.perf_counter,
+                  **fe_kw):
     reg = Registry()
-    server = PolicyServer(HostEngine(max_bucket, cost_s), registry=reg)
+    server = PolicyServer(HostEngine(max_bucket, cost_s), registry=reg,
+                          clock=clock)
     server.start()
     obs, mask = example()
     handle = start_frontend(server, obs, mask, port=0, **fe_kw)
@@ -130,7 +132,12 @@ class TestShedMapping:
     server (no service-time observation yet) admits instead."""
 
     def test_cold_server_admits_deadlined_request(self):
-        with serving_stack(cost_s=0.0) as (handle, server, reg, obs, mask):
+        # the server's clock stands still, so the 1 ms deadline cannot
+        # run out in the queue: the only shed left is admission's, the
+        # one this contract is about (on the wall clock a loaded machine
+        # took over 1 ms to reach the pump's expiry scan)
+        with serving_stack(cost_s=0.0, clock=lambda: 0.0) as (
+                handle, server, reg, obs, mask):
             assert server.service_time_s() is None      # nothing learned
             status, _, payload = post(
                 handle.url + DECIDE_PATH, obs.tobytes() + mask.tobytes(),

@@ -355,7 +355,7 @@ class TestDeadlineShedding:
             t[0] += float(rng.uniform(0.0, 0.05))
             if rng.random() < 0.3:
                 server.pump()
-        while server._pending:
+        while server.queue_depth():
             server.pump()
         shed = 0
         for f in futs:

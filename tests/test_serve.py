@@ -411,55 +411,146 @@ class TestArenaDataPlane:
     views, shape policing at the door, and the estimator re-warm
     reset."""
 
-    def test_plane_parity_bit_identical(self):
-        rows = request_rows(40)
-        actions = {}
-        for plane in ("legacy", "arena"):
-            server = PolicyServer(ArgmaxEngine(8), data_plane=plane,
-                                  example_obs=rows[0][0],
-                                  example_mask=rows[0][1])
-            futs = [server.submit(o, m) for o, m in rows]
+    @pytest.mark.parametrize("stream", [
+        # fills its buckets exactly: no tail to neutralise
+        dict(n=16, bucket=8),
+        # leaves a partial bucket, in a slab that still holds the first
+        # round's rows: 5 live rows, 3 neutral ones
+        dict(n=21, bucket=8, arena_blocks=2, neutral=3),
+        # outgrows the ring's first slabs: 64 rows through 3 slabs of 4,
+        # each recycled over stale rows, producers held back meanwhile
+        dict(n=64, bucket=4, arena_blocks=2, dispatchers=1, ring_rows=12),
+        # two producers against two dispatchers
+        dict(n=80, bucket=8, producers=2, dispatchers=2),
+        # float64 rows are cast to the arena's float32 at the door
+        dict(n=11, bucket=8, dtype=np.float64, neutral=1),
+        # deadline-shed rows in the middle become padding, the live rows
+        # close up over them: 8 claimed - 2 shed -> 6 (+2 neutral), then
+        # 4 claimed - 1 shed -> 3 (+1 neutral)
+        dict(n=12, bucket=8, shed=(3, 4, 9), neutral=3),
+    ], ids=["exact", "partial", "outgrow", "threads", "float64", "shed"])
+    def test_served_actions_match_row_wise_reference(self, stream):
+        """What the arena serves is what ``engine.decide`` returns for
+        each request ALONE, and no row the engine is handed is anything
+        but a submitted row or a neutral one (zero obs, every action
+        legal): coalescing, sealing, compaction and slab reuse change
+        nothing and leak nothing."""
+        import threading
+        from rlgpuschedule_tpu.serve.batching import DeadlineSheddedError
+        n, bucket = stream["n"], stream["bucket"]
+        shed = set(stream.get("shed", ()))
+        rng = np.random.default_rng(n)
+        rows = []
+        for _ in range(n):
+            mask = rng.integers(0, 2, 9).astype(bool)
+            mask[0] = True
+            rows.append((rng.standard_normal(6).astype(
+                stream.get("dtype", np.float32)), mask))
+        alone = ArgmaxEngine(1)
+        want = [alone.decide(o.astype(np.float32)[None], m[None])[0][0]
+                for o, m in rows]
+
+        seen = []                       # copies of every dispatched batch
+
+        class RecordingEngine(ArgmaxEngine):
+            def decide(self, obs, mask, stall=None):
+                seen.append((np.array(obs), np.array(mask)))  # GIL-atomic
+                return super().decide(obs, mask, stall)
+
+        t = [0.0]
+        server = PolicyServer(
+            RecordingEngine(bucket), clock=lambda: t[0],
+            example_obs=rows[0][0].astype(np.float32),
+            example_mask=rows[0][1],
+            arena_blocks=stream.get("arena_blocks"))
+        futs = [None] * n
+
+        def produce(idx):
+            for i in idx:
+                futs[i] = server.submit(
+                    *rows[i], deadline_s=0.01 if i in shed else None)
+
+        if "dispatchers" in stream:
+            server.start(dispatchers=stream["dispatchers"])
+            k = stream.get("producers", 1)
+            threads = [threading.Thread(target=produce,
+                                        args=(range(j, n, k),))
+                       for j in range(k)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=60)
+            assert not any(th.is_alive() for th in threads)
+        else:
+            for i in range(n):
+                produce([i])
+                if not shed and (i + 1) % bucket == 0:
+                    assert server.pump() == bucket
+            t[0] = 1.0                  # every deadline has run out
             while server.pump():
                 pass
-            actions[plane] = np.stack(
-                [np.asarray(f.result(timeout=10).action) for f in futs])
-            server.close()
-        np.testing.assert_array_equal(actions["legacy"], actions["arena"])
+        for i, f in enumerate(futs):
+            if i in shed:
+                with pytest.raises(DeadlineSheddedError) as ei:
+                    f.result(timeout=30)
+                assert ei.value.reason == "expired"
+            else:
+                assert f.result(timeout=30).action == want[i]
+        if "ring_rows" in stream:       # the stream is longer than the ring
+            assert server.arena_stats()["rows"] == stream["ring_rows"] < n
+        server.close()
 
-    def test_zero_steady_state_allocations(self):
-        """THE perf contract: after warmup, a full-bucket round on the
-        arena plane calls none of the numpy batch constructors and
-        allocates no new slabs; the legacy plane's nonzero count is the
-        churn being deleted (and proves the counter sees through)."""
+        # every live row reached the engine exactly once (a stale copy
+        # in a reused slab would be a second time), and nothing else did
+        # but neutral rows
+        unseen = {o.astype(np.float32).tobytes() + m.tobytes()
+                  for i, (o, m) in enumerate(rows) if i not in shed}
+        assert len(unseen) == n - len(shed)
+        neutral = 0
+        for obs, mask in seen:
+            assert obs.dtype == np.float32 and mask.dtype == np.bool_
+            for o, m in zip(obs, mask):
+                key = o.tobytes() + m.tobytes()
+                if key in unseen:
+                    unseen.remove(key)
+                else:
+                    assert not o.any() and m.all()
+                    neutral += 1
+        assert not unseen
+        if "neutral" in stream:
+            assert neutral == stream["neutral"]
+
+    @pytest.mark.parametrize("bucket", [1, 8, 64])
+    def test_zero_steady_state_allocations(self, bucket):
+        """THE perf contract: after warmup, a full-bucket round calls
+        none of the numpy batch constructors and allocates no new
+        slabs, at the smallest bucket, a middling one and the largest
+        the CLI's default ring is sized for."""
         from rlgpuschedule_tpu.serve.bench import StubEngine, _AllocCounter
         rows = request_rows(16)
-        counts = {}
-        for plane in ("legacy", "arena"):
-            reg = Registry()
-            server = PolicyServer(StubEngine(8), registry=reg,
-                                  data_plane=plane,
-                                  example_obs=rows[0][0],
-                                  example_mask=rows[0][1])
+        server = PolicyServer(StubEngine(bucket), registry=Registry(),
+                              example_obs=rows[0][0],
+                              example_mask=rows[0][1])
 
-            def one_round():
-                for i in range(8):
-                    server.submit(*rows[i % len(rows)])
-                return server.pump()
+        def one_round():
+            for i in range(bucket):
+                server.submit(*rows[i % len(rows)])
+            return server.pump()
 
-            for _ in range(4):                      # warmup: ring growth
-                one_round()
-            slabs_before = server.arena_stats()["slab_allocs"]
-            served = 0
-            with _AllocCounter() as counter:
-                for _ in range(32):
-                    served += one_round()
-            counts[plane] = counter.calls
-            assert served == 32 * 8                  # conservation
-            assert (server.arena_stats()["slab_allocs"]
-                    == slabs_before)                 # no slab growth
-            server.close()
-        assert counts["arena"] == 0
-        assert counts["legacy"] > 0
+        for _ in range(4):                          # warmup: ring growth
+            one_round()
+        slabs_before = server.arena_stats()["slab_allocs"]
+        served = 0
+        with _AllocCounter() as counter:
+            for _ in range(32):
+                served += one_round()
+        assert served == 32 * bucket                # conservation
+        assert counter.calls == 0
+        assert server.arena_stats()["slab_allocs"] == slabs_before
+        server.close()
+        with _AllocCounter() as probe:              # the counter sees through
+            stack_requests([rows[0][0], rows[1][0]])
+        assert probe.calls > 0
 
     def test_scatter_returns_views_into_actions_buffer(self):
         """Zero-copy tail: when the engine's actions don't alias the
@@ -478,7 +569,7 @@ class TestArenaDataPlane:
 
         rows = request_rows(8)
         engine = VecActionEngine(8)
-        server = PolicyServer(engine, data_plane="arena",
+        server = PolicyServer(engine,
                               example_obs=rows[0][0],
                               example_mask=rows[0][1])
         futs = [server.submit(o, m) for o, m in rows]
@@ -491,7 +582,7 @@ class TestArenaDataPlane:
 
     def test_submit_rejects_wrong_row_shape_at_the_door(self):
         rows = request_rows(2)
-        server = PolicyServer(ArgmaxEngine(8), data_plane="arena",
+        server = PolicyServer(ArgmaxEngine(8),
                               example_obs=rows[0][0],
                               example_mask=rows[0][1])
         with pytest.raises(ValueError):
@@ -506,20 +597,24 @@ class TestArenaDataPlane:
 
     def test_arena_stats_surface(self):
         rows = request_rows(1)
-        server = PolicyServer(ArgmaxEngine(8), data_plane="arena",
+        server = PolicyServer(ArgmaxEngine(8),
                               example_obs=rows[0][0],
                               example_mask=rows[0][1])
         stats = server.arena_stats()
-        assert stats["data_plane"] == "arena"
+        assert set(stats) == {"blocks", "rows", "slab_allocs"}
         assert stats["blocks"] >= 1
         assert stats["rows"] == stats["blocks"] * 8
         # one counted allocation per slab array: obs leaves + mask
         # leaves + the stall vector + the req-id lane, per block
         assert stats["slab_allocs"] == stats["blocks"] * 4
-        legacy = PolicyServer(ArgmaxEngine(8), data_plane="legacy")
-        assert legacy.arena_stats()["blocks"] == 0
-        legacy.close()
         server.close()
+        # without examples the ring is sized by the first request
+        lazy = PolicyServer(ArgmaxEngine(8))
+        assert lazy.arena_stats() == {"blocks": 0, "rows": 0,
+                                      "slab_allocs": 0}
+        lazy.submit(*rows[0])
+        assert lazy.arena_stats()["blocks"] >= 1
+        lazy.close()
 
     def test_rewarm_listener_resets_service_time_estimator(self):
         """ISSUE 17 satellite: a fleet re-warm (weight swap /
@@ -527,7 +622,7 @@ class TestArenaDataPlane:
         to cold-admit instead of shedding on the stale estimate."""
         rows = request_rows(8)
         engine = RewarmEngine(8)
-        server = PolicyServer(engine, data_plane="arena",
+        server = PolicyServer(engine,
                               example_obs=rows[0][0],
                               example_mask=rows[0][1])
         assert len(engine.listeners) == 1            # hook registered
@@ -547,7 +642,7 @@ class TestRequestCausality:
 
     def test_minted_ids_unique_salted_and_on_results(self):
         rows = request_rows(8)
-        server = PolicyServer(ArgmaxEngine(8), data_plane="arena",
+        server = PolicyServer(ArgmaxEngine(8),
                               example_obs=rows[0][0],
                               example_mask=rows[0][1])
         futs = [server.submit(o, m) for o, m in rows]
@@ -559,10 +654,9 @@ class TestRequestCausality:
         assert all(0 < i < (1 << 63) for i in ids)   # int64-safe
         server.close()
 
-    @pytest.mark.parametrize("plane", ["legacy", "arena"])
-    def test_explicit_id_round_trips(self, plane):
+    def test_explicit_id_round_trips(self):
         rows = request_rows(1)
-        server = PolicyServer(ArgmaxEngine(8), data_plane=plane,
+        server = PolicyServer(ArgmaxEngine(8),
                               example_obs=rows[0][0],
                               example_mask=rows[0][1])
         fut = server.submit(*rows[0], req_id=0x123456789ABCDEF)
@@ -590,7 +684,7 @@ class TestRequestCausality:
                 return super().decide(obs, mask, stall)
 
         bus = EventBus(str(tmp_path), rank=0, name="serve")
-        server = PolicyServer(FlakyEngine(8), data_plane="arena",
+        server = PolicyServer(FlakyEngine(8),
                               example_obs=request_rows(1)[0][0],
                               example_mask=request_rows(1)[0][1],
                               tracer=Tracer(bus, enabled=True))
@@ -646,7 +740,7 @@ class TestRequestCausality:
         from rlgpuschedule_tpu.serve.batching import DeadlineSheddedError
         bus = EventBus(str(tmp_path), rank=0, name="serve")
         rows = request_rows(2)
-        server = PolicyServer(ArgmaxEngine(8), data_plane="arena",
+        server = PolicyServer(ArgmaxEngine(8),
                               example_obs=rows[0][0],
                               example_mask=rows[0][1],
                               tracer=Tracer(bus, enabled=True))
@@ -666,7 +760,7 @@ class TestRequestCausality:
 
     def test_p99_exemplar_rides_snapshot(self):
         rows = request_rows(16)
-        server = PolicyServer(ArgmaxEngine(8), data_plane="arena",
+        server = PolicyServer(ArgmaxEngine(8),
                               example_obs=rows[0][0],
                               example_mask=rows[0][1])
         futs = [server.submit(o, m) for o, m in rows]
@@ -702,66 +796,6 @@ class TestBench:
         assert report["decisions_per_s"] > 0
         assert report["latency_p50_ms"] > 0
         assert report["latency_p99_ms"] >= report["latency_p50_ms"]
-
-    def test_run_host_path_gates_and_report_shape(self):
-        """BENCH_r09's driver: both in-process arms present, the arena
-        arm allocation-free and slab-flat, conservation structural, the
-        stub engine recompile-free. (The >= 2x speedup itself is gated
-        on the recorded BENCH run, not a CI-noise-sensitive assert.)"""
-        from rlgpuschedule_tpu.serve.bench import run_host_path
-        pool = request_rows(16)
-        report = run_host_path(pool, max_bucket=8, rounds=40,
-                               warmup_rounds=4)
-        assert [a["data_plane"] for a in report["arms"]] == \
-            ["legacy", "arena"]
-        arena, legacy = report["arms"][1], report["arms"][0]
-        assert arena["alloc_calls"] == 0
-        assert arena["allocs_per_batch"] == 0
-        assert arena["steady_state_slab_allocs"] == 0
-        assert legacy["alloc_calls"] > 0
-        for arm in report["arms"]:
-            assert arm["conservation_ok"]
-            assert arm["requests"] == 40 * 8
-            assert arm["served"] == 40 * 8 and arm["shed"] == 0
-            assert arm["post_warmup_recompiles"] == 0
-            assert arm["decisions_per_s"] > 0
-        assert arena["arena"]["slab_allocs"] >= 1
-        assert report["speedup"] == report["speedup_inproc"]
-        assert not report["paced"]
-
-    def test_run_host_path_wire_arms_over_live_sockets(self):
-        """The transport half of BENCH_r09: HTTP connection-per-request
-        (pre-PR) vs one framed keep-alive connection per client
-        (post-PR), both conserving every request, with the headline
-        speedup switched to the wire ratio."""
-        from rlgpuschedule_tpu.serve.bench import run_host_path
-        pool = request_rows(16)
-        report = run_host_path(pool, max_bucket=8, rounds=10,
-                               warmup_rounds=2, wire_requests=64,
-                               clients=4)
-        before, after = report["wire_arms"]
-        assert before["transport"] == "http connection-per-request"
-        assert before["data_plane"] == "legacy"
-        assert after["transport"] == "framed keep-alive"
-        assert after["data_plane"] == "arena"
-        for arm in report["wire_arms"]:
-            assert arm["conservation_ok"]
-            assert arm["served"] == arm["requests"]
-            assert arm["decisions_per_s"] > 0
-            assert arm["post_warmup_recompiles"] == 0
-        assert report["speedup"] == pytest.approx(
-            after["decisions_per_s"] / before["decisions_per_s"])
-        assert "speedup_inproc" in report
-
-    def test_run_host_path_refusals(self):
-        from rlgpuschedule_tpu.serve.bench import run_host_path
-        pool = request_rows(4)
-        with pytest.raises(ValueError, match="rounds"):
-            run_host_path(pool, rounds=0)
-        with pytest.raises(ValueError, match="empty request pool"):
-            run_host_path([])
-        with pytest.raises(ValueError, match="rate_hz"):
-            run_host_path(pool, fit=object())
 
 
 class TestFleetReplay:
@@ -912,18 +946,6 @@ class TestServeCLI:
         assert report["repro"]["ckpt_step"] == 3
         assert report["repro"]["ckpt_dir"] == str(tmp_path / "ckpt")
 
-    def test_host_path_mode(self):
-        report = serve_cli.main(
-            SERVE_FAST + ["--host-path", "--bucket", "8",
-                          "--host-rounds", "20", "--pool-steps", "1"])
-        hp = report["host_path"]
-        arena = [a for a in hp["arms"] if a["data_plane"] == "arena"][0]
-        assert arena["alloc_calls"] == 0
-        assert arena["steady_state_slab_allocs"] == 0
-        assert all(a["conservation_ok"] for a in hp["arms"])
-        assert hp["speedup"] > 0
-        assert "wire_arms" not in hp                   # not requested
-
     def test_refusals(self):
         with pytest.raises(SystemExit):
             serve_cli.main(SERVE_FAST)                     # no mode
@@ -943,9 +965,3 @@ class TestServeCLI:
         with pytest.raises(SystemExit):
             serve_cli.main(SERVE_FAST + ["--fleet", "1",
                                          "--fleet-regime", "nope"])
-        with pytest.raises(SystemExit):                    # silent no-op
-            serve_cli.main(SERVE_FAST + ["--wire-requests", "64",
-                                         "--bench"])
-        with pytest.raises(SystemExit):
-            serve_cli.main(SERVE_FAST + ["--host-path",
-                                         "--host-rounds", "0"])
