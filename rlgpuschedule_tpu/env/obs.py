@@ -12,8 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from ..sim.core import (SimParams, SimState, Trace, pending_queue,
-                        running_queue, PENDING, RUNNING, in_system,
-                        utilization)
+                        queue_rows, running_queue, PENDING, RUNNING,
+                        in_system, utilization)
 from ..sim.faults import FaultSchedule, node_up
 
 
@@ -48,17 +48,17 @@ def queue_features(params: SimParams, state: SimState, trace: Trace,
     """Per-queue-slot features [K, 4]: demand/capacity, waiting time,
     service demand (both in units of ``time_scale`` via the caller), valid.
     Pass a precomputed ``pending_queue`` to share it with the action mask
-    (the env step computes it once — VERDICT r1 weak #2)."""
+    (the env step computes it once — VERDICT r1 weak #2). The trace fields
+    are read at the queue's rows through ``core.queue_rows`` (dense)."""
     if queue is None:
         queue = pending_queue(params, state)               # [K]
-    jc = jnp.clip(queue, 0, params.max_jobs - 1)
     occupied = queue >= 0
     valid = occupied.astype(jnp.float32)
-    demand = trace.gpus[jc].astype(jnp.float32) / params.capacity * valid
-    # where (not *valid): padding rows have submit=+inf, and (clock-inf)*0
-    # would be NaN and poison the whole vmapped obs batch
-    wait = jnp.where(occupied, state.clock - trace.submit[jc], 0.0)
-    service = jnp.where(occupied, trace.duration[jc], 0.0)
+    demand = (queue_rows(trace.gpus, queue).astype(jnp.float32)
+              / params.capacity * valid)
+    wait = jnp.where(occupied,
+                     state.clock - queue_rows(trace.submit, queue), 0.0)
+    service = jnp.where(occupied, queue_rows(trace.duration, queue), 0.0)
     return jnp.stack([demand, wait, service, valid], axis=1)
 
 
@@ -139,6 +139,12 @@ def grid_obs(params: SimParams, state: SimState, trace: Trace,
     values on a node are taken in the same round and paint the same value
     on the sum of their GPUs, so the image cannot depend on how ties
     would be ordered.
+
+    The queue rows read ``trace.gpus`` and ``trace.duration`` at the
+    queue's K rows through ``core.queue_rows``: the same trade, a
+    ``[K, J]`` compare and masked sum in place of two gathers of K single
+    elements (~10 ns an element on the chip: 107 ms an iteration of the
+    CNN cell before PR 34).
     """
     N, G, K = params.n_nodes, params.gpus_per_node, params.queue_len
     used = (params.gpus_per_node - state.free).astype(jnp.float32)    # [N]
@@ -165,11 +171,11 @@ def grid_obs(params: SimParams, state: SimState, trace: Trace,
 
     if queue is None:
         queue = pending_queue(params, state)
-    jc = jnp.clip(queue, 0, params.max_jobs - 1)
     valid = (queue >= 0).astype(jnp.float32)
-    demand = jnp.minimum(trace.gpus[jc], G).astype(jnp.float32) * valid
+    demand = (jnp.minimum(queue_rows(trace.gpus, queue), G)
+              .astype(jnp.float32) * valid)
     bar = (slots[None, :] < demand[:, None]).astype(jnp.float32)      # [K,G]
-    service = jnp.tanh(trace.duration[jc] / time_scale) * valid
+    service = jnp.tanh(queue_rows(trace.duration, queue) / time_scale) * valid
     qimg = jnp.stack([bar, bar * service[:, None]], axis=-1)          # [K,G,2]
     parts = [cluster, qimg]
     if params.preempt_len:

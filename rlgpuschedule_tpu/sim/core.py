@@ -360,14 +360,36 @@ def preempt(state: SimState, j: jax.Array, max_jobs: int
 def pending_queue(params: SimParams, state: SimState) -> jax.Array:
     """Row indices of the first K pending jobs, -1 padded. Trace rows are
     submit-sorted at construction, so row order IS the oracle's
-    (submit asc, id asc) queue order."""
+    (submit asc, id asc) queue order.
+
+    Selected densely: slot k takes the pending job whose rank among the
+    pending jobs is k, as a ``[K, J]`` compare and a masked max. At most
+    one job has a given rank, so the max IS that job's row; a slot no job
+    ranks at keeps -1, and a job of rank >= K matches no slot. No scatter:
+    the chip writes scattered int32 elements at ~10 ns each (1.1 ms a call
+    at 320 envs x 129 slots), where this compare-select-reduce fuses into
+    one pass that also keeps its ``named_scope`` (PERF.md section 6,
+    PR 34)."""
     K = params.queue_len
     pending = state.status == PENDING
     rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
     rows = jnp.arange(params.max_jobs, dtype=jnp.int32)
-    target = jnp.where(pending & (rank < K), rank, K)  # K = scatter-drop slot
-    return jnp.full((K + 1,), -1, jnp.int32).at[target].set(
-        jnp.where(pending & (rank < K), rows, -1), mode="drop")[:K]
+    slots = jnp.arange(K, dtype=jnp.int32)
+    sel = pending[None, :] & (rank[None, :] == slots[:, None])    # [K,J]
+    return jnp.max(jnp.where(sel, rows[None, :], -1), axis=1)
+
+
+def queue_rows(field: jax.Array, queue: jax.Array) -> jax.Array:
+    """``field[J]`` at the rows a queue view ``[K]`` names; an empty slot
+    (-1) reads 0, so callers mask by ``queue >= 0`` as they always did.
+    Dense like :func:`pending_queue` and for the same reason (an element
+    gather costs the chip ~10 ns an element): a ``[K, J]`` compare of each
+    slot's row against the job index and a masked sum of at most one term,
+    so a float comes through exactly. ``where``, not a product: a padding
+    row's ``submit`` is +inf."""
+    rows = jnp.arange(field.shape[0], dtype=queue.dtype)
+    sel = queue[:, None] == rows[None, :]                         # [K,J]
+    return jnp.sum(jnp.where(sel, field[None, :], 0), axis=1)
 
 
 def running_queue(params: SimParams, state: SimState, trace: Trace,
@@ -414,11 +436,11 @@ def action_mask(params: SimParams, state: SimState, trace: Trace,
     no-op is always valid. Pass precomputed ``pending_queue`` /
     ``running_queue`` to share them with the observation builder. With
     ``faults``, feasibility counts only up nodes' free GPUs — the mask and
-    :func:`try_place` always agree on what fits."""
+    :func:`try_place` always agree on what fits. The slots' demands are
+    read through :func:`queue_rows` (dense; no gather of K elements)."""
     if queue is None:
         queue = pending_queue(params, state)                   # [K]
-    jc = jnp.clip(queue, 0, params.max_jobs - 1)
-    demand = trace.gpus[jc]
+    demand = queue_rows(trace.gpus, queue)
     free = effective_free(faults, state.free, state.clock)
     ok = (queue >= 0) & (demand <= jnp.sum(free))              # [K]
     slots = jnp.repeat(ok, params.n_placements)                # [K*P]

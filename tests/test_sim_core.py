@@ -87,6 +87,173 @@ class TestQueueAndMask:
         np.testing.assert_array_equal(mask, [0, 0, 0, 0, 0, 0, 1])
 
 
+def scatter_pending_queue(params, state):
+    """The scatter form ``pending_queue`` had up to PR 33, kept here as the
+    reference the dense selection is held to, element for element."""
+    K = params.queue_len
+    pending = state.status == C.PENDING
+    rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
+    rows = jnp.arange(params.max_jobs, dtype=jnp.int32)
+    target = jnp.where(pending & (rank < K), rank, K)  # K = scatter-drop slot
+    return jnp.full((K + 1,), -1, jnp.int32).at[target].set(
+        jnp.where(pending & (rank < K), rows, -1), mode="drop")[:K]
+
+
+QV_J, QV_K = 24, 6
+
+
+def _queue_view_status(case):
+    """int32[J] job statuses for one named case of the queue view."""
+    rng = np.random.default_rng(7)
+    status = np.full(QV_J, C.DONE, np.int32)
+    if case == "none":
+        status[::2] = C.RUNNING
+        status[1::4] = C.NOT_ARRIVED
+    elif case == "fewer":
+        status[[2, 9, 17]] = C.PENDING
+    elif case == "exactly_k":
+        status[rng.choice(QV_J, QV_K, replace=False)] = C.PENDING
+    elif case == "more":
+        status[rng.choice(QV_J, QV_K + 4, replace=False)] = C.PENDING
+    elif case == "k_plus_one_tail":
+        # the job past the view is the LAST row: the drop slot's corner
+        status[:QV_K] = C.PENDING
+        status[QV_J - 1] = C.PENDING
+    elif case == "all":
+        status[:] = C.PENDING
+    elif case == "interleaved":
+        status = rng.choice(
+            np.array([C.NOT_ARRIVED, C.PENDING, C.RUNNING, C.DONE], np.int32),
+            QV_J).astype(np.int32)
+    else:
+        raise ValueError(case)
+    return status
+
+
+def _queue_view_state(status):
+    status = jnp.asarray(status, jnp.int32)
+    J = status.shape[-1]
+    lead = status.shape[:-1]
+    z = jnp.zeros(lead + (J,), jnp.float32)
+    return C.SimState(clock=jnp.zeros(lead, jnp.float32), status=status,
+                      remaining=z, start=z, finish=z,
+                      alloc=jnp.zeros(lead + (J, 2), jnp.int32),
+                      free=jnp.zeros(lead + (2,), jnp.int32))
+
+
+QV_CASES = ["none", "fewer", "exactly_k", "more", "k_plus_one_tail", "all",
+            "interleaved"]
+
+
+class TestDenseQueueView:
+    """PR 34: the K-slot queue view is a dense [K, J] selection; the scatter
+    and the element gathers it replaced live on only as references here."""
+    params = C.SimParams(n_nodes=2, gpus_per_node=4, max_jobs=QV_J,
+                         queue_len=QV_K)
+
+    @pytest.mark.parametrize("case", QV_CASES)
+    def test_pending_queue_matches_scatter_form(self, case):
+        status = _queue_view_status(case)
+        state = _queue_view_state(status)
+        got = np.asarray(jax.jit(
+            lambda s: C.pending_queue(self.params, s))(state))
+        want = np.asarray(scatter_pending_queue(self.params, state))
+        assert got.dtype == np.int32 and got.shape == (QV_K,)
+        np.testing.assert_array_equal(got, want)
+        # and against the plain definition
+        rows = np.flatnonzero(status == C.PENDING)[:QV_K]
+        np.testing.assert_array_equal(got[:len(rows)], rows)
+        assert (got[len(rows):] == -1).all()
+
+    @pytest.mark.parametrize("first", [0, 2, 4])
+    def test_pending_queue_vmapped_matches_scatter_form(self, first):
+        names = (QV_CASES + QV_CASES)[first:first + 4]
+        state = _queue_view_state(
+            np.stack([_queue_view_status(c) for c in names]))
+        got = jax.jit(jax.vmap(
+            lambda s: C.pending_queue(self.params, s)))(state)
+        want = jax.vmap(
+            lambda s: scatter_pending_queue(self.params, s))(state)
+        assert got.shape == (4, QV_K)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("field", ["gpus_int32", "duration_float32",
+                                       "submit_inf_padding"])
+    @pytest.mark.parametrize("case", ["none", "fewer", "more", "all"])
+    def test_queue_rows_matches_masked_gather(self, case, field):
+        rng = np.random.default_rng(11)
+        if field == "gpus_int32":
+            f = rng.integers(1, 33, QV_J).astype(np.int32)
+        elif field == "duration_float32":
+            # values a float32 sum must bring through to the last bit
+            f = (rng.random(QV_J) * 1e5 + 1e-3).astype(np.float32)
+        else:
+            f = np.cumsum(rng.random(QV_J)).astype(np.float32)
+            f[-5:] = np.inf                    # padding rows
+        f = jnp.asarray(f)
+        state = _queue_view_state(_queue_view_status(case))
+        queue = C.pending_queue(self.params, state)
+        got = jax.jit(C.queue_rows)(f, queue)
+        occupied = np.asarray(queue) >= 0
+        want = np.where(occupied,
+                        np.asarray(f)[np.clip(np.asarray(queue), 0, QV_J - 1)],
+                        0)
+        assert got.dtype == f.dtype and got.shape == (QV_K,)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert np.isfinite(np.asarray(got)[~occupied]).all()
+
+    def test_queue_rows_vmapped(self):
+        rng = np.random.default_rng(3)
+        f = jnp.asarray(rng.random((4, QV_J)).astype(np.float32))
+        state = _queue_view_state(
+            np.stack([_queue_view_status(c) for c in QV_CASES[:4]]))
+        queues = jax.vmap(lambda s: C.pending_queue(self.params, s))(state)
+        got = np.asarray(jax.jit(jax.vmap(C.queue_rows))(f, queues))
+        q = np.asarray(queues)
+        want = np.take_along_axis(np.asarray(f), np.clip(q, 0, QV_J - 1),
+                                  axis=1) * (q >= 0)
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("what", ["pending_queue", "queue_rows",
+                                      "action_mask", "grid_obs",
+                                      "queue_features"])
+    def test_lowered_queue_view_has_no_scatter_or_gather(self, what):
+        """Structural guard: nothing that selects or reads the queue view
+        lowers to a scatter or a gather (vmapped over envs, as the rollout
+        runs it). ``grid_obs`` and ``action_mask`` are lowered whole, with
+        the queue passed in: the waterfall and ``jnp.repeat`` lower to
+        neither."""
+        from rlgpuschedule_tpu.env import obs as obs_lib
+        params = self.params
+        E = 3
+        state = _queue_view_state(
+            np.stack([_queue_view_status(c) for c in QV_CASES[:E]]))
+        f32 = jnp.ones((E, QV_J), jnp.float32)
+        trace = C.Trace(submit=f32, duration=f32,
+                        gpus=jnp.ones((E, QV_J), jnp.int32),
+                        tenant=jnp.zeros((E, QV_J), jnp.int32),
+                        valid=jnp.ones((E, QV_J), bool))
+        queues = jnp.zeros((E, QV_K), jnp.int32)
+        fn, args = {
+            "pending_queue": (lambda s: C.pending_queue(params, s), (state,)),
+            "queue_rows": (C.queue_rows, (f32, queues)),
+            "action_mask": (lambda s, t, q: C.action_mask(params, s, t, q),
+                            (state, trace, queues)),
+            "grid_obs": (lambda s, t, q: obs_lib.grid_obs(
+                params, s, t, 100.0, q), (state, trace, queues)),
+            "queue_features": (lambda s, t, q: obs_lib.queue_features(
+                params, s, t, q), (state, trace, queues)),
+        }[what]
+        text = jax.jit(jax.vmap(fn)).lower(*args).as_text()
+        # the words looked for are the ones the old forms lower to
+        old_forms = jax.jit(jax.vmap(lambda s, f, q: (
+            scatter_pending_queue(params, s), f[jnp.clip(q, 0, QV_J - 1)]))
+            ).lower(state, f32, queues).as_text()
+        assert "scatter" in old_forms and "gather" in old_forms
+        assert "scatter" not in text
+        assert "gather" not in text
+
+
 def run_pair(trace, n_nodes, gpus_per_node, actions, queue_len,
              n_placements=2, preempt_len=0):
     """Drive oracle and JAX sim with the same action sequence; compare
