@@ -37,8 +37,37 @@ def load_cell(workload: str) -> dict:
 
 
 def load_module(kind: str, name: str):
-    """``drivers/<name>.py`` or ``readers/<name>.py``, by name."""
+    """``drivers/<name>.py``, ``readers/<name>.py`` or
+    ``reference/<name>.py``, by name."""
     return importlib.import_module(f"benchmark.{kind}.{name}")
+
+
+class Reference:
+    """A configuration's plain reference, resolved ONCE from its file: the
+    module ``reference/<name>.py`` that the file's ``reference`` key names,
+    and the settings that module is handed with every call: the file's
+    top-level keys in a run, overlaid by its ``rehearse_trunk`` in a
+    rehearsal. Which keys a reference reads is that reference's business;
+    the harness requires none of them, looks at no other configuration's
+    file and asks the program nothing."""
+
+    def __init__(self, config: dict, rehearse: bool = False):
+        if "reference" not in config:
+            raise SystemExit(
+                f"benchmark: configuration {config.get('name')!r} names no "
+                f"'reference' (a module of benchmark/reference/)")
+        self.name = config["reference"]
+        self.module = load_module("reference", self.name)
+        self.settings = dict(config)
+        if rehearse:
+            self.settings.update(config.get("rehearse_trunk", {}))
+
+    def trunk(self, encoder, obs, quant=None):
+        """One pooled vector a row from the encoder's leaves."""
+        return self.module.trunk(encoder, obs, quant, self.settings)
+
+    def forward_flops_per_row(self, params) -> float:
+        return self.module.forward_flops_per_row(params, self.settings)
 
 
 def log(**fields) -> None:

@@ -196,7 +196,7 @@ class TrainCell:
                 sim.queue_len, exp.env_params.horizon,
                 exp.env_params.reward_scale, exp.env_params.place_bonus)
         build = lambda **kw: ppo_ref.Follower(
-            cfg.obs_kind, self.hyper(), self.params0, block, **kw)
+            self.ctx.reference, self.hyper(), self.params0, block, **kw)
         follower = build()
         norms = jax.jit(lambda tree: [
             jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
@@ -385,47 +385,52 @@ def stages(cell: TrainCell) -> dict:
     """What the per-layer readers read in a traced run: the program's
     rollout, advantage and update, each jitted alone at the cell's shape
     (``profile_breakdown``'s pattern), a resample, and the update's FLOPs
-    from shapes."""
-    import importlib
+    from shapes (counted by the configuration's own reference).
 
+    ONE copy of the train state: the update takes the program's own,
+    donated and threaded (the box and ``exp`` always hold the live one),
+    and ``rollout`` and ``advantage`` read the state the box holds. The
+    stages are listed in the order they are to be timed in
+    (``readers/stage_time`` times a stage's predecessors before it):
+    ``rollout`` and ``advantage`` on the state the window left, before the
+    first ``update`` moves it; ``resample`` last, since it re-cuts the
+    windows (the rollout keeps the window's own carry and traces)."""
     import jax
     from rlgpuschedule_tpu.algos.ppo import (compute_advantages,
                                               run_ppo_epochs)
     from rlgpuschedule_tpu.algos.update import make_update_step
 
     exp, cfg = cell.exp, cell.cfg
-    params = exp.train_state.params
-    carry = cell.copy(exp.carry)
+    box = {"state": exp.train_state}
+    carry, traces = cell.copy(exp.carry), exp.traces
     rollout = cell.rollout_alone()
     _, tr, last_value = jax.block_until_ready(
-        rollout(params, carry, exp.traces, exp.faults))
+        rollout(box["state"].params, carry, traces, exp.faults))
     adv_jit = jax.jit(lambda state, tr, lv: compute_advantages(
         exp.apply_fn, cfg.ppo, state, tr, lv)[1:3])
-    adv, ret = jax.block_until_ready(
-        adv_jit(exp.train_state, tr, last_value))
+    adv, ret = jax.block_until_ready(adv_jit(box["state"], tr, last_value))
     upd = make_update_step(lambda state, tr, adv, ret, key: run_ppo_epochs(
         exp.apply_fn, cfg.ppo, state, tr, adv, ret, key,
         lambda s, g: s.apply_gradients(grads=g)))
-    box = {"state": cell.copy(exp.train_state)}
     key = jax.random.PRNGKey(0)
 
     def update():
         box["state"], m = upd(box["state"], tr, adv, ret, key)
+        exp.train_state = box["state"]      # the donated one is dead
         jax.block_until_ready(m)
 
-    fwd = importlib.import_module(
-        f"benchmark.reference.forward_{cfg.obs_kind}").forward_flops_per_row
     rows = cfg.ppo.n_steps * cfg.n_envs
+    fwd = cell.ctx.reference.forward_flops_per_row(box["state"].params)
     return {
         "stages": {
             "rollout": lambda: jax.block_until_ready(
-                rollout(params, carry, exp.traces, exp.faults)),
+                rollout(box["state"].params, carry, traces, exp.faults)),
             "advantage": lambda: jax.block_until_ready(
-                adv_jit(exp.train_state, tr, last_value)),
+                adv_jit(box["state"], tr, last_value)),
             "update": update,
             "resample": lambda: (exp.advance_windows(),
                                  jax.block_until_ready(exp.carry)),
         },
         # forward + backward (2x forward) over every row, every epoch
-        "flops": {"update": 3.0 * fwd(params) * rows * cfg.ppo.n_epochs},
+        "flops": {"update": 3.0 * fwd * rows * cfg.ppo.n_epochs},
     }

@@ -126,8 +126,8 @@ def main(argv=None) -> int:
         max_grad_norm=p.max_grad_norm, n_epochs=p.n_epochs,
         n_minibatches=p.n_minibatches)
     follower = ppo_ref.Follower(
-        cfg.obs_kind, hyper, params0,
-        int(loaded["config"]["reference_block_rows"]))
+        common.Reference(loaded["config"], args.rehearse_cpu), hyper,
+        params0, int(loaded["config"]["reference_block_rows"]))
     jax.block_until_ready(follower.params)
     read("follower_built")
     out = follower.step(traj, jax.random.PRNGKey(7))

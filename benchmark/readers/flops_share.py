@@ -1,6 +1,6 @@
 """Reader ``flops_share``: model FLOP/s utilisation of one stage in % -
 the FLOPs the stage needs (from shapes, ``probe["flops"][flops]``, counted
-by ``reference/forward_<obs_kind>.py``) over the stage's measured time,
+by the configuration's own ``reference/forward_<name>.py``) over the stage's measured time,
 over the device's peak from ``peaks.json``. Not a roofline share of a
 kernel: it is named ``mfu``."""
 from __future__ import annotations

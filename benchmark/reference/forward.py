@@ -1,6 +1,10 @@
-"""Plain float32 forward passes of the two policy networks, one function
-per ``obs_kind``; ``jax.numpy``/``jax.lax`` only, no flax, no program
-import. Called under ``jax.default_matmul_precision("highest")`` by
+"""Plain float32 forward passes of the policy networks, one trunk module
+per reference a configuration's file names (``forward_<name>.py``: its
+``trunk(encoder, obs, quant, settings)`` and ``forward_flops_per_row(params,
+settings)`` get the file's settings as an argument); ``jax.numpy``/
+``jax.lax`` only, no flax, no program import. ``ref`` below is that
+reference as ``benchmark.common.Reference`` resolved it, once, from the
+file. Called under ``jax.default_matmul_precision("highest")`` by
 :func:`forward` (on a TPU a float32 matmul is otherwise one bf16 pass).
 
 ``quant`` is the lower-precision control's hook: it is applied to every
@@ -15,8 +19,6 @@ trunk = two dense-256 blocks; heads = dense(n_actions) and dense(1) on the
 trunk output; infeasible actions get logit -1e9.
 """
 from __future__ import annotations
-
-import importlib
 
 import jax
 import jax.numpy as jnp
@@ -67,18 +69,11 @@ def conv(x, p, strides, quant):
     return y + p["bias"]
 
 
-def trunk_of(obs_kind: str):
-    """``reference/forward_<obs_kind>.py`` is found by name, so a later
-    configuration with a new kind of observation adds a file."""
-    return importlib.import_module(
-        f"{__package__}.forward_{obs_kind}").trunk
-
-
-def forward(obs_kind: str, params, obs, mask, quant=None):
+def forward(ref, params, obs, mask, quant=None):
     """``(masked_logits f32[B, A], value f32[B])`` for rows ``obs[B, ...]``."""
     p = params["params"]
     with jax.default_matmul_precision("highest"):
-        h = trunk_of(obs_kind)(p["encoder"], obs, quant)
+        h = ref.trunk(p["encoder"], obs, quant)
         logits = dense(h, p["policy"], quant)
         value = dense(h, p["value"], quant)[..., 0]
     return jnp.where(mask, logits, NEG_INF), value
@@ -95,7 +90,7 @@ def entropy(logits):
     return -jnp.sum(p * jnp.where(p > 0, logp, 0.0), axis=-1)
 
 
-def forward_blocks(obs_kind, params, obs, mask, block, quant=None):
+def forward_blocks(ref, params, obs, mask, block, quant=None):
     """Forward over many rows in blocks of ``block`` (one ``lax.map``), so
     float32 activations of a block, not of the whole set, are live."""
     n = obs.shape[0]
@@ -109,6 +104,6 @@ def forward_blocks(obs_kind, params, obs, mask, block, quant=None):
     obs = obs.reshape(nb, block, *obs.shape[1:])
     mask = mask.reshape(nb, block, *mask.shape[1:])
     logits, value = jax.lax.map(
-        lambda om: forward(obs_kind, params, om[0], om[1], quant),
+        lambda om: forward(ref, params, om[0], om[1], quant),
         (obs, mask))
     return (logits.reshape(nb * block, -1)[:n], value.reshape(-1)[:n])

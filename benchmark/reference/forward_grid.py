@@ -1,5 +1,6 @@
-"""Plain float32 trunk for ``obs_kind = grid`` (see ``forward.py``), and the
-FLOPs its forward pass needs per row, from shapes alone."""
+"""Plain float32 trunk of the grid-CNN policy (see ``forward.py``), and the
+FLOPs its forward pass needs per row, from shapes alone: every size is a
+leaf's, so the configuration's ``settings`` are taken and not read."""
 from __future__ import annotations
 
 import jax
@@ -8,7 +9,7 @@ import jax.numpy as jnp
 from .forward import conv, dense, layer_norm
 
 
-def trunk(enc, obs, quant):
+def trunk(enc, obs, quant, settings=None):
     x = obs.astype(jnp.float32)
     for i in range(3):
         x = conv(x, enc[f"Conv_{i}"], (2, 1) if i else (1, 1), quant)
@@ -18,7 +19,7 @@ def trunk(enc, obs, quant):
     return jax.nn.silu(layer_norm(x, enc["LayerNorm_3"]))
 
 
-def forward_flops_per_row(params) -> float:
+def forward_flops_per_row(params, settings=None) -> float:
     """Multiply-adds x2 of the convolutions (counted per OUTPUT position:
     a conv kernel is reused at every grid position), the dense block and
     the two heads, for one observation row. ``params`` may be shapes.

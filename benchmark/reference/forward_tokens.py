@@ -1,6 +1,6 @@
-"""Plain float32 trunk for ``obs_kind = tokens`` (see ``forward.py``): the
-``afmoe`` block over one token per cluster node and per job, and the FLOPs
-its forward pass needs per row. ``jax.numpy`` only: no flax, no program
+"""Plain float32 ``afmoe`` trunk over the token observation (see
+``forward.py``): the block over one token per cluster node and per job,
+and the FLOPs its forward pass needs per row. ``jax.numpy`` only: no flax, no program
 import, no sort, no grouped product, no kernel.
 
 Per layer ``i``, ``x`` ``[T, d]``, every norm an RMSNorm (eps from the
@@ -27,19 +27,17 @@ the last layer: final RMSNorm, mean over valid tokens. Input: the token
 features through ``embed`` times sqrt(d).
 
 What no leaf's shape says (layer types, window, k, route scale and norm,
-theta, eps, the first held expert, and the tokens a row holds) is read
-from the ``benchmark/configs/*.json`` whose ``obs_kind`` is ``tokens``:
-the top-level keys at the published hidden size, the file's
-``rehearse_trunk`` at the rehearsal's. A ``benchmark`` PR should have the
-driver pass the configuration in (``stages`` hands over ``params`` only).
+theta, eps, the count of leading dense layers, the first held expert, and
+the tokens a row holds) comes in ``settings``, which the harness resolves
+from the configuration's file (``benchmark.common.Reference``: the file's
+top-level keys in a run, overlaid by its ``rehearse_trunk`` in a
+rehearsal) and hands to ``trunk`` and ``forward_flops_per_row``. But for
+the shim at its end this module looks at no file, and at no other
+configuration ever.
 """
 from __future__ import annotations
 
-import functools
-import glob
-import json
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -47,39 +45,6 @@ import jax.numpy as jnp
 from .forward import _q
 
 SLIDING = "sliding_attention"
-KEYS = ("hidden_size", "layer_types", "sliding_window",
-        "num_experts_per_tok", "route_scale", "route_norm", "rope_theta",
-        "rms_norm_eps", "num_dense_layers", "experts_held_first",
-        "tokens_per_row")
-
-
-@functools.lru_cache(maxsize=None)
-def specs() -> tuple:
-    """The trunk settings each tokens configuration states: its published
-    ones and its rehearsal's (read once a process)."""
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = []
-    for path in sorted(glob.glob(os.path.join(here, "configs", "*.json"))):
-        with open(path) as f:
-            cfg = json.load(f)
-        if cfg.get("obs_kind") != "tokens":
-            continue
-        out.append({k: cfg[k] for k in KEYS})
-        out.append({k: cfg["rehearse_trunk"][k] for k in KEYS})
-    return tuple(out)
-
-
-def spec_for(encoder) -> dict:
-    """The one stated setting whose hidden size is this tree's; files that
-    state the same size must agree."""
-    d = encoder["embed"]["kernel"].shape[-1]
-    found = [s for s in specs() if s["hidden_size"] == d]
-    if not found:
-        raise ValueError(f"no tokens configuration states hidden size {d}")
-    if any(s != found[0] for s in found[1:]):
-        raise ValueError(f"tokens configurations disagree at hidden size "
-                         f"{d}: {found}")
-    return found[0]
 
 
 def rms_norm(x, p, eps):
@@ -177,8 +142,8 @@ def expert_layer(p, x, spec: dict, quant):
     return gated_mlp(p["shared"], x, quant) + experts(p, x, spec, quant)
 
 
-def trunk(enc, obs, quant, spec: "dict | None" = None):
-    spec = spec_for(enc) if spec is None else spec
+def trunk(enc, obs, quant, settings: "dict | None" = None):
+    spec = legacy_settings(enc) if settings is None else settings
     eps = spec["rms_norm_eps"]
     obs = obs.astype(jnp.float32)
     valid = obs[..., -1] > 0.5
@@ -200,7 +165,7 @@ def trunk(enc, obs, quant, spec: "dict | None" = None):
     return jnp.sum(x * m, axis=-2) / jnp.maximum(jnp.sum(m, axis=-2), 1.0)
 
 
-def forward_flops_per_row(params) -> float:
+def forward_flops_per_row(params, settings: dict) -> float:
     """Multiply-adds x2 of one observation row's forward pass, from
     shapes, the stated window and the tokens a row holds (T): every
     projection and both heads; attention scores and their product with
@@ -211,8 +176,7 @@ def forward_flops_per_row(params) -> float:
     be shapes."""
     p = params["params"]
     enc = p["encoder"]
-    spec = spec_for(enc)
-    T = spec["tokens_per_row"]
+    T = settings["tokens_per_row"]
     size = lambda leaf: math.prod(leaf.shape)
     per_token = size(enc["embed"]["kernel"])
     pairs = 0.0
@@ -224,11 +188,11 @@ def forward_flops_per_row(params) -> float:
                          ("q_proj", "k_proj", "v_proj", "gate_proj",
                           "o_proj"))
         width = a["q_proj"]["kernel"].shape[-1]          # Hq * D
-        window = (spec["sliding_window"]
-                  if spec["layer_types"][i] == SLIDING else T)
+        window = (settings["sliding_window"]
+                  if settings["layer_types"][i] == SLIDING else T)
         seen_pairs = sum(min(q + 1, window) for q in range(T))
         pairs += 2.0 * width * seen_pairs                # scores, values
-        if i < spec["num_dense_layers"]:
+        if i < settings["num_dense_layers"]:
             per_token += sum(size(lp["mlp"][n]["kernel"])
                              for n in ("gate", "up", "down"))
         else:
@@ -241,6 +205,31 @@ def forward_flops_per_row(params) -> float:
                        ("experts_gate", "experts_up", "experts_down"))
             # an assignment passes one expert: held / count parameters;
             # a token makes k * count / published of them here
-            per_token += held * spec["num_experts_per_tok"] / n_published
+            per_token += held * settings["num_experts_per_tok"] / n_published
     heads = size(p["policy"]["kernel"]) + size(p["value"]["kernel"])
     return 2.0 * (T * per_token + pairs + heads)
+
+
+# ---- kept for tests/test_trunk.py alone ------------------------------
+# Two tier-1 tests there still call ``specs()`` and ``trunk(enc, obs,
+# quant)`` without settings, and a ``benchmark`` PR may not edit
+# ``tests/`` (ISSUE 35; PERF.md section 7). Nothing under ``benchmark/``
+# calls either: the harness hands the settings over. The shim names the
+# ONE file this reference was written for, so no other configuration's
+# file can break it; it goes when those two tests are rewritten.
+KEYS = ("hidden_size", "layer_types", "sliding_window",
+        "num_experts_per_tok", "route_scale", "route_norm", "rope_theta",
+        "rms_norm_eps", "num_dense_layers", "experts_held_first",
+        "tokens_per_row")
+
+
+def specs() -> tuple:
+    from benchmark.common import Reference, load_json
+    config = load_json("configs", "philly512-trinity.json")
+    return tuple({k: Reference(config, rehearse).settings[k] for k in KEYS}
+                 for rehearse in (False, True))
+
+
+def legacy_settings(encoder) -> dict:
+    d = encoder["embed"]["kernel"].shape[-1]
+    return next(s for s in specs() if s["hidden_size"] == d)

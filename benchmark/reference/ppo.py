@@ -56,10 +56,10 @@ def adam_init(params) -> AdamState:
     return AdamState(jnp.zeros((), jnp.int32), z(), z())
 
 
-def _loss_sum(obs_kind, params, blk, hp: Hyper, quant):
+def _loss_sum(ref, params, blk, hp: Hyper, quant):
     """Sums (not means) over one block of rows, so blocks add up."""
     obs, mask, action, logp_old, v_old, adv, ret = blk
-    logits, value = forward(obs_kind, params, obs, mask, quant)
+    logits, value = forward(ref, params, obs, mask, quant)
     logp = log_prob(logits, action)
     ratio = jnp.exp(logp - logp_old)
     pg = -jnp.minimum(ratio * adv,
@@ -70,9 +70,9 @@ def _loss_sum(obs_kind, params, blk, hp: Hyper, quant):
     return jnp.sum(pg + hp.vf_coef * vl - hp.ent_coef * ent)
 
 
-def make_update(obs_kind: str, hp: Hyper, block: int, quant=None,
-                fault=None):
-    """Jittable ``(params, adam, data[B,...], key) -> (params', adam',
+def make_update(ref, hp: Hyper, block: int, quant=None, fault=None):
+    """``ref``: the configuration's reference as
+    ``benchmark.common.Reference`` resolved it. Jittable ``(params, adam, data[B,...], key) -> (params', adam',
     losses[n_epochs, n_minibatches])``; gradients of a minibatch are
     accumulated over blocks of ``block`` rows. ``fault``: one of
     ``FAULTS``, planted for a control."""
@@ -90,7 +90,7 @@ def make_update(obs_kind: str, hp: Hyper, block: int, quant=None,
 
         def acc(c, blk):
             loss, grads = jax.value_and_grad(
-                lambda p: _loss_sum(obs_kind, p, blk, hp, quant))(params)
+                lambda p: _loss_sum(ref, p, blk, hp, quant))(params)
             return (c[0] + loss, jax.tree.map(jnp.add, c[1], grads)), None
 
         zero = (jnp.zeros((), jnp.float32),
@@ -140,14 +140,13 @@ def make_update(obs_kind: str, hp: Hyper, block: int, quant=None,
     return update
 
 
-def make_behaviour(obs_kind: str, block: int, quant=None):
+def make_behaviour(ref, block: int, quant=None):
     """Jittable ``(params, obs[B,...], mask[B,A], action[B], last_obs[E,...],
     last_mask[E,A]) -> (log_prob[B], value[B], last_value[E])``."""
 
     def behaviour(params, obs, mask, action, last_obs, last_mask):
-        logits, value = forward_blocks(obs_kind, params, obs, mask, block,
-                                       quant)
-        _, last_value = forward_blocks(obs_kind, params, last_obs, last_mask,
+        logits, value = forward_blocks(ref, params, obs, mask, block, quant)
+        _, last_value = forward_blocks(ref, params, last_obs, last_mask,
                                        block, quant)
         return log_prob(logits, action), value, last_value
 
@@ -161,15 +160,15 @@ class Follower:
     that the scans' carries alias the arguments and the follower's peak is
     its state once, not twice; ``release`` frees them."""
 
-    def __init__(self, obs_kind: str, hp: Hyper, params, block: int,
-                 quant=None, fault=None):
+    def __init__(self, ref, hp: Hyper, params, block: int, quant=None,
+                 fault=None):
         self.hp = hp
         self.params = jax.tree.map(jnp.array, params)
         self.adam = adam_init(self.params)
         self.memory: dict | None = None
-        self._behaviour = jax.jit(make_behaviour(obs_kind, block, quant))
-        self._update = jax.jit(make_update(obs_kind, hp, block, quant,
-                                           fault), donate_argnums=(0, 1))
+        self._behaviour = jax.jit(make_behaviour(ref, block, quant))
+        self._update = jax.jit(make_update(ref, hp, block, quant, fault),
+                               donate_argnums=(0, 1))
         self._compiled = None
 
     def step(self, traj: dict, key) -> dict:

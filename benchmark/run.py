@@ -50,6 +50,9 @@ class Context:
         self.seconds = args.seconds
         self.trace = bool(args.trace)
         self.rehearse = args.rehearse_cpu
+        from benchmark.common import Reference
+        # resolved once, from this cell's own file
+        self.reference = Reference(self.config, self.rehearse)
         self.trace_seconds = min(
             args.seconds, float(self.traffic.get("trace_seconds", 2.0)))
         self.device = device
@@ -128,7 +131,7 @@ def per_layer(ctx: Context, result: dict, reduced: "dict | None") -> dict:
     from benchmark.common import load_module, log
     probe = dict(result.get("probe", {}))
     probe.update(trace=reduced, memory_peak_bytes=ctx.memory_peak_bytes,
-                 device=ctx.device, cache={})
+                 device=ctx.device, config=ctx.config, cache={})
     out = {}
     for path in sorted(glob.glob(os.path.join(BENCH_DIR, "layer_metrics",
                                               "*.json"))):
@@ -235,6 +238,13 @@ def execute(args, loaded: dict, device: dict) -> "tuple[dict, object]":
             line["breakdown"] = {"device_ops": reduced["device_ops"][:10],
                                  "idle_gaps": reduced["idle_gaps"][:10]}
         line["metrics"] = per_layer(ctx, result, reduced)
+        import jax
+        stats = jax.local_devices()[0].memory_stats() or {}
+        # the traced run's largest moment, the stages' included (the
+        # ``memory`` line above is the window's)
+        common.log(phase="memory_after_stages", **{
+            k: stats.get(k) for k in ("bytes_in_use", "peak_bytes_in_use",
+                                      "peak_bytes_reserved")})
     line["checks"] = checks.compared()      # last in the line
     return line, checks
 

@@ -7,8 +7,8 @@
    busy time, window and top operation are known (and recomputed here by
    brute force);
 2. each plain reference against the program at a tiny shape: the forward
-   pass of every ``reference/forward_<obs_kind>.py`` against the program's
-   module in float32, GAE against its scan,
+   pass of ``reference/forward_grid.py`` against the program's module in
+   float32 (``forward_tokens.py``: ``tests/test_trunk.py``), GAE against its scan,
    one whole PPO iteration's learning half (loss, Adam average, parameters)
    against its learn step in float32;
 3. ``run.py --rehearse-cpu`` for every cell of ``BENCHMARK.json``, both
@@ -85,6 +85,7 @@ def check_references() -> None:
     from rlgpuschedule_tpu.ops.gae import compute_gae
     from flax.training.train_state import TrainState
 
+    from benchmark.common import Reference
     from benchmark.reference import gae as gae_ref
     from benchmark.reference import ppo as ppo_ref
     from benchmark.reference import weights
@@ -92,8 +93,10 @@ def check_references() -> None:
 
     T, E, A = 8, 16, 9
     key = jax.random.PRNGKey(3)
-    # a tiny observation per ``reference/forward_<obs_kind>.py``
+    # a tiny observation, the program's policy for it, and the reference
+    # a configuration's file would name for it
     for kind, shape in (("grid", (16, 4, 2)),):
+        ref = Reference({"reference": f"forward_{kind}"})
         net = make_policy(kind, A, dtype=jnp.float32)
         k1, k2, k3, k4, k5 = jax.random.split(key, 5)
         obs = jax.random.uniform(k1, (T, E, *shape))
@@ -103,7 +106,7 @@ def check_references() -> None:
         apply_fn = lambda p, o, m: net.apply(p, o, m)
         with jax.default_matmul_precision("highest"):
             lg, v = apply_fn(params, obs[0], mask[0])
-        lr, vr = forward(kind, params, obs[0], mask[0])
+        lr, vr = forward(ref, params, obs[0], mask[0])
         gap = float(jnp.max(jnp.abs(jnp.where(mask[0], lg - lr, 0.0))))
         report(f"forward_{kind}.logits", gap < 1e-5, f"gap {gap:.2e}")
         report(f"forward_{kind}.value",
@@ -138,7 +141,7 @@ def check_references() -> None:
                            cfg.vf_coef, cfg.ent_coef, cfg.lr,
                            cfg.max_grad_norm, cfg.n_epochs,
                            cfg.n_minibatches)
-        fol = ppo_ref.Follower(kind, hp, params, block=8)
+        fol = ppo_ref.Follower(ref, hp, params, block=8)
         ref = fol.step({"obs": obs, "mask": mask, "action": action,
                         "reward": reward, "done": done,
                         "last_obs": obs[-1], "last_mask": mask[-1]}, key)

@@ -6,6 +6,7 @@ import jax
 import numpy as np
 import pytest
 
+from benchmark.common import Reference
 from benchmark.drivers import train_loop
 from benchmark.reference import ppo as ppo_ref
 from benchmark.tests.test_broken_path import _execute
@@ -62,6 +63,7 @@ def test_no_program_state_on_the_device_while_the_follower_steps(
 
 
 HYPER = ppo_ref.Hyper(0.99, 0.95, 0.2, 0.5, 0.01, 3e-4, 0.5, 2, 2)
+GRID = Reference({"reference": "forward_grid"})
 
 
 def _tiny_policy_and_trajectory():
@@ -88,7 +90,7 @@ def test_followers_update_aliases_its_state_and_spares_the_callers():
     """At the parent ``alias_size_in_bytes`` is 0 and arguments and outputs
     are held side by side (24 B a parameter before one temporary)."""
     params, traj = _tiny_policy_and_trajectory()
-    fol = ppo_ref.Follower("grid", HYPER, params, block=8)
+    fol = ppo_ref.Follower(GRID, HYPER, params, block=8)
     fol.step(traj, jax.random.PRNGKey(1))
     m = fol.memory
     assert m["param_count"] == sum(x.size for x in jax.tree.leaves(params))
@@ -110,9 +112,9 @@ def test_donated_update_reads_what_the_parents_update_read():
     aliased) and this one, in one process on the same trajectories: every
     reading and every parameter bit for bit, whatever the jax."""
     params, traj = _tiny_policy_and_trajectory()
-    donated = ppo_ref.Follower("grid", HYPER, params, block=8)
-    plain = ppo_ref.Follower("grid", HYPER, params, block=8)
-    plain._update = jax.jit(ppo_ref.make_update("grid", HYPER, 8))
+    donated = ppo_ref.Follower(GRID, HYPER, params, block=8)
+    plain = ppo_ref.Follower(GRID, HYPER, params, block=8)
+    plain._update = jax.jit(ppo_ref.make_update(GRID, HYPER, 8))
     for k in range(2):
         a = donated.step(traj, jax.random.PRNGKey(k))
         b = plain.step(traj, jax.random.PRNGKey(k))
@@ -140,6 +142,10 @@ def test_traced_rehearsal_reads_every_stage_on_the_state_put_back(
     def watched_unpark(cell):
         unpark(cell)
         seen["cell"] = cell
+        # put back bit for bit, before the update stage moves it on
+        seen["back"] = [np.asarray(x)
+                        for x in jax.tree.leaves(cell.exp.train_state)]
+        seen["step"] = int(cell.exp.train_state.step)
 
     monkeypatch.setattr(train_loop.TrainCell, "park", watched_park)
     monkeypatch.setattr(train_loop.TrainCell, "unpark", watched_unpark)
@@ -147,12 +153,15 @@ def test_traced_rehearsal_reads_every_stage_on_the_state_put_back(
     for stage in ("rollout_ms.train", "advantage_ms.train",
                   "update_ms.train", "resample_ms.train"):
         assert line["metrics"][stage]["value"] > 0, line["metrics"]
-    back = seen["cell"].exp
     assert all(isinstance(x, np.ndarray) for x in seen["parked"])
-    # put back bit for bit (the carry has moved on since: a resample)
-    for host, dev in zip(seen["parked"], jax.tree.leaves(back.train_state)):
-        assert isinstance(dev, jax.Array) and not dev.is_deleted()
-        np.testing.assert_array_equal(host, np.asarray(dev))
+    for host, back in zip(seen["parked"], seen["back"]):
+        np.testing.assert_array_equal(host, back)
+    # ONE copy of the state: the update stage took the program's own,
+    # donated, and left the program the live one, moved on
+    state = seen["cell"].exp.train_state
+    assert all(isinstance(x, jax.Array) and not x.is_deleted()
+               for x in jax.tree.leaves(state))
+    assert int(state.step) > seen["step"]
 
 
 @pytest.mark.skipif(jax.__version__ != PARENT_JAX,

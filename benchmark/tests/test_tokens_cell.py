@@ -2,9 +2,18 @@
 the configuration's tiny shape through ``run.execute``: every check is
 read and holds; a fault planted in the PROGRAM underneath a whole run
 (one held expert computing nothing) turns ``correct`` false by a number
-the ledger names; the trunk's scope reader keeps the program's names and
-leaves its metrics out where there is nothing to read."""
+the ledger names; the trunk's scope reader takes the program's names from
+the configuration's file and leaves its metrics out where there is nothing
+to read. The sound rehearsal runs beside a second ``tokens`` configuration
+file that has none of this trunk's keys (ISSUE 35): nothing in the harness
+looks at a file the cell does not name. That file is written into a copy
+of the harness's data under ``tmp_path`` (``common.BENCH_DIR``, where
+``load_json`` finds ``configs/``, ``traffic/`` and ``peaks.json``), never
+into the checkout."""
 import argparse
+import json
+import os
+import shutil
 
 import pytest
 
@@ -34,14 +43,29 @@ def _execute(seed: int = 5, trace: int = 0):
     return line, checks
 
 
+# a later configuration's file, as far as this cell is concerned: the same
+# ``obs_kind``, its own reference, none of the ``afmoe`` keys
+STRANGER = {"name": "zz-test-tokens-stranger", "obs_kind": "tokens",
+            "reference": "forward_of_another_trunk", "hidden_size": 64}
+
+
 @pytest.fixture(scope="module")
-def sound():
-    return _execute()
+def sound(tmp_path_factory):
+    data = tmp_path_factory.mktemp("benchmark")
+    for name in ("configs", "traffic"):
+        shutil.copytree(os.path.join(common.BENCH_DIR, name), data / name)
+    shutil.copy(os.path.join(common.BENCH_DIR, "peaks.json"), data)
+    with open(data / "configs" / (STRANGER["name"] + ".json"), "w") as f:
+        json.dump(STRANGER, f)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(common, "BENCH_DIR", str(data))
+        return _execute()
 
 
 def test_the_cells_files_are_found_by_name():
     loaded = common.load_cell(CELL)
     assert loaded["config"]["obs_kind"] == "tokens"
+    assert loaded["config"]["reference"] == "forward_tokens"
     assert loaded["config"]["preset"] == "ppo-trinity-philly512"
     assert loaded["traffic"]["driver"] == "train_loop"
     assert loaded["cell"]["chips"] == 1
@@ -101,12 +125,13 @@ def test_traced_rehearsal_reads_the_stages_and_leaves_the_trunk_scopes_out():
                    for name in line["metrics"])
 
 
-def test_the_reader_keeps_the_programs_names():
+NAMES = frozenset(common.load_cell(CELL)["config"]["trunk_scopes"])
+
+
+def test_the_configuration_file_lists_the_programs_names():
     from rlgpuschedule_tpu.obs import scopes
-    assert trunk_scope_time.NAMES == {n for path in scopes.TRUNK_TREE
-                                      for n in path}
-    assert not trunk_scope_time.NAMES & {n for path in xplane_scopes.TREE
-                                         for n in path}
+    assert NAMES == {n for path in scopes.TRUNK_TREE for n in path}
+    assert not NAMES & {n for path in xplane_scopes.TREE for n in path}
 
 
 def _events(ops):
@@ -124,7 +149,8 @@ def _reduce_trunk(events):
     plane = "/device:TPU:0"
     return trunk_scope_time.reduce_trunk(
         xplane_scopes.reduce_scopes(events, [plane]),
-        {op: op_name for op, _, _, op_name, _ in events["devices"][plane]})
+        {op: op_name for op, _, _, op_name, _ in events["devices"][plane]},
+        NAMES)
 
 
 def test_the_reader_adds_both_forward_passes_of_a_scope():
@@ -152,5 +178,10 @@ def test_the_reader_returns_nothing_without_a_trunk():
     one: no operation carries a name, and the metric is left out."""
     sim = "jit(train_step)/rollout/while/body/env_step/vmap(observe)/add"
     assert _reduce_trunk(_events([sim, sim])) is None
-    assert trunk_scope_time.read({"trace": None}, {"under": ["trunk"]}) \
+    config = {"trunk_scopes": sorted(NAMES)}
+    assert trunk_scope_time.read({"trace": None, "config": config},
+                                 {"under": ["trunk"]}) is None
+    # a configuration that lists no names has no such metric: the trace is
+    # not even looked at
+    assert trunk_scope_time.read({"config": {}}, {"under": ["trunk"]}) \
         is None
