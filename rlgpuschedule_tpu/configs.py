@@ -45,10 +45,12 @@ class ExperimentConfig:
     preempt_len: int = 0                # >0 = preemptive RL action space
     n_pods: int = 1                     # >1 = hierarchical env (config 5)
     obs_kind: Literal["flat", "grid", "graph", "tokens"] = "flat"
-    # obs_kind "tokens": which whole set of trunk sizes
-    # (models.trunk.TRUNKS); "published" is the source model's widths,
-    # "tiny" the CPU tests' shape. No flag sets a single width.
-    trunk: Literal["published", "tiny"] = "published"
+    # obs_kind "tokens": which family of blocks at which whole set of
+    # sizes (models.trunk.TRUNKS): "published" / "tiny" the afmoe blocks
+    # at the source model's widths / the CPU tests' shape, "ling" /
+    # "ling-tiny" the linear-attention blocks likewise. No flag sets a
+    # single width.
+    trunk: Literal["published", "tiny", "ling", "ling-tiny"] = "published"
     reward_kind: Literal["jct", "fair"] = "jct"
     n_tenants: int = 1
     nodes_per_rack: int | None = None   # graph topology granularity
@@ -133,6 +135,13 @@ PPO_CNN_PHILLY512 = _register(ExperimentConfig(
 # view's. Train on a CPU host with --trunk tiny.
 PPO_TRINITY_PHILLY512 = _register(dataclasses.replace(
     PPO_CNN_PHILLY512, name="ppo-trinity-philly512", obs_kind="tokens"))
+
+# The same again under the second family of token blocks: five
+# linear-attention (KDA) layers and one latent-attention (MLA) layer a
+# period, 512 experts chosen by groups (one chip's share of a 64-chip
+# expert layout). Train on a CPU host with --trunk ling-tiny.
+PPO_LING_PHILLY512 = _register(dataclasses.replace(
+    PPO_TRINITY_PHILLY512, name="ppo-ling-philly512", trunk="ling"))
 
 # 3. A2C multi-actor on Alibaba PAI trace, multi-tenant fairness reward.
 # Same proxy arrangement as config 2 (PAI-statistics preset).

@@ -60,7 +60,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="must match the training run when restoring a "
                         "checkpoint (same contract as the cluster-shape "
                         "overrides)")
-    p.add_argument("--trunk", default=None, choices=["published", "tiny"],
+    p.add_argument("--trunk", default=None,
+                   choices=["published", "tiny", "ling", "ling-tiny"],
                    help="obs-kind tokens: the trunk sizes the checkpoint "
                         "was trained with (train --trunk)")
     p.add_argument("--drain-frac", type=float, default=None,

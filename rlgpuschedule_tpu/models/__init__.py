@@ -3,8 +3,9 @@ from .encoders import MLPEncoder, CNNEncoder, GNNEncoder
 from .actor_critic import (ActorCritic, GNNActorCritic, make_policy,
                            mask_logits, NEG_INF)
 from .hier import HierActorCritic
-from .trunk import TRUNKS, TokenTrunk, TrunkConfig
+from .trunk import TRUNKS, LingConfig, TokenTrunk, TrunkConfig
 
 __all__ = ["MLPEncoder", "CNNEncoder", "GNNEncoder", "ActorCritic",
            "GNNActorCritic", "make_policy", "mask_logits", "NEG_INF",
-           "HierActorCritic", "TokenTrunk", "TrunkConfig", "TRUNKS"]
+           "HierActorCritic", "TokenTrunk", "TrunkConfig", "LingConfig",
+           "TRUNKS"]

@@ -89,7 +89,7 @@ def make_policy(obs_kind: str, n_actions: int, *, n_cluster_nodes: int = 0,
                 preempt_len: int = 0, trunk: str = "published",
                 dtype=jnp.bfloat16) -> nn.Module:
     """Encoder-selection factory matching EnvParams.obs_kind. ``trunk``
-    names the token trunk's sizes (``models.trunk.TRUNKS``)."""
+    names the token trunk's family and sizes (``models.trunk.TRUNKS``)."""
     if obs_kind == "flat":
         return ActorCritic(MLPEncoder(dtype=dtype), n_actions)
     if obs_kind == "grid":

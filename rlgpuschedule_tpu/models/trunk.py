@@ -1,9 +1,20 @@
-"""Token trunk (L3): sparse-expert transformer blocks over one token per
-cluster node and per job of the window (``env.obs.token_obs``).
+"""Token trunk (L3): sparse-expert blocks over one token per cluster node
+and per job of the window (``env.obs.token_obs``), of two public
+families. ``TRUNKS`` names each whole set of sizes AND its family:
+``published`` / ``tiny`` the ``afmoe`` blocks below (:class:`TrunkConfig`),
+``ling`` / ``ling-tiny`` the linear-attention blocks further down
+(:class:`LingConfig`). **What the two share:** the token observation,
+:class:`TokenTrunk` (the embedding's linear map, blocks rematerialised and
+taken ``ROW_BLOCK`` rows at a time, the final norm and the mean over valid
+tokens), :class:`RMSNorm`, :class:`GatedMLP`, :class:`ExpertLayer` with
+:func:`routed_experts` and :func:`take_rows` (an expert layer told which
+experts it holds), :func:`attend` / ``ops.attention.blocked_attend`` for a
+softmax score product, the counters and ``ActorCritic``'s heads.
 
-The block is the public ``afmoe`` family's (arcee-ai Trinity; widths and
-``layer_types`` as the family's ``config.json`` keys them, the rest after
-``transformers``' ``models/afmoe``). Per layer ``i``, ``x`` ``[T, d]``::
+**The ``afmoe`` block** is the public ``afmoe`` family's (arcee-ai
+Trinity; widths and ``layer_types`` as the family's ``config.json`` keys
+them, the rest after ``transformers``' ``models/afmoe``). Per layer
+``i``, ``x`` ``[T, d]``::
 
     h = x + post_attn_norm(Attn_i(input_norm(x)))
     y = h + post_mlp_norm(MLP_i(pre_mlp_norm(h)))          all RMSNorm
@@ -26,6 +37,34 @@ tokens. Where a policy departs from the language model: no token ids, so
 a linear map of each token's features (times sqrt(d), as the family's
 ``mup_enabled`` scales its embedding) stands for the embedding, and
 ``ActorCritic``'s heads for the output head.
+
+**The ``ling`` block** (inclusionAI Ling-3.0-flash's language model: its
+``config.json`` keys; what they do not settle follows Kimi Linear,
+arXiv:2510.26692, flash-linear-attention's ``kda`` and ``bailing_moe_v2``,
+and is listed under ``assumed`` in
+``benchmark/configs/philly512-ling.json``). Pre-norm, ``u = norm(x)``::
+
+    h = x + Attn_i(input_norm(x));  y = h + MLP_i(pre_mlp_norm(h))
+
+Layers ``i % layer_group_size != layer_group_size - 1`` are **KDA**
+(:class:`KDA`): ``q~, k~, v = silu(conv4(u W))`` for three projections to
+``H`` heads of ``D`` (``conv4``: depthwise, causal, no bias, over tokens);
+``q = q~ / |q~| / sqrt(D)``, ``k = k~ / |k~|`` a head; a log-decay a
+channel ``g = kda_lower_bound * sigmoid(exp(A_log) * (u W_f + dt_bias))``
+and a write strength a head ``beta = sigmoid(u W_beta)``; the gated delta
+rule over a ``[D, D]`` state a head (``ops.kda``: in chunks, never token by
+token); ``(RMSNorm_head(o) * sigmoid(u W_g)) W_o``. No RoPE. A token with
+``valid = 0`` feeds zeros to the convolutions and leaves the state as it
+was (``beta`` 0, ``g`` 0): the policy's departure, like the pool. The last
+layer of a period is **MLA** (:class:`MLA`): ``[c, r] = u W_kva``;
+``[k_nope, v]_h = RMSNorm(c) W_kvb``; one rope key ``RoPE(RMSNorm(r))`` a
+token for all heads; ``[q_nope, q_rope]_h = RMSNorm_head(u W_q)``, RoPE on
+``q_rope``; causal softmax of ``q . [k_nope, k_rope] / sqrt(dn + dr)``
+over valid keys; each head's output times one scalar ``sigmoid(u
+W_gate)_h``; ``W_o``. Its score product is the shared one: q and k have
+``dn + dr`` = 192 channels and v 128, so the kernel path zero-pads q and k
+to 256 (exact). The expert layers choose **groups before experts**
+(:func:`choose_experts`) and have a shared expert of its own width.
 
 **Two lowerings of the score product**, chosen by
 :func:`attention_path` from what the build can observe and from nothing a
@@ -69,6 +108,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import scopes
+from ..ops import kda
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 COUNTERS = "counters"       # the flax collection the expert layers sow
@@ -109,12 +149,10 @@ class TrunkConfig:
     layer_types: tuple[str, ...] = (SLIDING, SLIDING, SLIDING, FULL, SLIDING)
     experts_held: tuple[int, int] = (0, 8)      # (first, count)
 
+    family = "afmoe"
+
     def __post_init__(self):
-        first, count = self.experts_held
-        if not (0 <= first and count >= 1
-                and first + count <= self.num_experts):
-            raise ValueError(f"experts_held={self.experts_held} is not a "
-                             f"range of the {self.num_experts} experts")
+        _check_experts(self)
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("query heads must be a multiple of KV heads")
         if set(self.layer_types) - {SLIDING, FULL}:
@@ -124,8 +162,85 @@ class TrunkConfig:
     def num_hidden_layers(self) -> int:
         return len(self.layer_types)
 
+    @property
+    def shared_intermediate_size(self) -> int:
+        return self.moe_intermediate_size
 
-TRUNKS: dict[str, TrunkConfig] = {
+    @property
+    def embed_scale(self) -> float:     # as ``mup_enabled`` scales it
+        return math.sqrt(self.hidden_size)
+
+
+def _check_experts(c) -> None:
+    first, count = c.experts_held
+    if not (0 <= first and count >= 1 and first + count <= c.num_experts):
+        raise ValueError(f"experts_held={c.experts_held} is not a "
+                         f"range of the {c.num_experts} experts")
+    groups, kept = expert_groups(c)
+    if c.num_experts % groups or not 1 <= kept <= groups:
+        raise ValueError(f"{kept} of {groups} groups do not divide "
+                         f"{c.num_experts} experts")
+    if kept * (c.num_experts // groups) < c.num_experts_per_tok:
+        raise ValueError("the groups kept hold fewer experts than a token "
+                         "chooses")
+
+
+def expert_groups(c) -> tuple[int, int]:
+    """``(n_group, topk_group)`` of a trunk's router; a family that states
+    neither (``afmoe``) has one group: a flat top-k."""
+    return getattr(c, "n_group", 1), getattr(c, "topk_group", 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class LingConfig:
+    """The second family (module docstring): the defaults are
+    Ling-3.0-flash's published widths at this repo's cut: one whole period
+    (five KDA layers, one MLA layer), the first of them dense, experts 0-7
+    of 512 held (one chip of 64). Field names are ``TrunkConfig``'s where
+    the two mean the same; the file
+    ``benchmark/configs/philly512-ling.json`` has the source's keys."""
+    hidden_size: int = 2560
+    num_attention_heads: int = 32
+    head_dim: int = 128                 # KDA's q, k and v
+    kv_lora_rank: int = 512             # MLA
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    shared_intermediate_size: int = 768
+    num_experts: int = 512
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    route_scale: float = 2.5            # routed_scaling_factor
+    route_norm: bool = True             # norm_topk_prob
+    rope_theta: float = 6000000.0
+    rms_norm_eps: float = 1e-6
+    num_dense_layers: int = 1           # first_k_dense_replace, cut
+    num_hidden_layers: int = 6
+    layer_group_size: int = 6
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
+    kda_chunk: int = 64
+    experts_held: tuple[int, int] = (0, 8)
+
+    family = "ling"
+    embed_scale = 1.0
+
+    def __post_init__(self):
+        _check_experts(self)
+        if not 0 <= -self.kda_lower_bound * kda.SUB <= kda.MAX_LOG_DECAY:
+            raise ValueError(
+                f"kda_lower_bound {self.kda_lower_bound} lets {kda.SUB} "
+                f"tokens decay by more than exp({kda.MAX_LOG_DECAY}): "
+                f"ops.kda's sub-block products would overflow")
+
+    def is_mla(self, layer: int) -> bool:
+        return layer % self.layer_group_size == self.layer_group_size - 1
+
+
+TRUNKS: dict[str, "TrunkConfig | LingConfig"] = {
     "published": TrunkConfig(),
     # the CPU tests' and rehearsals' shape: the window (8) is shorter
     # than any observation, so the sliding mask bites
@@ -134,6 +249,17 @@ TRUNKS: dict[str, TrunkConfig] = {
                         sliding_window=8, intermediate_size=96,
                         moe_intermediate_size=32, num_experts=8,
                         num_experts_per_tok=2, experts_held=(0, 2)),
+    "ling": LingConfig(),
+    # two periods of three, so that both kinds of layer come twice; 16
+    # experts in 4 groups of 4, 2 groups kept; the chunk (8) is shorter
+    # than any observation, so the state crosses chunks
+    "ling-tiny": LingConfig(
+        hidden_size=64, num_attention_heads=2, head_dim=32, kv_lora_rank=16,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+        intermediate_size=96, moe_intermediate_size=32,
+        shared_intermediate_size=24, num_experts=16, num_experts_per_tok=2,
+        n_group=4, topk_group=2, num_hidden_layers=6, layer_group_size=3,
+        kda_chunk=8, experts_held=(0, 4)),
 }
 
 
@@ -325,10 +451,48 @@ def routed_experts(x, gid, weight, w_gate, w_up, w_down):
     return jnp.sum(back * weight.astype(x.dtype)[..., None], axis=1), sizes
 
 
+def largest(x: jax.Array, k: int) -> jax.Array:
+    """Indices ``[..., k]`` of the ``k`` largest of ``x[..., E]``, largest
+    first, ties to the lower index: ``k`` passes of a ``max`` and the
+    ``min`` of the positions that hold it. For the few an expert layer
+    wants of its hundreds these are cheap reductions where ``lax.top_k``
+    sorts (on the chip the group-limited choice over 512 experts took
+    1.1 s an iteration by ``top_k``), and two plain reductions a pass
+    compile in a third of the time of one ``argmax`` over (value, index)
+    pairs (PERF.md section 6, PR 39)."""
+    E = x.shape[-1]
+    at = jnp.arange(E, dtype=jnp.int32)
+    out = []
+    for _ in range(k):
+        top = jnp.max(x, axis=-1, keepdims=True)
+        i = jnp.min(jnp.where(x == top, at, E), axis=-1)
+        out.append(i)
+        x = jnp.where(at == i[..., None], -jnp.inf, x)
+    return jnp.stack(out, axis=-1)
+
+
+def choose_experts(choice: jax.Array, k: int, n_group: int,
+                   topk_group: int) -> jax.Array:
+    """Indices ``[..., k]`` of the ``k`` largest of ``choice[..., E]``
+    among the experts of the ``topk_group`` best of ``n_group`` groups of
+    ``E / n_group`` neighbours; a group's score is the sum of its two
+    largest entries (``bailing_moe_v2`` / DeepSeek-V3's group-limited
+    routing). One group: a flat top-k. Ties go to the lower index."""
+    if n_group == 1:
+        return jax.lax.top_k(choice, k)[1]
+    E = choice.shape[-1]
+    grouped = choice.reshape(*choice.shape[:-1], n_group, E // n_group)
+    two = jnp.take_along_axis(grouped, largest(grouped, 2), axis=-1)
+    best = largest(jnp.sum(two, axis=-1), topk_group)       # [..., kept]
+    kept = jnp.any(best[..., None] == jnp.arange(n_group), axis=-2)
+    inside = jnp.where(kept[..., None], grouped, -jnp.inf)
+    return largest(inside.reshape(choice.shape), k)
+
+
 class ExpertLayer(nn.Module):
     """``shared(x) + sum over this chip's experts`` (module docstring),
     for ``x[B, T, d]``; sows each held expert's load."""
-    cfg: TrunkConfig
+    cfg: "TrunkConfig | LingConfig"
     dtype: jnp.dtype
 
     @nn.compact
@@ -345,8 +509,8 @@ class ExpertLayer(nn.Module):
             scores = jax.nn.sigmoid(jnp.dot(
                 x.astype(jnp.float32), router,
                 precision=jax.lax.Precision.HIGHEST))           # [B,T,E]
-            _, idx = jax.lax.top_k(
-                jax.lax.stop_gradient(scores) + bias, k)        # [B,T,k]
+            idx = choose_experts(jax.lax.stop_gradient(scores) + bias, k,
+                                 *expert_groups(c))             # [B,T,k]
             w = jnp.take_along_axis(scores, idx, axis=-1)
             if c.route_norm:
                 w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -365,7 +529,8 @@ class ExpertLayer(nn.Module):
                 w.reshape(-1, k), *kernels)
             routed = routed.reshape(B, T, d)
         with jax.named_scope(scopes.MOE_SHARED):
-            shared = GatedMLP(f, d, self.dtype, name="shared")(x)
+            shared = GatedMLP(c.shared_intermediate_size, d, self.dtype,
+                              name="shared")(x)
         if not self.is_initializing():      # init's tree is params only
             self.sow(COUNTERS, "held", jnp.sum(held, dtype=jnp.int32))
             self.sow(COUNTERS, "load", sizes)
@@ -396,17 +561,163 @@ class Block(nn.Module):
         return h + norm("post_mlp_norm")(m)
 
 
+class Bias(nn.Module):
+    """One ``bias`` leaf of the given shape, zeros."""
+    shape: tuple[int, ...]
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("bias", nn.initializers.zeros, self.shape,
+                          jnp.float32)
+
+
+def unit(x: jax.Array, gain: float = 1.0) -> jax.Array:
+    """``x / |x|`` over the last axis (flash-linear-attention's
+    ``l2norm``: eps 1e-6 under the root), times ``gain``; float32."""
+    x = x.astype(jnp.float32)
+    return x * (jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1,
+                                      keepdims=True) + 1e-6) * gain)
+
+
+class KDA(nn.Module):
+    """A linear-attention layer of the ``ling`` block (module docstring)
+    for ``u[B, T, d]``, ``valid[B, T]``."""
+    cfg: LingConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u: jax.Array, valid: jax.Array) -> jax.Array:
+        c = self.cfg
+        B, T, _ = u.shape
+        H, D = c.num_attention_heads, c.head_dim
+        there = valid[..., None]
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
+                                         name=name)(u)
+        heads = lambda a: a.reshape(B, T, H, D)
+
+        def conv(name):
+            x = jnp.where(there, dense(H * D, f"{name}_proj"), 0)
+            w = Kernel((c.short_conv_kernel_size, H * D),
+                       name=f"{name}_conv")()
+            with jax.named_scope(scopes.KDA_CONV):
+                return heads(nn.silu(kda.causal_conv(x, w)))
+
+        q, k, v = conv("q"), conv("k"), conv("v")
+        f = dense(H * D, "f_proj")
+        b = dense(H, "b_proj")
+        a_log, dt = Bias((H,), name="A_log")(), Bias((H * D,), name="dt")()
+        with jax.named_scope(scopes.KDA_GATES):
+            rate = jnp.exp(a_log)[:, None] * heads(
+                f.astype(jnp.float32) + dt)
+            g = jnp.where(there[..., None],
+                          c.kda_lower_bound * jax.nn.sigmoid(rate), 0.0)
+            beta = jnp.where(there, jax.nn.sigmoid(b.astype(jnp.float32)),
+                             0.0)
+            q = unit(q, 1.0 / math.sqrt(D)).astype(self.dtype)
+            k = unit(k).astype(self.dtype)
+        with jax.named_scope(scopes.KDA_SCAN):
+            o = kda.chunked_delta_rule(q, k, v, g, beta, chunk=c.kda_chunk,
+                                       dtype=self.dtype)
+        o = RMSNorm(c.rms_norm_eps, self.dtype, name="o_norm")(o)
+        out = o.reshape(B, T, H * D) * jax.nn.sigmoid(dense(H * D, "g_proj"))
+        return nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
+                        name="o_proj")(out)
+
+
+class MLA(nn.Module):
+    """A latent-attention layer of the ``ling`` block (module docstring)
+    for ``u[B, T, d]``, ``valid[B, T]``."""
+    cfg: LingConfig
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, u: jax.Array, valid: jax.Array) -> jax.Array:
+        from ..parallel.sharding import active_mesh
+        c = self.cfg
+        B, T, _ = u.shape
+        H, dn, dr, dv = c.num_attention_heads, c.qk_nope_head_dim, \
+            c.qk_rope_head_dim, c.v_head_dim
+        wide = -(-(dn + dr) // 128) * 128       # q and k, zero-padded
+        kernel = attention_path(jax.default_backend(), wide,
+                                active_mesh() is not None) == KERNEL
+        pre = 1.0 / math.sqrt(dn + dr) if kernel else 1.0   # as Attention
+        dense = lambda n, name, x=u: nn.Dense(
+            n, use_bias=False, dtype=self.dtype, name=name)(x)
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
+        kva = dense(c.kv_lora_rank + dr, "kv_a_proj")
+        latent = norm("kv_a_norm")(kva[..., :c.kv_lora_rank])
+        kvb = dense(H * (dn + dv), "kv_b_proj", latent).reshape(
+            B, T, H, dn + dv)
+        k_nope, v = kvb[..., :dn], kvb[..., dn:]
+        k_rope = rope(norm("k_rope_norm")(kva[..., c.kv_lora_rank:])[
+            :, :, None, :], c.rope_theta)                   # [B, T, 1, dr]
+        q = norm("q_norm")(dense(H * (dn + dr), "q_proj").reshape(
+            B, T, H, dn + dr), pre)
+        q = jnp.concatenate([q[..., :dn], rope(q[..., dn:], c.rope_theta)],
+                            axis=-1)
+        k = jnp.concatenate([norm("k_norm")(k_nope),
+                             jnp.broadcast_to(k_rope, (B, T, H, dr))],
+                            axis=-1)
+        gate = dense(H, "gate_proj")
+        q = q[:, :, :, None, :]             # every head its own keys
+        tiles = 0.0
+        if kernel:
+            from ..ops import attention     # Pallas: this path only
+            widen = lambda a: jnp.pad(
+                a, [(0, 0)] * (a.ndim - 1) + [(0, wide - dn - dr)])
+            out = attention.blocked_attend(widen(q), widen(k), v, valid,
+                                           None)
+            tiles = attention.tiles_computed_share(T, None)
+        else:
+            out = attend(q, k, v, valid, None)
+        if not self.is_initializing():      # init's tree is params only
+            self.sow(COUNTERS, "attn_tiles", jnp.float32(tiles))
+        out = out.reshape(B, T, H, dv) * jax.nn.sigmoid(gate)[..., None]
+        return nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
+                        name="o_proj")(out.reshape(B, T, H * dv))
+
+
+class LingBlock(nn.Module):
+    cfg: LingConfig
+    index: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array, valid: jax.Array) -> jax.Array:
+        c = self.cfg
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
+        mla = c.is_mla(self.index)
+        with jax.named_scope(scopes.TRUNK_ATTN):
+            u = norm("input_norm")(x)
+            with jax.named_scope(scopes.ATTN_MLA if mla
+                                 else scopes.ATTN_KDA):
+                layer = (MLA if mla else KDA)(c, self.dtype, name="attn")
+                h = x + layer(u, valid)
+        z = norm("pre_mlp_norm")(h)
+        if self.index < c.num_dense_layers:
+            with jax.named_scope(scopes.TRUNK_DENSE_MLP):
+                m = GatedMLP(c.intermediate_size, c.hidden_size, self.dtype,
+                             name="mlp")(z)
+        else:
+            m = ExpertLayer(c, self.dtype, name="moe")(z)
+        return h + m
+
+
+BLOCKS = {"afmoe": Block, "ling": LingBlock}
+
+
 class TokenTrunk(nn.Module):
     """``obs[..., T, F]`` (last feature: ``valid``) -> float32 ``[..., d]``.
     Each block takes the batch ``ROW_BLOCK`` rows at a time and is
     rematerialised in the backward pass: what a minibatch keeps is each
     block's input, and what is live is one group of rows' activations."""
-    cfg: TrunkConfig = TrunkConfig()
+    cfg: "TrunkConfig | LingConfig" = TrunkConfig()
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
     def __call__(self, obs: jax.Array) -> jax.Array:
         c = self.cfg
+        Layer = BLOCKS[c.family]
         with jax.named_scope(scopes.TRUNK):
             lead = obs.shape[:-2]
             obs = obs.reshape(-1, *obs.shape[-2:])
@@ -416,7 +727,8 @@ class TokenTrunk(nn.Module):
                 x = nn.Dense(c.hidden_size, use_bias=False,
                              dtype=self.dtype, name="embed")(
                     obs.astype(self.dtype))
-                x = x * jnp.asarray(math.sqrt(c.hidden_size), self.dtype)
+                if c.embed_scale != 1.0:
+                    x = x * jnp.asarray(c.embed_scale, self.dtype)
             r = max(b for b in range(1, min(ROW_BLOCK, B) + 1)
                     if B % b == 0)
             groups = lambda a: a.reshape(B // r, r, *a.shape[1:])
@@ -428,7 +740,7 @@ class TokenTrunk(nn.Module):
             for i in range(c.num_hidden_layers):
                 # inside a scan nothing can merge the recomputation with
                 # the forward pass, so the barriers against it are left out
-                block = nn.remat(Block, prevent_cse=False)(
+                block = nn.remat(Layer, prevent_cse=False)(
                     c, i, self.dtype, name=f"layer_{i}")
                 _, x = over_groups(block, None, (groups(x), groups(valid)))
                 x = x.reshape(B, T, c.hidden_size)
@@ -439,6 +751,19 @@ class TokenTrunk(nn.Module):
                 pooled = jnp.sum(x * m, axis=-2) / jnp.maximum(
                     jnp.sum(m, axis=-2), 1.0)
             return pooled.reshape(*lead, c.hidden_size)
+
+
+def describe(c: "TrunkConfig | LingConfig") -> dict:
+    """What a trunk's configuration fixes about its layers, for a run's
+    own record (``train.py`` prints it once, at build): constants of the
+    build, so no counter carries them through every iteration."""
+    kda_layers = sum(not c.is_mla(i) for i in range(c.num_hidden_layers)) \
+        if c.family == "ling" else 0
+    groups, kept = expert_groups(c)
+    return {"family": c.family, "layers": c.num_hidden_layers,
+            "kda_layers": kda_layers,
+            "kda_chunk": c.kda_chunk if kda_layers else 0,
+            "moe_groups": groups, "moe_groups_kept": kept}
 
 
 def read_counters(collection: dict) -> dict:

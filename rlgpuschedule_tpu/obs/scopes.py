@@ -44,6 +44,17 @@ forward pass is traced, so the same subtree hangs under both
       moe_experts           dispatch, the grouped products, combine
       moe_shared            the shared expert
       trunk_pool            final norm, mean over valid tokens
+
+The second family of blocks (``models.trunk.LingConfig``) has a tree of
+its own (``LING_TRUNK_TREE``): the names above that it shares, and under
+``trunk_attn`` its two kinds of layer in place of the two windows::
+
+      trunk_attn            the input norm and the layer
+        attn_kda            a linear-attention layer, projections to o_proj
+          kda_conv          the three causal convolutions and their silu
+          kda_gates         decays, write strengths, q/k normalisation
+          kda_scan          the gated delta rule in chunks (ops.kda)
+        attn_mla            a latent-attention layer, projections to o_proj
 """
 from __future__ import annotations
 
@@ -78,6 +89,11 @@ MOE_ROUTE = "moe_route"
 MOE_EXPERTS = "moe_experts"
 MOE_SHARED = "moe_shared"
 TRUNK_POOL = "trunk_pool"
+ATTN_KDA = "attn_kda"
+KDA_CONV = "kda_conv"
+KDA_GATES = "kda_gates"
+KDA_SCAN = "kda_scan"
+ATTN_MLA = "attn_mla"
 
 # every scope as its path from the program's top, parents first
 TREE = (
@@ -106,6 +122,22 @@ TRUNK_TREE = (
     (TRUNK, TRUNK_ATTN),
     (TRUNK, TRUNK_ATTN, ATTN_SLIDING),
     (TRUNK, TRUNK_ATTN, ATTN_FULL),
+    (TRUNK, TRUNK_DENSE_MLP),
+    (TRUNK, MOE_ROUTE),
+    (TRUNK, MOE_EXPERTS),
+    (TRUNK, MOE_SHARED),
+    (TRUNK, TRUNK_POOL),
+)
+# the second family's (module docstring): what it shares, and its layers
+LING_TRUNK_TREE = (
+    (TRUNK,),
+    (TRUNK, TRUNK_EMBED),
+    (TRUNK, TRUNK_ATTN),
+    (TRUNK, TRUNK_ATTN, ATTN_KDA),
+    (TRUNK, TRUNK_ATTN, ATTN_KDA, KDA_CONV),
+    (TRUNK, TRUNK_ATTN, ATTN_KDA, KDA_GATES),
+    (TRUNK, TRUNK_ATTN, ATTN_KDA, KDA_SCAN),
+    (TRUNK, TRUNK_ATTN, ATTN_MLA),
     (TRUNK, TRUNK_DENSE_MLP),
     (TRUNK, MOE_ROUTE),
     (TRUNK, MOE_EXPERTS),

@@ -184,8 +184,10 @@ HIER_RULES: Rules = FLAT_RULES
 # Token trunk (models.trunk): the held experts' three kernels a layer are
 # [d, count*f] / [f, count*d], experts contiguous along the LAST axis, so
 # that axis on ``model`` gives each shard whole experts (expert parallel);
-# everything else (attention, router, shared expert, norms, heads) is
-# replicated, as each chip of the stated deployment holds it whole.
+# everything else (attention of every kind: the afmoe blocks' GQA, the
+# ling blocks' KDA projections, convolutions, decay leaves and MLA
+# projections; router, shared expert, norms, heads) is replicated, as each
+# chip of the stated deployment holds it whole.
 TOKENS_RULES: Rules = [
     (r"experts_(gate|up|down)/kernel$", P(None, MODEL_AXIS)),
     (r"(^|/)kernel$", P()),

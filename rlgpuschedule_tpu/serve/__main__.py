@@ -76,7 +76,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--horizon", type=int, default=None)
     p.add_argument("--obs-kind", default=None,
                    choices=["flat", "grid", "graph", "tokens"])
-    p.add_argument("--trunk", default=None, choices=["published", "tiny"],
+    p.add_argument("--trunk", default=None,
+                   choices=["published", "tiny", "ling", "ling-tiny"],
                    help="obs-kind tokens: the trunk sizes the checkpoint "
                         "was trained with (train --trunk)")
     # bench mode

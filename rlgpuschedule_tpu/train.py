@@ -50,11 +50,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="override the preset's observation/encoder family "
                         "(e.g. train config 2's cluster on the flat MLP "
                         "encoder on a CPU host)")
-    p.add_argument("--trunk", default=None, choices=["published", "tiny"],
-                   help="obs-kind tokens: the token trunk's whole set of "
-                        "sizes (models.trunk.TRUNKS): the source model's "
-                        "published widths, or the tiny shape for a CPU "
-                        "host")
+    p.add_argument("--trunk", default=None,
+                   choices=["published", "tiny", "ling", "ling-tiny"],
+                   help="obs-kind tokens: the token trunk's family and "
+                        "whole set of sizes (models.trunk.TRUNKS): a "
+                        "source model's published widths (published: "
+                        "afmoe blocks; ling: linear-attention blocks), or "
+                        "its tiny shape for a CPU host (tiny; ling-tiny)")
     p.add_argument("--trace", default=None,
                    choices=["synthetic", "philly", "pai", "philly-proxy",
                             "pai-proxy"],
@@ -722,6 +724,13 @@ def main(argv: list[str] | None = None) -> dict:
             from .parallel import rule_table_hash, rules_for
             print(f"mesh: {dict(run_mesh.shape)} rules="
                   f"{rule_table_hash(rules_for(cfg))}", file=sys.stderr)
+        trunk_record = None
+        if cfg.obs_kind == "tokens":
+            # what the trunk's configuration fixes (layers of each kind,
+            # chunk, expert groups): said once, not logged an iteration
+            from .models.trunk import TRUNKS, describe
+            trunk_record = {"name": cfg.trunk, **describe(TRUNKS[cfg.trunk])}
+            print(f"trunk: {json.dumps(trunk_record)}", file=sys.stderr)
         if args.resume:
             if ckpt is None:
                 sys.exit("--resume requires --ckpt-dir")
@@ -841,6 +850,8 @@ def main(argv: list[str] | None = None) -> dict:
             sys.exit(f"divergence watchdog gave up: {e}")
 
         summary = {k: v for k, v in out.items() if k != "history"}
+        if trunk_record is not None:
+            summary["trunk"] = trunk_record
         if run_mesh is not None:
             from .parallel import rule_table_hash, rules_for
             summary["mesh"] = {

@@ -43,8 +43,14 @@ SMALL["tokens"] = dataclasses.replace(
     CONFIGS["ppo-trinity-philly512"], trunk="tiny", n_envs=2, n_nodes=2,
     gpus_per_node=4, window_jobs=16, queue_len=4, horizon=64,
     ppo=PPOConfig(n_steps=8, n_epochs=1, n_minibatches=2))
+# the second family of token blocks (ISSUE 39), likewise
+SMALL["ling"] = dataclasses.replace(SMALL["tokens"],
+                                    name="ppo-ling-philly512",
+                                    trunk="ling-tiny")
 SCOPE_NAMES = tuple(path[-1] for path in scopes.TREE)
 TRUNK_SCOPE_NAMES = tuple(path[-1] for path in scopes.TRUNK_TREE)
+# each token policy's step with the tree of its family
+TRUNK_TREES = {"tokens": scopes.TRUNK_TREE, "ling": scopes.LING_TRUNK_TREE}
 
 
 def lower_step(algo: str):
@@ -89,11 +95,18 @@ def test_scope_is_on_the_token_policys_train_step(lowered_names, scope):
     assert scope in lowered_names("tokens")
 
 
+@pytest.mark.parametrize("scope", SCOPE_NAMES + tuple(
+    path[-1] for path in scopes.LING_TRUNK_TREE))
+def test_scope_is_on_the_ling_policys_train_step(lowered_names, scope):
+    assert scope in lowered_names("ling")
+
+
 @pytest.mark.parametrize("parent", scopes.TRUNK_PARENTS,
                          ids=lambda p: "/".join(p))
-@pytest.mark.parametrize("path", scopes.TRUNK_TREE,
-                         ids=lambda p: "/".join(p))
-def test_trunk_scope_hangs_under_both_forward_passes(path, parent):
+@pytest.mark.parametrize("policy,path", [
+    (policy, path) for policy, tree in TRUNK_TREES.items() for path in tree],
+    ids=lambda v: v if isinstance(v, str) else "/".join(v))
+def test_trunk_scope_hangs_under_both_forward_passes(policy, path, parent):
     """Some operation of the COMPILED step carries ``parent`` and then
     the trunk scope's own path, in order, in its ``op_name`` (what the
     benchmark's readers match): the rollout's forward and the update's
@@ -105,20 +118,22 @@ def test_trunk_scope_hangs_under_both_forward_passes(path, parent):
         return all(any(c == w for c in it) for w in want)
 
     assert any(holds([bare(c) for c in name.split("/")])
-               for name in _tokens_op_names())
+               for name in _tokens_op_names(policy))
 
 
 @functools.lru_cache(maxsize=None)
-def _tokens_op_names() -> frozenset:
+def _tokens_op_names(policy: str) -> frozenset:
     return frozenset(re.findall(
-        r'op_name="([^"]+)"', lower_step("tokens").compile().as_text()))
+        r'op_name="([^"]+)"', lower_step(policy).compile().as_text()))
 
 
 def test_tree_is_parents_first_and_names_are_unique():
     assert len(set(SCOPE_NAMES)) == len(SCOPE_NAMES)
     assert len(set(SCOPE_NAMES + TRUNK_SCOPE_NAMES)) == len(
         SCOPE_NAMES + TRUNK_SCOPE_NAMES)
-    for tree in (scopes.TREE, scopes.TRUNK_TREE):
+    ling = tuple(path[-1] for path in scopes.LING_TRUNK_TREE)
+    assert len(set(SCOPE_NAMES + ling)) == len(SCOPE_NAMES + ling)
+    for tree in (scopes.TREE, scopes.TRUNK_TREE, scopes.LING_TRUNK_TREE):
         seen = set()
         for path in tree:
             assert path[:-1] == () or path[:-1] in seen

@@ -140,6 +140,50 @@ def test_blocked_attention_compiles_at_the_cells_call_shape(one_chip):
     assert f"{T},{T}]" not in text and f"{padded},{padded}]" not in text
 
 
+def test_mla_score_product_compiles_with_q_and_k_padded_to_256(one_chip):
+    """The ``ling`` trunk's MLA layer on the kernel path: 32 heads, each
+    with its own keys, q and k of 192 channels zero-padded to 256, v of
+    128: the kernels take the two widths, forward and gradient."""
+    from rlgpuschedule_tpu.models.trunk import ROW_BLOCK
+    from rlgpuschedule_tpu.ops import attention
+    b, T, H = ROW_BLOCK, 832, 32
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    loss = lambda q, k, v, valid: jnp.sum(attention.blocked_attend(
+        q, k, v, valid, None, interpret=False).astype(jnp.float32))
+    with time_limit(60):
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            sds(b, T, H, 1, 256), sds(b, T, H, 256), sds(b, T, H, 128),
+            sds(b, T, dtype=jnp.bool_)).compile().as_text()
+    assert "splash_mqa_fwd" in text and "splash_mqa_dkv" in text
+    assert f"{T},{T}]" not in text
+
+
+def test_chunked_delta_rule_compiles_at_the_cells_call_shape(one_chip):
+    """``ops.kda`` forward and gradient at one call of the ``ling`` cell:
+    ``ROW_BLOCK`` rows of 832 tokens, 32 heads of 128 x 128 state, chunks
+    of 64, bfloat16 tiles. The compiler takes it (a ``jnp.diagonal`` in
+    the solve once tripped its algebraic simplifier), no array of a pair
+    of tokens by channel is kept (``[.., 16, 16, 128]`` over a call is
+    1.7 GB: it lives inside one fusion), and the temporaries (2.22 GB as
+    this was written: two dozen arrays of 55-109 MB, the levels' factors
+    and the scan's residuals) stay under 2.5 GB."""
+    from rlgpuschedule_tpu.models.trunk import ROW_BLOCK
+    from rlgpuschedule_tpu.ops import kda
+    b, T, H, D = ROW_BLOCK, 832, 32, 128
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    loss = lambda q, k, v, g, beta: jnp.sum(kda.chunked_delta_rule(
+        q, k, v, g, beta, chunk=64, dtype=jnp.bfloat16))
+    with time_limit(120):
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+            sds(b, T, H, D), sds(b, T, H, D), sds(b, T, H, D),
+            sds(b, T, H, D, dtype=jnp.float32),
+            sds(b, T, H, dtype=jnp.float32)).compile()
+    assert "64,64,128]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
 @pytest.mark.parametrize("path", ["plain", "kernel"])
 def test_token_policy_update_compiles_and_leaves_room_for_a_second_state(
         one_chip, monkeypatch, path):

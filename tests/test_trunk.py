@@ -62,14 +62,24 @@ def reference(params, obs, mask, cfg, quant=None):
     return jnp.where(mask, logits, ref_forward.NEG_INF), value
 
 
+def file_settings(rehearse: bool) -> dict:
+    """The settings the harness hands ``forward_tokens`` for the standing
+    token cell (``common.Reference``: the file's top level in a run,
+    overlaid by its ``rehearse_trunk`` in a rehearsal)."""
+    from benchmark import common
+    config = common.load_json("configs", "philly512-trinity.json")
+    return common.Reference(config, rehearse).settings
+
+
 def test_the_configuration_file_states_the_tiny_trunk():
-    """``forward_tokens`` finds its settings by hidden size: the file's
-    ``rehearse_trunk`` is ``TRUNKS['tiny']`` and its top level
-    ``TRUNKS['published']``."""
-    by_size = {s["hidden_size"]: s for s in ref.specs()}
-    for name, tokens in (("tiny", 20), ("published", 832)):
+    """The file's ``rehearse_trunk`` overlay is ``TRUNKS['tiny']`` and its
+    top level ``TRUNKS['published']``, in every setting the reference
+    reads."""
+    for name, tokens, rehearse in (("tiny", 20, True),
+                                   ("published", 832, False)):
         want = spec_of(TRUNKS[name], tokens)
-        got = dict(by_size[TRUNKS[name].hidden_size])
+        settings = file_settings(rehearse)
+        got = {k: settings[k] for k in want}
         n = len(want["layer_types"])
         got["layer_types"] = got["layer_types"][:n]
         assert got == want, name
@@ -84,9 +94,10 @@ def test_float32_program_equals_the_plain_reference():
     r_logits, r_value = reference(params, obs, mask, TINY)
     assert float(jnp.max(jnp.abs(logits - r_logits))) < 1e-5
     assert float(jnp.max(jnp.abs(value - r_value))) < 1e-5
-    # and through the file's own settings (no spec handed over)
+    # and through the file's own settings, as the harness hands them over
     with jax.default_matmul_precision("highest"):
-        by_file = ref.trunk(params["params"]["encoder"], obs, None)
+        by_file = ref.trunk(params["params"]["encoder"], obs, None,
+                            file_settings(rehearse=True))
         by_spec = ref.trunk(params["params"]["encoder"], obs, None,
                             spec_of(TINY))
     assert np.array_equal(np.asarray(by_file), np.asarray(by_spec))
