@@ -109,17 +109,17 @@ class PPOMetrics(NamedTuple):
 
 
 # :class:`PPOMetrics` and the token trunk's counters (what each counts:
-# ``models.trunk.read_counters``), for a policy whose apply has a
+# ``models.trunk.read_counters``: assignments held and dropped, the
+# fullest held expert's load, the short buffer's share of calls, the
+# attention kernel's layers and tiles), for a policy whose apply has a
 # ``counted`` form; read in the update's own loss forward, each reduced
-# over the update's minibatches as ``COUNTER_REDUCTIONS`` says (max where
-# it names none): assignments held a minibatch, the fullest held expert's
-# load over the mean, assignments dropped (0 by construction), attention
-# layers on the blocked kernel, and the share of tiles it computes.
+# over the minibatches as ``COUNTER_REDUCTIONS`` says (max where none).
 MOE_COUNTERS = ("moe_assignments_held", "moe_expert_load_max_over_mean",
-                "moe_dropped_assignments", "attn_kernel_layers",
-                "attn_tiles_computed_share")
+                "moe_dropped_assignments", "moe_short_path_share",
+                "attn_kernel_layers", "attn_tiles_computed_share")
 COUNTER_REDUCTIONS = {"moe_assignments_held": jnp.mean,
-                      "moe_dropped_assignments": jnp.sum}
+                      "moe_dropped_assignments": jnp.sum,
+                      "moe_short_path_share": jnp.mean}
 MoEPPOMetrics = NamedTuple("MoEPPOMetrics", [
     *((f, jax.Array) for f in PPOMetrics._fields + MOE_COUNTERS)])
 

@@ -7,9 +7,10 @@ families. ``TRUNKS`` names each whole set of sizes AND its family:
 :class:`TokenTrunk` (the embedding's linear map, blocks rematerialised and
 taken ``ROW_BLOCK`` rows at a time, the final norm and the mean over valid
 tokens), :class:`RMSNorm`, :class:`GatedMLP`, :class:`ExpertLayer` with
-:func:`routed_experts` and :func:`take_rows` (an expert layer told which
-experts it holds), :func:`attend` / ``ops.attention.blocked_attend`` for a
-softmax score product, the counters and ``ActorCritic``'s heads.
+:func:`routed_experts`, :class:`SortedRows` and :func:`sum_rows` (an expert
+layer told which experts it holds), :func:`attend` /
+``ops.attention.blocked_attend`` for a softmax score product, the counters
+and ``ActorCritic``'s heads.
 
 **The ``afmoe`` block** is the public ``afmoe`` family's (arcee-ai
 Trinity; widths and ``layer_types`` as the family's ``config.json`` keys
@@ -84,11 +85,21 @@ ones (``(0, num_experts)`` is the whole layer). Token-choice and
 dropless: every assignment to a held expert is computed. Assignments are
 sorted by expert (non-held ones last) and each projection is ONE grouped
 matrix product (``jax.lax.ragged_dot``: on a TPU XLA's own grouped-matmul
-kernel, whose work follows the group sizes). The sorted buffer has a row
-for EVERY assignment (``tokens x k``): the worst case, every token's k
-choices held here, fits by construction, so ``moe_dropped_assignments``
-is 0 whatever the router does, and a row's result cannot depend on which
-rows share its batch.
+kernel, whose work follows the group sizes). **What is sized for what:**
+the sorted ORDER has an entry for every assignment (``tokens x k``
+integers); the BUFFER of rows the products run on is sized for the
+assignments that are held: :func:`routed_experts` works on the first
+``L`` entries of the order, at ``L`` = :func:`short_rows` (two choices a
+token held here) when the held count fits that, and at ``L = tokens x
+k``, the worst case (every token's k choices held here), when it does
+not, one routine at two static lengths behind a ``jax.lax.cond`` on the
+held count. Each row's result times its router weight is summed into its
+token (:func:`sum_rows`: a token's own terms and exact zeros, at either
+length). So ``moe_dropped_assignments`` is 0 whatever the router does, a
+row's result cannot depend on which rows share its batch, and what a chip
+with a small share of the experts pays follows what it holds; a chip
+whose experts get a deployment's load takes the long buffer every time.
+The counters say which ran (``moe_short_path_share``).
 
 **Parameter leaves** end in ``kernel``, ``scale`` or ``bias``. The held
 experts' weights are three leaves a layer, laid out so that a kernel's
@@ -398,25 +409,112 @@ class GatedMLP(nn.Module):
         return dense(self.out, "down")(h)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def take_rows(x, index, inverse, fan: int):
-    """``x[index]`` where ``index`` holds every row of ``x`` exactly
-    ``fan`` times and ``inverse`` lists, row by row of ``x``, where its
-    ``fan`` copies went: the backward pass is then a gather and a sum
-    over ``fan``, not a scatter-add."""
-    return x[index]
+def sum_rows(y, index, n: int):
+    """``[n, d]`` whose row ``i`` is the sum of the rows ``y[j]`` with
+    ``index[j] == i`` (zeros where there is none): ONE product of the
+    0/1 matrix ``[n, L]`` with ``y``, accumulated in float32 and rounded
+    once (the matrix unit's work for a few thousand rows is a fraction of
+    a scatter-add's, which serialises: PERF.md section 6, PR 40). A row
+    of the result is its own terms and exact zeros."""
+    hot = (index[:, None] == jnp.arange(n)[None, :]).astype(y.dtype)
+    return jnp.einsum("ln,ld->nd", hot, y,
+                      precision=jax.lax.Precision.HIGHEST,   # float32 rows
+                      preferred_element_type=jnp.float32).astype(y.dtype)
 
 
-def _take_rows_fwd(x, index, inverse, fan):
-    return x[index], inverse
+def short_rows(n: int, k: int) -> int:
+    """Rows of the short expert buffer for ``n`` tokens of ``k`` choices:
+    two choices a token held here, in whole 512s, and never more than
+    the worst case ``n * k``. A static function of the call's shapes.
+    Why two: at seeded weights every token of a block makes much the same
+    choices, so a layer's held count comes in steps of the block's valid
+    tokens (0, about ``n``, about ``2n``: PERF.md section 6, PR 40); one
+    choice a token would send one layer in twelve the long way."""
+    return min(-(-2 * n // 512) * 512, n * k)
 
 
-def _take_rows_bwd(fan, inverse, g):
-    back = g[inverse].reshape(-1, fan, g.shape[-1])
-    return back.sum(axis=1), None, None
+class SortedRows:
+    """:func:`routed_experts`' ONE routine over the first ``L`` of the
+    sorted assignments ``order``, forward and backward: what a length
+    needs of the call's integers."""
+
+    def __init__(self, L: int, order, sizes, k: int, dtype):
+        self.at = order[:L]                             # assignment a row
+        self.token = self.at // k
+        self.in_group = (jnp.arange(L) < jnp.sum(sizes))[:, None]
+        self.grouped = lambda a, b: jax.lax.ragged_dot(
+            a, b, sizes, preferred_element_type=dtype)
+
+    def forward(self, x, weight, w_gate, w_up, w_down):
+        """The weighted sum ``[n, d]`` and the rows the backward pass
+        reads."""
+        xs = jnp.where(self.in_group, x[self.token], 0)
+        gate, up = self.grouped(xs, w_gate), self.grouped(xs, w_up)
+        ys = jnp.where(self.in_group,
+                       self.grouped(nn.silu(gate) * up, w_down), 0)
+        w = weight.reshape(-1)[self.at].astype(x.dtype)
+        return (sum_rows(ys * w[:, None], self.token, x.shape[0]),
+                (xs, gate, up, ys))
+
+    def backward(self, kept, g, x, weight, w_gate, w_up, w_down):
+        """The operands' cotangents from ``forward``'s rows and the
+        result's cotangent ``g``: the transpose, step by step (a gather
+        of ``g``'s rows, the grouped products' own transposes, one
+        :func:`sum_rows` into the tokens)."""
+        xs, gate, up, ys = kept
+        g_rows = g[self.token]
+        w = weight.reshape(-1)[self.at].astype(x.dtype)
+        d_ys = jnp.where(self.in_group, g_rows * w[:, None], 0)
+        hid, pull_hid = jax.vjp(lambda a, b: nn.silu(a) * b, gate, up)
+        d_hid, d_down = jax.vjp(self.grouped, hid, w_down)[1](d_ys)
+        d_gate, d_up = pull_hid(d_hid)
+        d_xs_gate, d_w_gate = jax.vjp(self.grouped, xs, w_gate)[1](d_gate)
+        d_xs_up, d_w_up = jax.vjp(self.grouped, xs, w_up)[1](d_up)
+        d_xs = jnp.where(self.in_group, d_xs_gate + d_xs_up, 0)
+        d_w = jnp.sum(g_rows * ys, axis=-1, dtype=weight.dtype)
+        d_weight = jnp.zeros((weight.size,), weight.dtype).at[self.at].set(
+            d_w, unique_indices=True).reshape(weight.shape)
+        return (sum_rows(d_xs, self.token, x.shape[0]), d_weight, d_w_gate,
+                d_w_up, d_down)
 
 
-take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(short: int, order, sizes, *operands):
+    return _routed_fwd(short, order, sizes, *operands)[0]
+
+
+def _routed_fwd(short, order, sizes, *operands):
+    """The short length when the held assignments fit it, else the long
+    one, whose rows are not handed on (the backward pass makes them
+    again: the worst case needs no speed, and a ``cond`` gives both
+    branches' results one shape); no branch where the two are one."""
+    x, weight = operands[:2]
+    at = lambda L: SortedRows(L, order, sizes, weight.shape[1], x.dtype)
+    if short == weight.size:
+        out, kept = at(short).forward(*operands)
+    else:
+        spare = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                             jax.eval_shape(at(short).forward, *operands)[1])
+        out, kept = jax.lax.cond(
+            jnp.sum(sizes) <= short, at(short).forward,
+            lambda *o: (at(weight.size).forward(*o)[0], spare), *operands)
+    return out, (order, sizes, kept, operands)
+
+
+def _routed_bwd(short, passed, g):
+    order, sizes, kept, operands = passed
+    x, weight = operands[:2]
+    at = lambda L: SortedRows(L, order, sizes, weight.shape[1], x.dtype)
+    if short == weight.size:
+        return None, None, *at(short).backward(kept, g, *operands)
+    long = at(weight.size)
+    return None, None, *jax.lax.cond(
+        jnp.sum(sizes) <= short, at(short).backward,
+        lambda _, g, *o: long.backward(long.forward(*o)[1], g, *o),
+        kept, g, *operands)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 def routed_experts(x, gid, weight, w_gate, w_up, w_down):
@@ -426,29 +524,32 @@ def routed_experts(x, gid, weight, w_gate, w_up, w_down):
     held), the kernels ``[count, d, f]`` / ``[count, f, d]``. Returns the
     weighted sum ``[n, d]`` and the held experts' loads ``[count]``.
 
-    Assignments are sorted by expert, non-held ones last; the sorted
-    buffer has a row for EVERY assignment, so none can be dropped. Each
-    projection is one grouped product over the held groups. Rows behind
-    the last group belong to no expert here: a grouped product leaves
-    them unwritten, so they are zeroed on the way in (which zeroes their
-    gradient too) and on the way out."""
-    n, d = x.shape
-    k = weight.shape[-1]
+    Assignments are sorted by expert, non-held ones last, so the held
+    ones are the first ``sum(loads)`` of the order. ONE routine
+    (:class:`SortedRows`) works on the first ``L`` of them: it gathers
+    their tokens' rows, zeroes the rows behind the last group (a grouped
+    product leaves them unwritten; zeroed on the way in, which zeroes
+    their gradient too, and on the way out), runs each projection as one
+    grouped product and sums every row's result times its router weight
+    into its token (:func:`sum_rows`). It is called at ``L`` =
+    :func:`short_rows` when the held assignments fit that and at ``L = n
+    * k``, a row for EVERY assignment, when they do not
+    (``jax.lax.cond``; where the short length is the long one there is
+    one call and no branch): none can be dropped, and a token's terms
+    are the same rows' at either length. Only integers are ever ``n *
+    k`` long on the short path. The backward pass is the routine's own
+    transpose at the length the forward pass took, written out
+    (:meth:`SortedRows.backward`) and chosen again by the same ``cond``:
+    differentiated through, a ``cond`` hands back both branches'
+    residuals, zeros the length of the worst case on every short call
+    (PERF.md section 6, PR 40)."""
+    n, k = weight.shape
     count = w_gate.shape[0]
-    A = n * k
     order = jnp.argsort(gid, stable=True).astype(jnp.int32)
-    slot_of = jnp.zeros((A,), jnp.int32).at[order].set(
-        jnp.arange(A, dtype=jnp.int32), unique_indices=True)
     sizes = jnp.sum(gid[:, None] == jnp.arange(count)[None, :], axis=0,
                     dtype=jnp.int32)
-    in_group = (jnp.arange(A) < jnp.sum(sizes))[:, None]
-    grouped = lambda a, b: jax.lax.ragged_dot(
-        a, b, sizes, preferred_element_type=x.dtype)
-    xs = jnp.where(in_group, take_rows(x, order // k, slot_of, k), 0)
-    hid = nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-    ys = jnp.where(in_group, grouped(hid, w_down), 0)           # [A, d]
-    back = take_rows(ys, slot_of, order, 1).reshape(n, k, d)
-    return jnp.sum(back * weight.astype(x.dtype)[..., None], axis=1), sizes
+    return _routed(short_rows(n, k), order, sizes, x, weight, w_gate, w_up,
+                   w_down), sizes
 
 
 def largest(x: jax.Array, k: int) -> jax.Array:
@@ -532,8 +633,11 @@ class ExpertLayer(nn.Module):
             shared = GatedMLP(c.shared_intermediate_size, d, self.dtype,
                               name="shared")(x)
         if not self.is_initializing():      # init's tree is params only
+            short = short_rows(B * T, k)
             self.sow(COUNTERS, "held", jnp.sum(held, dtype=jnp.int32))
             self.sow(COUNTERS, "load", sizes)
+            self.sow(COUNTERS, "short", jnp.float32(short < B * T * k) * (
+                jnp.sum(sizes) <= short))
         return shared + routed
 
 
@@ -769,14 +873,17 @@ def describe(c: "TrunkConfig | LingConfig") -> dict:
 def read_counters(collection: dict) -> dict:
     """The counters of one forward pass from what its layers sowed, one
     entry a group of rows. The expert layers' (each: assignments to held
-    experts, and every held expert's load): assignments held and
-    assignments dropped, each summed over the layers; the fullest held
-    expert's load over the mean held load, the largest of the layers'.
+    experts, every held expert's load, and whether the call took
+    ``routed_experts``' short buffer): assignments held and assignments
+    dropped, each summed over the layers; the fullest held expert's load
+    over the mean held load, the largest of the layers'; the share of the
+    calls (layers x groups of rows) that took the short buffer, 0 where
+    the shapes leave no shorter one than the worst case's.
     The attention layers' (each: the share of its padded grid's tiles
     that the kernel computes, a constant of the trace; 0 where the score
     product took the plain path): the layers on the kernel, and the
     share's mean over them (0 where none is)."""
-    held, ratio, computed, tiles = [], [], [], []
+    held, ratio, computed, tiles, short = [], [], [], [], []
     paths, _ = jax.tree_util.tree_flatten_with_path(collection)
     for path, leaf in paths:
         name = [p.key for p in path if hasattr(p, "key")][-1]
@@ -784,6 +891,8 @@ def read_counters(collection: dict) -> dict:
             held.append(jnp.sum(leaf))
         elif name == "attn_tiles":
             tiles.append(jnp.max(leaf))
+        elif name == "short":
+            short.append(jnp.mean(leaf))
         else:
             load = jnp.sum(leaf.reshape(-1, leaf.shape[-1]), axis=0)
             computed.append(jnp.sum(load))
@@ -796,6 +905,7 @@ def read_counters(collection: dict) -> dict:
             "moe_expert_load_max_over_mean": jnp.max(jnp.stack(ratio)),
             "moe_dropped_assignments": (held - computed).astype(
                 jnp.float32),
+            "moe_short_path_share": jnp.mean(jnp.stack(short)),
             "attn_kernel_layers": layers,
             "attn_tiles_computed_share": jnp.sum(tiles) / jnp.maximum(
                 layers, 1.0)}

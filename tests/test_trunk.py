@@ -248,6 +248,107 @@ def test_every_token_on_one_held_expert_loses_none():
     assert float(jnp.max(jnp.abs(y - once))) < 1e-6
 
 
+# 640 tokens of 4 choices among 16 experts, 4 of them held: the short
+# buffer (``trunk_lib.short_rows``) is 1,536 rows, the worst case 2,560
+STEERED = dataclasses.replace(SHARES, experts_held=(4, 4))
+N_STEERED = 640
+assert trunk_lib.short_rows(N_STEERED, 4) == 1536
+
+
+def steered_params(seed=11):
+    """A share's leaves whose router reads a token's first 16 features,
+    one an expert, so that a test chooses each token's experts."""
+    part = share_params(whole_layer_params(seed), 4, 4)
+    steer = jnp.zeros((STEERED.hidden_size, 16)).at[:16].set(
+        2.0 * jnp.eye(16))
+    part["router"] = {"kernel": steer}
+    return part
+
+
+def steered_tokens(key, held_of_token):
+    """``x[n, d]`` whose token ``t`` chooses ``held_of_token[t]`` of the
+    four held experts (4-7) and the rest of its four among the others:
+    +1 on a chosen expert's feature, -1 on the others' (the router's
+    margin), a little noise so that the weights differ."""
+    held_of_token = np.asarray(held_of_token)
+    n = len(held_of_token)
+    k1, k2 = jax.random.split(key)
+    drive = -np.ones((n, 16), np.float32)
+    others = [e for e in range(16) if not 4 <= e < 8]
+    for t, h in enumerate(held_of_token):
+        drive[t, [4 + (t + j) % 4 for j in range(h)]] = 1.0
+        drive[t, [others[(t + j) % 12] for j in range(4 - h)]] = 1.0
+    drive = drive + 0.3 * jax.random.uniform(k1, (n, 16), minval=-1.0)
+    rest = jax.random.normal(k2, (n, STEERED.hidden_size - 16))
+    return jnp.concatenate([drive, rest], axis=-1)
+
+
+def spread(total: int, n: int) -> list[int]:
+    """``total`` held assignments over ``n`` tokens, four a token from
+    the first token on."""
+    return [min(4, max(0, total - 4 * t)) for t in range(n)]
+
+
+@pytest.mark.parametrize("held", [0, 1, 1535, 1536, 1537, 2560])
+def test_both_buffer_lengths_are_the_dense_reference(held):
+    """``routed_experts`` at the held counts around its short buffer's
+    length (1,536 rows here) and at the worst case: the layer's value
+    and its gradients (the tokens, the router, the three expert kernels,
+    the shared expert) are the dense reference's on either side of the
+    branch, nothing is dropped, and the counter says which length ran."""
+    part = steered_params()
+    x = steered_tokens(jax.random.PRNGKey(12), spread(held, N_STEERED)
+                       ).reshape(5, 128, -1)
+    cot = jax.random.normal(jax.random.PRNGKey(13), x.shape)
+    y, c = counted(STEERED, part, x)
+    assert c["moe_assignments_held"] == held
+    assert c["moe_dropped_assignments"] == 0
+    assert c["moe_short_path_share"] == float(held <= 1536)
+    layer = trunk_lib.ExpertLayer(STEERED, jnp.float32)
+    spec = dict(spec_of(STEERED))
+    with jax.default_matmul_precision("highest"):
+        want = ref.expert_layer(part, x, spec, None)
+        got = jax.grad(lambda p, x: jnp.sum(layer.apply(
+            {"params": p}, x) * cot), argnums=(0, 1))(part, x)
+        wanted = jax.grad(lambda p, x: jnp.sum(ref.expert_layer(
+            p, x, spec, None) * cot), argnums=(0, 1))(part, x)
+    close = lambda a, b: float(jnp.max(jnp.abs(a - b))) < 2e-5 * max(
+        1.0, float(jnp.max(jnp.abs(b))))
+    assert close(y, want)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, leaf), ref_leaf in zip(flat, jax.tree.leaves(wanted)):
+        assert close(leaf, ref_leaf), jax.tree_util.keystr(path)
+    if held:    # the held experts' kernels did get a gradient
+        assert float(jnp.max(jnp.abs(
+            got[0]["experts_down"]["kernel"]))) > 1e-3
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_rows_result_is_the_same_from_either_buffer(dtype):
+    """The rows beside a row decide which buffer its block takes: three
+    mates whose every choice is a held expert's push the block past the
+    short buffer (1,920 + the row's own of 2,560 rows; 1,536 fit), three
+    mates with none leave it inside. The row's result is bit for bit the
+    same: the same terms, added in the same order, at either length."""
+    part = steered_params()
+    T = N_STEERED // 4
+    row = steered_tokens(jax.random.PRNGKey(14), [t % 3 for t in range(T)])
+    mates = lambda h, key: steered_tokens(key, [h] * (3 * T)).reshape(
+        3, T, -1)
+    layer = trunk_lib.ExpertLayer(STEERED, dtype)
+    apply = jax.jit(lambda x: layer.apply(
+        {"params": part}, x.astype(dtype), mutable=[trunk_lib.COUNTERS]))
+    block = lambda m: jnp.concatenate([m[:1], row[None], m[1:]], axis=0)
+    short, c_short = apply(block(mates(0, jax.random.PRNGKey(15))))
+    long, c_long = apply(block(mates(4, jax.random.PRNGKey(16))))
+    share = lambda c: float(trunk_lib.read_counters(
+        c[trunk_lib.COUNTERS])["moe_short_path_share"])
+    assert (share(c_short), share(c_long)) == (1.0, 0.0)
+    assert float(jnp.max(jnp.abs(short[1].astype(jnp.float32)))) > 0.1
+    assert np.array_equal(np.asarray(short[1], np.float32),
+                          np.asarray(long[1], np.float32))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_a_rows_logits_do_not_depend_on_its_minibatch(dtype):
     """Token-choice, dropless: PPO's shuffle may put any rows beside a
@@ -447,6 +548,9 @@ def test_train_cli_logs_the_expert_counters(tmp_path):
         # quarter of the experts held
         assert 0 < float(row["moe_assignments_held"]) <= 16 * 18 * 2 * 4
         assert 1.0 <= float(row["moe_expert_load_max_over_mean"]) <= 2.0
+        # four rows of 18 tokens a block: the worst case's 144 rows are
+        # fewer than a short buffer's 512, so there is none to take
+        assert float(row["moe_short_path_share"]) == 0.0
 
 
 def test_configuration_file_and_trunk_agree_on_every_width():
