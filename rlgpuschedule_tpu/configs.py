@@ -11,6 +11,11 @@ from typing import Literal
 
 from .algos.a2c import A2CConfig
 from .algos.ppo import PPOConfig
+from .models.trunk import TRUNKS
+
+# the token trunks by name, for every CLI's --trunk (one list: the
+# registry's own)
+TRUNK_NAMES = tuple(TRUNKS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,11 +51,12 @@ class ExperimentConfig:
     n_pods: int = 1                     # >1 = hierarchical env (config 5)
     obs_kind: Literal["flat", "grid", "graph", "tokens"] = "flat"
     # obs_kind "tokens": which family of blocks at which whole set of
-    # sizes (models.trunk.TRUNKS): "published" / "tiny" the afmoe blocks
-    # at the source model's widths / the CPU tests' shape, "ling" /
-    # "ling-tiny" the linear-attention blocks likewise. No flag sets a
-    # single width.
-    trunk: Literal["published", "tiny", "ling", "ling-tiny"] = "published"
+    # sizes, one of TRUNK_NAMES (models.trunk.TRUNKS): "published" /
+    # "tiny" the afmoe blocks at the source model's widths / the CPU
+    # tests' shape, "ling" / "ling-tiny" the linear-attention blocks and
+    # "ouro" / "ouro-tiny" the looped dense blocks likewise. No flag sets
+    # a single width or a loop count.
+    trunk: str = "published"
     reward_kind: Literal["jct", "fair"] = "jct"
     n_tenants: int = 1
     nodes_per_rack: int | None = None   # graph topology granularity
@@ -142,6 +148,19 @@ PPO_TRINITY_PHILLY512 = _register(dataclasses.replace(
 # expert layout). Train on a CPU host with --trunk ling-tiny.
 PPO_LING_PHILLY512 = _register(dataclasses.replace(
     PPO_TRINITY_PHILLY512, name="ppo-ling-philly512", trunk="ling"))
+
+# And under the third: one stack of dense multi-head-attention layers
+# applied several times with the same weights, an exit gate a step (one
+# stage of a six-stage pipeline). Train on a CPU host with --trunk
+# ouro-tiny. Its Adam step is the other token presets' over the loop
+# count: Adam moves a weight by about lr a step whatever its gradient,
+# and here every weight acts four times a pass, so at 3e-4 one
+# iteration moved the policy by an approximate KL of 0.2 with three
+# quarters of the ratios clipped (PERF.md section 6, PR 41).
+PPO_OURO_PHILLY512 = _register(dataclasses.replace(
+    PPO_TRINITY_PHILLY512, name="ppo-ouro-philly512", trunk="ouro",
+    ppo=dataclasses.replace(PPO_TRINITY_PHILLY512.ppo,
+                            lr=PPO_TRINITY_PHILLY512.ppo.lr / 4)))
 
 # 3. A2C multi-actor on Alibaba PAI trace, multi-tenant fairness reward.
 # Same proxy arrangement as config 2 (PAI-statistics preset).

@@ -27,6 +27,7 @@ PERCENTILES = (50, 90, 99)
 
 
 def build_parser() -> argparse.ArgumentParser:
+    from .configs import TRUNK_NAMES
     p = argparse.ArgumentParser(
         prog="rlgpuschedule_tpu.evaluate",
         description="JCT evaluation: trained policy vs baseline schedulers.")
@@ -60,8 +61,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="must match the training run when restoring a "
                         "checkpoint (same contract as the cluster-shape "
                         "overrides)")
-    p.add_argument("--trunk", default=None,
-                   choices=["published", "tiny", "ling", "ling-tiny"],
+    p.add_argument("--trunk", default=None, choices=TRUNK_NAMES,
                    help="obs-kind tokens: the trunk sizes the checkpoint "
                         "was trained with (train --trunk)")
     p.add_argument("--drain-frac", type=float, default=None,

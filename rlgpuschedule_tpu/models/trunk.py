@@ -1,16 +1,18 @@
-"""Token trunk (L3): sparse-expert blocks over one token per cluster node
-and per job of the window (``env.obs.token_obs``), of two public
+"""Token trunk (L3): transformer blocks over one token per cluster node
+and per job of the window (``env.obs.token_obs``), of three public
 families. ``TRUNKS`` names each whole set of sizes AND its family:
 ``published`` / ``tiny`` the ``afmoe`` blocks below (:class:`TrunkConfig`),
 ``ling`` / ``ling-tiny`` the linear-attention blocks further down
-(:class:`LingConfig`). **What the two share:** the token observation,
-:class:`TokenTrunk` (the embedding's linear map, blocks rematerialised and
-taken ``ROW_BLOCK`` rows at a time, the final norm and the mean over valid
-tokens), :class:`RMSNorm`, :class:`GatedMLP`, :class:`ExpertLayer` with
-:func:`routed_experts`, :class:`SortedRows` and :func:`sum_rows` (an expert
-layer told which experts it holds), :func:`attend` /
-``ops.attention.blocked_attend`` for a softmax score product, the counters
-and ``ActorCritic``'s heads.
+(:class:`LingConfig`), ``ouro`` / ``ouro-tiny`` the looped dense blocks
+after them (:class:`OuroConfig`). **What they share:** the token
+observation, :class:`TokenTrunk` (the embedding's linear map, blocks
+rematerialised and taken ``ROW_BLOCK`` rows at a time, the final norm and
+the mean over valid tokens), :class:`RMSNorm`, :class:`GatedMLP`,
+:func:`attend` / ``ops.attention.blocked_attend`` for a softmax score
+product, the counters and ``ActorCritic``'s heads; the two sparse families
+also :class:`ExpertLayer` with :func:`routed_experts`, :class:`SortedRows`
+and :func:`sum_rows` (an expert layer told which experts it holds);
+``afmoe`` and ``ouro`` also :class:`Block` and :class:`Attention`.
 
 **The ``afmoe`` block** is the public ``afmoe`` family's (arcee-ai
 Trinity; widths and ``layer_types`` as the family's ``config.json`` keys
@@ -66,6 +68,34 @@ W_gate)_h``; ``W_o``. Its score product is the shared one: q and k have
 ``dn + dr`` = 192 channels and v 128, so the kernel path zero-pads q and k
 to 256 (exact). The expert layers choose **groups before experts**
 (:func:`choose_experts`) and have a shared expert of its own width.
+
+**The ``ouro`` block** (ByteDance Ouro's looped language model: its
+``config.json`` keys; what they do not settle follows "Scaling Latent
+Reasoning via Looped Language Models" and is listed under ``assumed`` in
+``benchmark/configs/philly512-ouro.json``). ONE stack of ``L`` layers is
+applied ``R = total_ut_steps`` times to its own output, ``x^(0)`` the
+embedding::
+
+    for t = 1..R:  z = x^(t-1);  for i = 0..L-1: z = Block_i(z)   the SAME blocks
+                   x^(t) = final_norm(z)          closes every step, feeds the next
+                   lambda_t = sigmoid(x^(t) w_g + b_g)            a token
+    p_1 = lambda_1;  p_t = lambda_t prod_{j<t}(1 - lambda_j);  p_R = the rest
+
+``Block_i`` has the ``afmoe`` block's sandwich form above (and is the same
+class), every layer's MLP dense; its ``Attn`` is plain multi-head (as many
+KV heads as query heads), with RoPE on q and k in EVERY layer and no q/k
+norm, no output gate and no window. The exit rule hands on ``x^(t*)``,
+``t*`` the first step whose cumulated ``p`` reaches ``early_exit_threshold``;
+at the published threshold 1 that is ``R``, every step runs, and the pool
+reads ``x^(R)`` (the rule for a lower threshold is the plain reference's
+``exit_step``; nothing here regroups a batch between steps yet: ROADMAP
+Queue 2 A). The gate and ``p`` are computed at every
+step and feed counters only (the family trains the gate by a loss over each
+step's vocabulary logits, which a policy does not have), so no gradient
+reaches ``exit_gate``'s two leaves. **One compiled body:** the steps are a
+scan (``nn.scan``, parameters broadcast), so the parameter tree holds the
+``L`` layers once, the program holds their bodies once whatever ``R`` is,
+and a leaf's gradient is the sum over its ``R`` uses.
 
 **Two lowerings of the score product**, chosen by
 :func:`attention_path` from what the build can observe and from nothing a
@@ -161,11 +191,15 @@ class TrunkConfig:
     experts_held: tuple[int, int] = (0, 8)      # (first, count)
 
     family = "afmoe"
+    loop_steps = 1              # the layers run once a pass
+    kda_layers = 0
+    # what :class:`Attention` does for this family beside the products
+    qk_norm = attn_gate = True
+    rope_full = False           # RoPE on sliding layers only
 
     def __post_init__(self):
         _check_experts(self)
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError("query heads must be a multiple of KV heads")
+        _check_heads(self)
         if set(self.layer_types) - {SLIDING, FULL}:
             raise ValueError(f"unknown layer type in {self.layer_types}")
 
@@ -180,6 +214,11 @@ class TrunkConfig:
     @property
     def embed_scale(self) -> float:     # as ``mup_enabled`` scales it
         return math.sqrt(self.hidden_size)
+
+
+def _check_heads(c) -> None:
+    if c.num_attention_heads % c.num_key_value_heads:
+        raise ValueError("query heads must be a multiple of KV heads")
 
 
 def _check_experts(c) -> None:
@@ -237,6 +276,7 @@ class LingConfig:
     experts_held: tuple[int, int] = (0, 8)
 
     family = "ling"
+    loop_steps = 1
     embed_scale = 1.0
 
     def __post_init__(self):
@@ -250,8 +290,58 @@ class LingConfig:
     def is_mla(self, layer: int) -> bool:
         return layer % self.layer_group_size == self.layer_group_size - 1
 
+    @property
+    def kda_layers(self) -> int:
+        return sum(not self.is_mla(i) for i in range(self.num_hidden_layers))
 
-TRUNKS: dict[str, "TrunkConfig | LingConfig"] = {
+
+@dataclasses.dataclass(frozen=True)
+class OuroConfig:
+    """The third family (module docstring): the defaults are Ouro-2.6B's
+    published widths and loop count at this repo's cut: the first 8 of
+    its 48 layers, stage 0 of a six-stage pipeline, looped four times.
+    Field names are ``TrunkConfig``'s where the two mean the same (its
+    :class:`Block` and :class:`Attention` serve both); the file
+    ``benchmark/configs/philly512-ouro.json`` has the source's keys."""
+    hidden_size: int = 2048
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    head_dim: int = 128
+    intermediate_size: int = 5632
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-6
+    num_hidden_layers: int = 8
+    total_ut_steps: int = 4
+
+    family = "ouro"
+    embed_scale = 1.0
+    qk_norm = attn_gate = False
+    rope_full = True            # RoPE on every layer
+    sliding_window = None
+    kda_layers = 0
+    n_group = topk_group = 0    # no router
+
+    def __post_init__(self):
+        _check_heads(self)
+        if self.total_ut_steps < 1:
+            raise ValueError("a looped trunk takes at least one step")
+
+    @property
+    def loop_steps(self) -> int:
+        return self.total_ut_steps
+
+    @property
+    def layer_types(self) -> tuple[str, ...]:
+        return (FULL,) * self.num_hidden_layers
+
+    @property
+    def num_dense_layers(self) -> int:          # every layer's MLP
+        return self.num_hidden_layers
+
+
+TrunkConfigs = TrunkConfig | LingConfig | OuroConfig
+
+TRUNKS: dict[str, TrunkConfigs] = {
     "published": TrunkConfig(),
     # the CPU tests' and rehearsals' shape: the window (8) is shorter
     # than any observation, so the sliding mask bites
@@ -271,6 +361,13 @@ TRUNKS: dict[str, "TrunkConfig | LingConfig"] = {
         shared_intermediate_size=24, num_experts=16, num_experts_per_tok=2,
         n_group=4, topk_group=2, num_hidden_layers=6, layer_group_size=3,
         kda_chunk=8, experts_held=(0, 4)),
+    "ouro": OuroConfig(),
+    # two layers looped three times: neither count is 1 and they differ,
+    # so a test can tell a step from a layer
+    "ouro-tiny": OuroConfig(hidden_size=32, num_attention_heads=2,
+                            num_key_value_heads=2, head_dim=16,
+                            intermediate_size=64, num_hidden_layers=2,
+                            total_ut_steps=3),
 }
 
 
@@ -301,9 +398,10 @@ class Kernel(nn.Module):
                           self.shape, jnp.float32)
 
 
-def rope(x: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, theta: float, gain: float = 1.0) -> jax.Array:
     """Rotary embedding over ``x[..., T, H, D]``, position = token index,
-    halves rotated (the ``rotate_half`` convention), in float32."""
+    halves rotated (the ``rotate_half`` convention), in float32; times
+    the constant ``gain`` there, before the one cast back."""
     T, D = x.shape[-3], x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
@@ -312,7 +410,8 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :D // 2], x32[..., D // 2:]
     rot = jnp.concatenate([-x2, x1], axis=-1)
-    return (x32 * cos + rot * sin).astype(x.dtype)
+    y = x32 * cos + rot * sin
+    return (y if gain == 1.0 else y * gain).astype(x.dtype)
 
 
 def attention_mask(valid: jax.Array, window: int | None) -> jax.Array:
@@ -351,7 +450,10 @@ def attention_path(backend: str, head_dim: int, mesh_bound: bool) -> str:
 
 
 class Attention(nn.Module):
-    cfg: TrunkConfig
+    """Softmax attention of the ``afmoe`` and ``ouro`` blocks (module
+    docstring); the family's class attributes say whether q and k are
+    normed, the output gated, and full layers rotated."""
+    cfg: "TrunkConfig | OuroConfig"
     sliding: bool
     dtype: jnp.dtype
 
@@ -364,20 +466,24 @@ class Attention(nn.Module):
         window = c.sliding_window if self.sliding else None
         kernel = attention_path(jax.default_backend(), D,
                                 active_mesh() is not None) == KERNEL
-        # the kernel takes no scale: 1/sqrt(D) goes onto q inside q_norm,
-        # where q is still float32, so that it costs q no rounding of its
-        # own (rope is linear); attend scales the scores itself
+        # the kernel takes no scale: 1/sqrt(D) goes onto q inside q_norm
+        # (or, in a family without one, inside rope), where q is still
+        # float32, so that it costs q no rounding of its own (rope is
+        # linear); attend scales the scores itself
         pre = 1.0 / math.sqrt(D) if kernel else 1.0
         proj = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                         name=name)(x)
         q = proj(Hq * D, "q_proj").reshape(B, T, Hq, D)
         k = proj(Hkv * D, "k_proj").reshape(B, T, Hkv, D)
         v = proj(Hkv * D, "v_proj").reshape(B, T, Hkv, D)
-        gate = proj(Hq * D, "gate_proj")
-        q = RMSNorm(c.rms_norm_eps, self.dtype, name="q_norm")(q, pre)
-        k = RMSNorm(c.rms_norm_eps, self.dtype, name="k_norm")(k)
-        if self.sliding:
-            q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
+        if c.attn_gate:
+            gate = proj(Hq * D, "gate_proj")
+        if c.qk_norm:
+            q = RMSNorm(c.rms_norm_eps, self.dtype, name="q_norm")(q, pre)
+            k = RMSNorm(c.rms_norm_eps, self.dtype, name="k_norm")(k)
+            pre = 1.0
+        if self.sliding or c.rope_full:
+            q, k = rope(q, c.rope_theta, pre), rope(k, c.rope_theta)
         q = q.reshape(B, T, Hkv, Hq // Hkv, D)
         tiles = 0.0
         with jax.named_scope(scopes.ATTN_SLIDING if self.sliding
@@ -390,7 +496,9 @@ class Attention(nn.Module):
                 out = attend(q, k, v, valid, window)
         if not self.is_initializing():      # init's tree is params only
             self.sow(COUNTERS, "attn_tiles", jnp.float32(tiles))
-        out = out.reshape(B, T, Hq * D) * jax.nn.sigmoid(gate)
+        out = out.reshape(B, T, Hq * D)
+        if c.attn_gate:
+            out = out * jax.nn.sigmoid(gate)
         return nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
                         name="o_proj")(out)
 
@@ -642,7 +750,7 @@ class ExpertLayer(nn.Module):
 
 
 class Block(nn.Module):
-    cfg: TrunkConfig
+    cfg: "TrunkConfig | OuroConfig"
     index: int
     dtype: jnp.dtype
 
@@ -807,21 +915,40 @@ class LingBlock(nn.Module):
         return h + m
 
 
-BLOCKS = {"afmoe": Block, "ling": LingBlock}
+BLOCKS = {"afmoe": Block, "ling": LingBlock, "ouro": Block}
+
+
+def exit_distribution(lam: jax.Array) -> jax.Array:
+    """``p[R, ...]`` from the exit gates ``lam[R, ...]`` of a looped
+    trunk's steps: ``p_t = lam_t prod_{j<t}(1 - lam_j)``, and the last
+    step takes what is left, so ``p`` sums to 1 (``lam_R`` is not read)."""
+    stay = jnp.cumprod(1.0 - lam[:-1], axis=0)      # mass not yet exited
+    ones = jnp.ones_like(lam[:1])
+    return jnp.concatenate([lam[:-1], ones]) * jnp.concatenate([ones, stay])
+
+
+def pool(x: jax.Array, valid: jax.Array) -> jax.Array:
+    """float32 mean of ``x[B, T, d]`` over each row's valid tokens."""
+    m = valid[..., None].astype(jnp.float32)
+    return jnp.sum(x * m, axis=-2) / jnp.maximum(jnp.sum(m, axis=-2), 1.0)
 
 
 class TokenTrunk(nn.Module):
     """``obs[..., T, F]`` (last feature: ``valid``) -> float32 ``[..., d]``.
     Each block takes the batch ``ROW_BLOCK`` rows at a time and is
     rematerialised in the backward pass: what a minibatch keeps is each
-    block's input, and what is live is one group of rows' activations."""
-    cfg: "TrunkConfig | LingConfig" = TrunkConfig()
+    block's input (of every application, where the trunk loops), and what
+    is live is one group of rows' activations."""
+    cfg: TrunkConfigs = TrunkConfig()
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
     def __call__(self, obs: jax.Array) -> jax.Array:
         c = self.cfg
         Layer = BLOCKS[c.family]
+        looped = c.family == "ouro"
+        final_norm = lambda trunk, dtype: RMSNorm(
+            c.rms_norm_eps, dtype, parent=trunk, name="final_norm")
         with jax.named_scope(scopes.TRUNK):
             lead = obs.shape[:-2]
             obs = obs.reshape(-1, *obs.shape[-2:])
@@ -836,76 +963,118 @@ class TokenTrunk(nn.Module):
             r = max(b for b in range(1, min(ROW_BLOCK, B) + 1)
                     if B % b == 0)
             groups = lambda a: a.reshape(B // r, r, *a.shape[1:])
-            over_groups = nn.scan(
-                lambda block, carry, xv: (carry, block(*xv)),
-                variable_broadcast="params",
+            scan = partial(
+                nn.scan, variable_broadcast="params",
                 variable_axes={COUNTERS: 0, "intermediates": 0},
                 split_rngs={"params": False})
-            for i in range(c.num_hidden_layers):
-                # inside a scan nothing can merge the recomputation with
-                # the forward pass, so the barriers against it are left out
-                block = nn.remat(Layer, prevent_cse=False)(
-                    c, i, self.dtype, name=f"layer_{i}")
-                _, x = over_groups(block, None, (groups(x), groups(valid)))
-                x = x.reshape(B, T, c.hidden_size)
+            over_groups = scan(lambda block, carry, xv: (carry, block(*xv)))
+
+            def layers(trunk, x):
+                """The ``L`` blocks, one after the other."""
+                for i in range(c.num_hidden_layers):
+                    # inside a scan nothing can merge the recomputation
+                    # with the forward pass, so the barriers against it
+                    # are left out
+                    block = nn.remat(Layer, prevent_cse=False)(
+                        c, i, self.dtype, parent=trunk, name=f"layer_{i}")
+                    _, x = over_groups(block, None,
+                                       (groups(x), groups(valid)))
+                    x = x.reshape(B, T, c.hidden_size)
+                return x
+
+            def step(trunk, carry, _):
+                """One pass of a looped trunk: the blocks, the closing
+                norm (the next step's input) and the exit gate."""
+                x = layers(trunk, carry[0])
+                with jax.named_scope(scopes.LOOP_GATE):
+                    y = final_norm(trunk, self.dtype)(x)
+                    lam = jax.nn.sigmoid(nn.Dense(
+                        1, dtype=self.dtype, parent=trunk,
+                        name="exit_gate")(y)[..., 0].astype(jnp.float32))
+                return (y, carry[0]), lam
+
+            if looped:
+                with jax.named_scope(scopes.TRUNK_LOOP):
+                    (x, before), lam = scan(step, length=c.loop_steps)(
+                        self, (x, x), None)
+                    with jax.named_scope(scopes.LOOP_GATE):
+                        p = exit_distribution(lam)
+            else:
+                x = layers(self, x)
             with jax.named_scope(scopes.TRUNK_POOL):
-                x = RMSNorm(c.rms_norm_eps, jnp.float32,
-                            name="final_norm")(x)
-                m = valid[..., None].astype(jnp.float32)
-                pooled = jnp.sum(x * m, axis=-2) / jnp.maximum(
-                    jnp.sum(m, axis=-2), 1.0)
+                if not looped:          # a loop's last step has normed it
+                    x = final_norm(self, jnp.float32)(x)
+                pooled = pool(x, valid)
+                if looped and not self.is_initializing():   # params only
+                    size = lambda a: jnp.linalg.norm(a, axis=-1)
+                    self.sow(COUNTERS, "loop_exit_mass_last",
+                             jnp.sum(p[-1] * valid) / jnp.maximum(
+                                 jnp.sum(valid), 1))
+                    self.sow(COUNTERS, "loop_last_step_change", jnp.mean(
+                        size(pooled - pool(before, valid))
+                        / jnp.maximum(size(pooled), 1e-30)))
             return pooled.reshape(*lead, c.hidden_size)
 
 
-def describe(c: "TrunkConfig | LingConfig") -> dict:
+def describe(c: TrunkConfigs) -> dict:
     """What a trunk's configuration fixes about its layers, for a run's
     own record (``train.py`` prints it once, at build): constants of the
     build, so no counter carries them through every iteration."""
-    kda_layers = sum(not c.is_mla(i) for i in range(c.num_hidden_layers)) \
-        if c.family == "ling" else 0
     groups, kept = expert_groups(c)
     return {"family": c.family, "layers": c.num_hidden_layers,
-            "kda_layers": kda_layers,
-            "kda_chunk": c.kda_chunk if kda_layers else 0,
+            "loop_steps": c.loop_steps, "kda_layers": c.kda_layers,
+            "kda_chunk": c.kda_chunk if c.kda_layers else 0,
             "moe_groups": groups, "moe_groups_kept": kept}
 
 
 def read_counters(collection: dict) -> dict:
     """The counters of one forward pass from what its layers sowed, one
-    entry a group of rows. The expert layers' (each: assignments to held
+    entry a group of rows (the last axis) and, where the trunk loops, a
+    loop step (the first). The expert layers' (each: assignments to held
     experts, every held expert's load, and whether the call took
     ``routed_experts``' short buffer): assignments held and assignments
     dropped, each summed over the layers; the fullest held expert's load
     over the mean held load, the largest of the layers'; the share of the
     calls (layers x groups of rows) that took the short buffer, 0 where
-    the shapes leave no shorter one than the worst case's.
+    the shapes leave no shorter one than the worst case's; all 0 in a
+    trunk without expert layers.
     The attention layers' (each: the share of its padded grid's tiles
     that the kernel computes, a constant of the trace; 0 where the score
-    product took the plain path): the layers on the kernel, and the
-    share's mean over them (0 where none is)."""
+    product took the plain path): the layer APPLICATIONS on the kernel
+    (a looped trunk applies each layer once a step), and the share's mean
+    over them (0 where none is).
+    A looped trunk's own: the share of exit mass its gates leave to the
+    last step (``p_R``, mean over valid tokens), and how far the last
+    step still moves the pooled output, ``|pool(x^(R)) - pool(x^(R-1))| /
+    |pool(x^(R))|``, mean over rows; 0 in a trunk that does not loop."""
     held, ratio, computed, tiles, short = [], [], [], [], []
+    loop = {"loop_exit_mass_last": 0.0, "loop_last_step_change": 0.0}
     paths, _ = jax.tree_util.tree_flatten_with_path(collection)
     for path, leaf in paths:
         name = [p.key for p in path if hasattr(p, "key")][-1]
         if name == "held":
             held.append(jnp.sum(leaf))
         elif name == "attn_tiles":
-            tiles.append(jnp.max(leaf))
+            tiles.append(jnp.max(jnp.atleast_1d(leaf),
+                                 axis=-1).reshape(-1))
         elif name == "short":
             short.append(jnp.mean(leaf))
+        elif name in loop:
+            loop[name] = leaf
         else:
             load = jnp.sum(leaf.reshape(-1, leaf.shape[-1]), axis=0)
             computed.append(jnp.sum(load))
             ratio.append(jnp.max(load) / jnp.maximum(jnp.mean(
                 load.astype(jnp.float32)), 1.0 / load.shape[0]))
     held, computed = sum(held), sum(computed)
-    tiles = jnp.asarray(tiles, jnp.float32)
+    largest = lambda a: jnp.max(jnp.stack(a)) if a else 0.0
+    tiles = jnp.concatenate([*tiles, jnp.zeros((0,), jnp.float32)])
     layers = jnp.sum(tiles > 0, dtype=jnp.float32)
-    return {"moe_assignments_held": held.astype(jnp.float32),
-            "moe_expert_load_max_over_mean": jnp.max(jnp.stack(ratio)),
-            "moe_dropped_assignments": (held - computed).astype(
-                jnp.float32),
-            "moe_short_path_share": jnp.mean(jnp.stack(short)),
-            "attn_kernel_layers": layers,
-            "attn_tiles_computed_share": jnp.sum(tiles) / jnp.maximum(
-                layers, 1.0)}
+    return jax.tree.map(jnp.float32, {
+        "moe_assignments_held": held,
+        "moe_expert_load_max_over_mean": largest(ratio),
+        "moe_dropped_assignments": held - computed,
+        "moe_short_path_share": jnp.mean(jnp.stack(short)) if short else 0.0,
+        "attn_kernel_layers": layers,
+        "attn_tiles_computed_share": jnp.sum(tiles) / jnp.maximum(
+            layers, 1.0), **loop})

@@ -55,6 +55,20 @@ its own (``LING_TRUNK_TREE``): the names above that it shares, and under
           kda_gates         decays, write strengths, q/k normalisation
           kda_scan          the gated delta rule in chunks (ops.kda)
         attn_mla            a latent-attention layer, projections to o_proj
+
+The third family (``models.trunk.OuroConfig``) applies one stack of dense
+layers several times, so its layers hang under the loop
+(``OURO_TRUNK_TREE``; an operation under ``trunk_attn`` runs once a layer
+APPLICATION, steps x layers a pass)::
+
+    trunk
+      trunk_embed
+      trunk_loop            the scan over loop steps and all inside it
+        trunk_attn          norms, projections, RoPE, o_proj
+          attn_full         scores, mask, softmax, values
+        trunk_dense_mlp     every layer's MLP
+        loop_gate           a step's closing norm, the exit gate, p
+      trunk_pool            mean over valid tokens of the last step's output
 """
 from __future__ import annotations
 
@@ -94,6 +108,8 @@ KDA_CONV = "kda_conv"
 KDA_GATES = "kda_gates"
 KDA_SCAN = "kda_scan"
 ATTN_MLA = "attn_mla"
+TRUNK_LOOP = "trunk_loop"
+LOOP_GATE = "loop_gate"
 
 # every scope as its path from the program's top, parents first
 TREE = (
@@ -142,6 +158,17 @@ LING_TRUNK_TREE = (
     (TRUNK, MOE_ROUTE),
     (TRUNK, MOE_EXPERTS),
     (TRUNK, MOE_SHARED),
+    (TRUNK, TRUNK_POOL),
+)
+# the third family's: its layers under the loop over steps
+OURO_TRUNK_TREE = (
+    (TRUNK,),
+    (TRUNK, TRUNK_EMBED),
+    (TRUNK, TRUNK_LOOP),
+    (TRUNK, TRUNK_LOOP, TRUNK_ATTN),
+    (TRUNK, TRUNK_LOOP, TRUNK_ATTN, ATTN_FULL),
+    (TRUNK, TRUNK_LOOP, TRUNK_DENSE_MLP),
+    (TRUNK, TRUNK_LOOP, LOOP_GATE),
     (TRUNK, TRUNK_POOL),
 )
 TRUNK_PARENTS = ((ROLLOUT, POLICY_FORWARD), (UPDATE, LOSS_GRAD))

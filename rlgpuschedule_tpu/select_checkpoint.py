@@ -26,6 +26,7 @@ import sys
 
 
 def build_parser() -> argparse.ArgumentParser:
+    from .configs import TRUNK_NAMES
     p = argparse.ArgumentParser(
         prog="rlgpuschedule_tpu.select_checkpoint",
         description="Rank retained checkpoints by full-trace JCT on a "
@@ -62,8 +63,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--horizon", type=int, default=None)
     p.add_argument("--obs-kind", default=None,
                    choices=["flat", "grid", "graph", "tokens"])
-    p.add_argument("--trunk", default=None,
-                   choices=["published", "tiny", "ling", "ling-tiny"],
+    p.add_argument("--trunk", default=None, choices=TRUNK_NAMES,
                    help="obs-kind tokens: the trunk sizes the checkpoint "
                         "was trained with (train --trunk)")
     p.add_argument("--trace-load", type=float, default=None,

@@ -51,6 +51,7 @@ import sys
 
 
 def build_parser() -> argparse.ArgumentParser:
+    from ..configs import TRUNK_NAMES
     p = argparse.ArgumentParser(
         prog="rlgpuschedule_tpu.serve",
         description="Fleet-scale policy serving: continuous-batching "
@@ -76,8 +77,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--horizon", type=int, default=None)
     p.add_argument("--obs-kind", default=None,
                    choices=["flat", "grid", "graph", "tokens"])
-    p.add_argument("--trunk", default=None,
-                   choices=["published", "tiny", "ling", "ling-tiny"],
+    p.add_argument("--trunk", default=None, choices=TRUNK_NAMES,
                    help="obs-kind tokens: the trunk sizes the checkpoint "
                         "was trained with (train --trunk)")
     # bench mode

@@ -23,8 +23,8 @@ import json
 import re
 import sys
 
-from .configs import (CONFIGS, ExperimentConfig, ModeCombinationError,
-                      validate_mode_combination)
+from .configs import (CONFIGS, TRUNK_NAMES, ExperimentConfig,
+                      ModeCombinationError, validate_mode_combination)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -50,13 +50,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="override the preset's observation/encoder family "
                         "(e.g. train config 2's cluster on the flat MLP "
                         "encoder on a CPU host)")
-    p.add_argument("--trunk", default=None,
-                   choices=["published", "tiny", "ling", "ling-tiny"],
+    p.add_argument("--trunk", default=None, choices=TRUNK_NAMES,
                    help="obs-kind tokens: the token trunk's family and "
                         "whole set of sizes (models.trunk.TRUNKS): a "
                         "source model's published widths (published: "
-                        "afmoe blocks; ling: linear-attention blocks), or "
-                        "its tiny shape for a CPU host (tiny; ling-tiny)")
+                        "afmoe blocks; ling: linear-attention blocks; "
+                        "ouro: looped dense blocks), or its tiny shape "
+                        "for a CPU host (tiny; ling-tiny; ouro-tiny)")
     p.add_argument("--trace", default=None,
                    choices=["synthetic", "philly", "pai", "philly-proxy",
                             "pai-proxy"],

@@ -47,10 +47,15 @@ SMALL["tokens"] = dataclasses.replace(
 SMALL["ling"] = dataclasses.replace(SMALL["tokens"],
                                     name="ppo-ling-philly512",
                                     trunk="ling-tiny")
+# the third, whose layers hang under a loop over steps (ISSUE 41)
+SMALL["ouro"] = dataclasses.replace(SMALL["tokens"],
+                                    name="ppo-ouro-philly512",
+                                    trunk="ouro-tiny")
 SCOPE_NAMES = tuple(path[-1] for path in scopes.TREE)
 TRUNK_SCOPE_NAMES = tuple(path[-1] for path in scopes.TRUNK_TREE)
 # each token policy's step with the tree of its family
-TRUNK_TREES = {"tokens": scopes.TRUNK_TREE, "ling": scopes.LING_TRUNK_TREE}
+TRUNK_TREES = {"tokens": scopes.TRUNK_TREE, "ling": scopes.LING_TRUNK_TREE,
+               "ouro": scopes.OURO_TRUNK_TREE}
 
 
 def lower_step(algo: str):
@@ -101,6 +106,12 @@ def test_scope_is_on_the_ling_policys_train_step(lowered_names, scope):
     assert scope in lowered_names("ling")
 
 
+@pytest.mark.parametrize("scope", SCOPE_NAMES + tuple(
+    path[-1] for path in scopes.OURO_TRUNK_TREE))
+def test_scope_is_on_the_ouro_policys_train_step(lowered_names, scope):
+    assert scope in lowered_names("ouro")
+
+
 @pytest.mark.parametrize("parent", scopes.TRUNK_PARENTS,
                          ids=lambda p: "/".join(p))
 @pytest.mark.parametrize("policy,path", [
@@ -133,7 +144,9 @@ def test_tree_is_parents_first_and_names_are_unique():
         SCOPE_NAMES + TRUNK_SCOPE_NAMES)
     ling = tuple(path[-1] for path in scopes.LING_TRUNK_TREE)
     assert len(set(SCOPE_NAMES + ling)) == len(SCOPE_NAMES + ling)
-    for tree in (scopes.TREE, scopes.TRUNK_TREE, scopes.LING_TRUNK_TREE):
+    ouro = tuple(path[-1] for path in scopes.OURO_TRUNK_TREE)
+    assert len(set(SCOPE_NAMES + ouro)) == len(SCOPE_NAMES + ouro)
+    for tree in (scopes.TREE, *TRUNK_TREES.values()):
         seen = set()
         for path in tree:
             assert path[:-1] == () or path[:-1] in seen

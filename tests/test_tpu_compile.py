@@ -255,3 +255,47 @@ def test_token_policy_update_compiles_and_leaves_room_for_a_second_state(
     assert "ragged-dot" in text
     assert ("splash_mqa" in text) == (path == "kernel")
     assert (f"{tokens},{tokens}]" in text) == (path == "plain")
+
+
+def test_looped_trunk_gradient_compiles_with_the_layers_once(one_chip,
+                                                             monkeypatch):
+    """``TRUNKS['ouro']`` at the published widths on the kernel path: the
+    gradient of one minibatch of the ``philly512-ouro.train`` cell (one
+    ``ROW_BLOCK`` of 832-token rows) through the scan over four loop
+    steps, the rematerialised blocks and the attention kernels. The
+    compiled program holds the eight layers' kernels once for the forward
+    pass and once each for the recomputation and the backward pass,
+    whatever the loop count (written out it would hold 32 of each), and
+    what it keeps beside the parameters and their gradient stays under
+    3 GB (each application's input, 32 x 27 MB, and one block's
+    activations)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from rlgpuschedule_tpu.env.obs import TOKEN_FEATURES as F
+    from rlgpuschedule_tpu.models import TRUNKS, make_policy
+    from rlgpuschedule_tpu.models.trunk import ROW_BLOCK
+    c, tokens, A = TRUNKS["ouro"], 832, 129
+    net = make_policy("tokens", A, trunk="ouro")
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, tokens, F)), jnp.ones((1, A), bool))
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert n_params == 411_400_323
+
+    def loss(p, obs, mask):
+        logits, value = net.apply(p, obs, mask)
+        return jnp.sum(value) + jnp.sum(jax.nn.log_softmax(logits)[:, 0])
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    with time_limit(120):
+        compiled = jax.jit(jax.grad(loss)).lower(
+            shapes(params, one_chip), sds((ROW_BLOCK, tokens, F),
+                                          jnp.float32),
+            sds((ROW_BLOCK, A), jnp.bool_)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "splash_mqa" in line]
+    forward = sum("splash_mqa_fwd" in line for line in calls)
+    assert forward == 2 * c.num_hidden_layers       # forward, recomputation
+    assert len(calls) - forward >= c.num_hidden_layers      # backward
+    assert len(calls) < 2 * c.total_ut_steps * c.num_hidden_layers
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes - 4 * n_params < 3e9, m

@@ -504,8 +504,9 @@ def test_train_cli_says_once_what_the_trunk_fixes(tmp_path):
         "--n-steps", "8", "--n-epochs", "1", "--n-minibatches", "2",
         "--iterations", "2", "--log-every", "1", "--log-csv", str(path)])
     assert summary["trunk"] == {
-        "name": "ling-tiny", "family": "ling", "layers": 6, "kda_layers": 4,
-        "kda_chunk": 8, "moe_groups": 4, "moe_groups_kept": 2}
+        "name": "ling-tiny", "family": "ling", "layers": 6, "loop_steps": 1,
+        "kda_layers": 4, "kda_chunk": 8, "moe_groups": 4,
+        "moe_groups_kept": 2}
     with open(path) as f:
         rows = list(csv.DictReader(f))
     assert len(rows) == 2
