@@ -111,14 +111,16 @@ class PPOMetrics(NamedTuple):
 # :class:`PPOMetrics` and the token trunk's counters (what each counts:
 # ``models.trunk.read_counters``: assignments held and dropped, the
 # fullest held expert's load, the short buffer's share of calls, the
-# attention kernel's layer applications and tiles, a looped trunk's exit
-# mass and last step), for a policy whose apply has a ``counted`` form;
-# read in the update's own loss forward, each reduced over the
-# minibatches as ``COUNTER_REDUCTIONS`` says (max where none).
+# attention kernel's layer applications and tiles, the KDA layers on the
+# chunked rule's kernels, a looped trunk's exit mass and last step), for
+# a policy whose apply has a ``counted`` form; read in the update's own
+# loss forward, each reduced over the minibatches as
+# ``COUNTER_REDUCTIONS`` says (max where none).
 MOE_COUNTERS = ("moe_assignments_held", "moe_expert_load_max_over_mean",
                 "moe_dropped_assignments", "moe_short_path_share",
                 "attn_kernel_layers", "attn_tiles_computed_share",
-                "loop_exit_mass_last", "loop_last_step_change")
+                "kda_kernel_layers", "loop_exit_mass_last",
+                "loop_last_step_change")
 COUNTER_REDUCTIONS = {"moe_assignments_held": jnp.mean,
                       "moe_dropped_assignments": jnp.sum,
                       "moe_short_path_share": jnp.mean,

@@ -56,10 +56,12 @@ Layers ``i % layer_group_size != layer_group_size - 1`` are **KDA**
 channel ``g = kda_lower_bound * sigmoid(exp(A_log) * (u W_f + dt_bias))``
 and a write strength a head ``beta = sigmoid(u W_beta)``; the gated delta
 rule over a ``[D, D]`` state a head (``ops.kda``: in chunks, never token by
-token); ``(RMSNorm_head(o) * sigmoid(u W_g)) W_o``. No RoPE. A token with
-``valid = 0`` feeds zeros to the convolutions and leaves the state as it
-was (``beta`` 0, ``g`` 0): the policy's departure, like the pool. The last
-layer of a period is **MLA** (:class:`MLA`): ``[c, r] = u W_kva``;
+token; as Pallas kernels where ``ops.kda.delta_rule_path`` says a build
+gets them, plain ``jax.numpy`` elsewhere, and ``kda_kernel_layers`` says
+which ran); ``(RMSNorm_head(o) * sigmoid(u W_g)) W_o``. No RoPE. A token
+with ``valid = 0`` feeds zeros to the convolutions and leaves the state as
+it was (``beta`` 0, ``g`` 0): the policy's departure, like the pool. The
+last layer of a period is **MLA** (:class:`MLA`): ``[c, r] = u W_kva``;
 ``[k_nope, v]_h = RMSNorm(c) W_kvb``; one rope key ``RoPE(RMSNorm(r))`` a
 token for all heads; ``[q_nope, q_rope]_h = RMSNorm_head(u W_q)``, RoPE on
 ``q_rope``; causal softmax of ``q . [k_nope, k_rope] / sqrt(dn + dr)``
@@ -799,6 +801,7 @@ class KDA(nn.Module):
 
     @nn.compact
     def __call__(self, u: jax.Array, valid: jax.Array) -> jax.Array:
+        from ..parallel.sharding import active_mesh
         c = self.cfg
         B, T, _ = u.shape
         H, D = c.num_attention_heads, c.head_dim
@@ -827,9 +830,13 @@ class KDA(nn.Module):
                              0.0)
             q = unit(q, 1.0 / math.sqrt(D)).astype(self.dtype)
             k = unit(k).astype(self.dtype)
+        path = kda.delta_rule_path(jax.default_backend(), D, D, c.kda_chunk,
+                                   self.dtype, active_mesh() is not None)
         with jax.named_scope(scopes.KDA_SCAN):
             o = kda.chunked_delta_rule(q, k, v, g, beta, chunk=c.kda_chunk,
-                                       dtype=self.dtype)
+                                       dtype=self.dtype, path=path)
+        if not self.is_initializing():      # init's tree is params only
+            self.sow(COUNTERS, "kda_kernel", jnp.float32(path == kda.KERNEL))
         o = RMSNorm(c.rms_norm_eps, self.dtype, name="o_norm")(o)
         out = o.reshape(B, T, H * D) * jax.nn.sigmoid(dense(H * D, "g_proj"))
         return nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
@@ -1043,11 +1050,14 @@ def read_counters(collection: dict) -> dict:
     product took the plain path): the layer APPLICATIONS on the kernel
     (a looped trunk applies each layer once a step), and the share's mean
     over them (0 where none is).
+    The KDA layers' (each: 1 where its chunks took the kernel pair,
+    ``ops.kda.delta_rule_path``'s answer, a constant of the trace): the
+    layer applications on it; 0 in a trunk without KDA layers.
     A looped trunk's own: the share of exit mass its gates leave to the
     last step (``p_R``, mean over valid tokens), and how far the last
     step still moves the pooled output, ``|pool(x^(R)) - pool(x^(R-1))| /
     |pool(x^(R))|``, mean over rows; 0 in a trunk that does not loop."""
-    held, ratio, computed, tiles, short = [], [], [], [], []
+    held, ratio, computed, tiles, short, kernels = [], [], [], [], [], []
     loop = {"loop_exit_mass_last": 0.0, "loop_last_step_change": 0.0}
     paths, _ = jax.tree_util.tree_flatten_with_path(collection)
     for path, leaf in paths:
@@ -1059,6 +1069,8 @@ def read_counters(collection: dict) -> dict:
                                  axis=-1).reshape(-1))
         elif name == "short":
             short.append(jnp.mean(leaf))
+        elif name == "kda_kernel":
+            kernels.append(jnp.max(leaf))
         elif name in loop:
             loop[name] = leaf
         else:
@@ -1075,6 +1087,6 @@ def read_counters(collection: dict) -> dict:
         "moe_expert_load_max_over_mean": largest(ratio),
         "moe_dropped_assignments": held - computed,
         "moe_short_path_share": jnp.mean(jnp.stack(short)) if short else 0.0,
-        "attn_kernel_layers": layers,
+        "attn_kernel_layers": layers, "kda_kernel_layers": sum(kernels),
         "attn_tiles_computed_share": jnp.sum(tiles) / jnp.maximum(
             layers, 1.0), **loop})

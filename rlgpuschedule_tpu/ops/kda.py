@@ -1,7 +1,12 @@
 """The gated delta rule of a KDA layer (Kimi Delta Attention: a linear
 attention whose state is a matrix a head, decayed channel by channel),
-computed in chunks. Plain ``jax.numpy``: the backward pass is autodiff of
-the chunked form.
+computed in chunks, in two lowerings of one mathematics
+(:func:`delta_rule_path` says which a build gets): on a TPU, at the
+published block's shapes, a pair of Pallas kernels that keep a head's state
+and a chunk's tiles in VMEM (``ops.kda_kernel``: forward, and a backward
+pass written out); everywhere else plain ``jax.numpy``
+(:func:`plain_chunks`), whose backward pass is autodiff of the chunked form
+and which is the kernels' oracle in the tests.
 
 A head's state ``S`` is ``[K, V]`` (key channels x value channels), zero
 before the first token. Token ``t`` brings ``q_t, k_t`` ``[K]``, ``v_t``
@@ -74,6 +79,7 @@ import jax.numpy as jnp
 CHUNK = 64      # tokens a chunk: one [64, 64] solve a head
 SUB = 16        # tokens a sub-block: SUB x max|g| must stay under 88
 MAX_LOG_DECAY = 80.0    # what a sub-block's keys may decay by, in all
+KERNEL, PLAIN = "kernel", "plain"       # delta_rule_path's two answers
 
 
 def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -150,29 +156,31 @@ def unit_lower_inverse(A, sub: int = SUB):
     return inv[..., 0, :, :]
 
 
-def chunked_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
-                       dtype=jnp.float32):
-    """The recurrence of the module docstring for ``q, k, g`` ``[B, T, H,
-    K]``, ``v`` ``[B, T, H, V]``, ``beta`` ``[B, T, H]``, from a zero
-    state; returns ``o`` float32 ``[B, T, H, V]``. ``T`` is padded to
-    whole chunks with tokens that leave the state alone."""
-    B, T, H, K = q.shape
+def delta_rule_path(backend: str, K: int, V: int, chunk: int, dtype,
+                    mesh_bound: bool) -> str:
+    """Which lowering of the chunks a build gets (module docstring):
+    ``KERNEL`` iff the backend is a TPU, a head's state is made of whole
+    128-lane tiles both ways, a chunk is whole sub-blocks (whose bfloat16
+    tiles fill their 16 sublanes), the products' operands are bfloat16 (a
+    float32 build wants products the kernel does not make) and the
+    program is not one GSPMD partitions over a mesh (a Mosaic custom call
+    is not partitioned for us). Any ``T``, any number of rows and heads:
+    the grid runs over them."""
+    fits = (backend == "tpu" and K % 128 == 0 and V % 128 == 0
+            and chunk % SUB == 0 and jnp.dtype(dtype) == jnp.bfloat16
+            and not mesh_bound)
+    return KERNEL if fits else PLAIN
+
+
+def plain_chunks(q, k, v, G, beta, dtype):
+    """The chunks in order, written out (module docstring), for ``q, k``
+    ``[N, B, H, C, K]`` and ``v`` ``[.., C, V]`` in ``dtype``, the decays'
+    running sums ``G`` ``[.., C, K]`` and ``beta`` ``[.., C, 1]`` float32;
+    ``O`` float32 ``[N, B, H, C, V]``. ``ops.kda_kernel.chunks``'
+    contract, and its oracle."""
+    _, B, H, C, K = q.shape
     V = v.shape[-1]
-    C = chunk
-    if C < 1 or C & (C - 1):
-        raise ValueError(f"chunk {C} is not a power of two")
-    # g is the caller's promise (the gate bounds it); nothing here checks
-    # a traced value
-    N = -(-T // C)
     f32 = jnp.float32
-
-    def tiles(z):       # [B, T, H, X] -> [N, B, H, C, X], zero padded
-        z = jnp.pad(z, ((0, 0), (0, N * C - T), (0, 0), (0, 0)))
-        return z.reshape(B, N, C, H, -1).transpose(1, 0, 3, 2, 4)
-
-    q, k, v = tiles(q), tiles(k), tiles(v)
-    G = jnp.cumsum(tiles(g.astype(f32)), axis=-2)       # [N, B, H, C, K]
-    beta = tiles(beta.astype(f32)[..., None])           # [N, B, H, C, 1]
     M = decayed_products(jnp.stack([k, q], axis=-3), k, G, dtype)
     strict = jnp.tril(jnp.ones((C, C), bool), -1)
     A = jnp.where(strict, beta * M[..., 0, :, :], 0.0)
@@ -197,6 +205,44 @@ def chunked_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
         S = keep * S + mm("bhck,bhcv->bhkv", k_out, U)
         return S, O
 
-    _, O = jax.lax.scan(step, jnp.zeros((B, H, K, V), f32),
-                        (U0, Wm, Bm, q_in, k_out, keep))
+    return jax.lax.scan(step, jnp.zeros((B, H, K, V), f32),
+                        (U0, Wm, Bm, q_in, k_out, keep))[1]
+
+
+def chunked_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
+                       dtype=jnp.float32, path: str = PLAIN,
+                       interpret: bool | None = None):
+    """The recurrence of the module docstring for ``q, k, g`` ``[B, T, H,
+    K]``, ``v`` ``[B, T, H, V]``, ``beta`` ``[B, T, H]``, from a zero
+    state; returns ``o`` float32 ``[B, T, H, V]``. ``T`` is padded to
+    whole chunks with tokens that leave the state alone. ``path``: the
+    chunks' lowering, :func:`delta_rule_path`'s answer for this build
+    (``interpret`` None: interpret the kernels where the backend is not a
+    TPU, the tests' way; run them where it is)."""
+    B, T, H, K = q.shape
+    V = v.shape[-1]
+    C = chunk
+    if C < 1 or C & (C - 1):
+        raise ValueError(f"chunk {C} is not a power of two")
+    # g is the caller's promise (the gate bounds it); nothing here checks
+    # a traced value
+    N = -(-T // C)
+    f32 = jnp.float32
+
+    def tiles(z):       # [B, T, H, X] -> [N, B, H, C, X], zero padded
+        z = jnp.pad(z, ((0, 0), (0, N * C - T), (0, 0), (0, 0)))
+        return z.reshape(B, N, C, H, -1).transpose(1, 0, 3, 2, 4)
+
+    q, k, v = tiles(q), tiles(k), tiles(v)
+    G = jnp.cumsum(tiles(g.astype(f32)), axis=-2)       # [N, B, H, C, K]
+    beta = tiles(beta.astype(f32)[..., None])           # [N, B, H, C, 1]
+    if path == KERNEL:
+        from . import kda_kernel        # Pallas: this path only
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        O = kda_kernel.chunks(q.astype(dtype), k.astype(dtype),
+                              v.astype(dtype), G,
+                              jnp.swapaxes(beta, -1, -2), interpret)
+    else:
+        O = plain_chunks(q, k, v, G, beta, dtype)
     return O.transpose(1, 0, 3, 2, 4).reshape(B, N * C, H, V)[:, :T]

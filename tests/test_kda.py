@@ -1,7 +1,10 @@
 """``ops.kda``: the gated delta rule in chunks against the recurrence
 written token by token, values and gradients, in float32; the decayed
 inner products against the pairwise sum; the causal convolution against a
-written-out sum; and that bfloat16 decays or state would not pass.
+written-out sum; and that bfloat16 decays or state would not pass. The
+kernel pair (``ops.kda_kernel``, interpreted here) against the plain path
+and the recurrence at the published head size, values and gradients, and
+the table of which build gets which.
 """
 import jax
 import jax.numpy as jnp
@@ -28,7 +31,8 @@ def recurrence(q, k, v, g, beta, state_dtype=jnp.float32):
     return jax.lax.scan(token, S0, xs)[1].swapaxes(0, 1)
 
 
-def inputs(T: int, invalid=(), seed: int = 0, lower: float = -5.0):
+def inputs(T: int, invalid=(), seed: int = 0, lower: float = -5.0,
+           B: int = B, H: int = H, K: int = K, V: int = V):
     """A layer's tensors as the trunk makes them: unit k, q of norm
     K^-1/2, g in (lower, 0), beta in (0, 1); tokens in ``invalid`` leave
     the state alone (beta 0, g 0)."""
@@ -206,3 +210,136 @@ def test_causal_convolution_against_the_written_out_sum(W):
 def test_chunk_must_be_a_power_of_two():
     with pytest.raises(ValueError, match="power of two"):
         kda.chunked_delta_rule(*inputs(8), chunk=12)
+
+
+# ---- the kernel pair (interpreted), at the published head size ------------
+
+D = 128
+# T, rows, heads, tokens passed over: 1, 2 and 13 chunks of 64; T no
+# multiple of the chunk; 1, 3 and 4 rows (a block of heads is the largest
+# divisor of H up to ops.kda_kernel.HEADS: 1, 2 and 3 here); a run of
+# tokens passed over across a chunk's edge, and one at the end
+KERNEL_CASES = [
+    (64, 1, 1, ()), (128, 3, 2, ()), (832, 1, 1, ()), (100, 4, 2, ()),
+    (150, 2, 3, tuple(range(58, 75))), (150, 1, 2, tuple(range(120, 150))),
+]
+FIVE = (0, 1, 2, 3, 4)
+
+
+def kernel_and(other, dtype):
+    rule = lambda path: lambda *a: kda.chunked_delta_rule(
+        *a, chunk=64, dtype=dtype, path=path, interpret=True)
+    return rule(kda.KERNEL), (recurrence if other == "recurrence"
+                              else rule(kda.PLAIN))
+
+
+def values_and_gradients(fn, args):
+    loss = lambda *a: jnp.sum(jnp.sin(fn(*a)))
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(fn)(*args), jax.jit(jax.grad(loss, FIVE))(*args)
+
+
+@pytest.mark.parametrize("other", ["recurrence", "plain"])
+@pytest.mark.parametrize("T,rows,heads,invalid", KERNEL_CASES)
+def test_kernel_pair_in_float32(T, rows, heads, invalid, other):
+    """The kernels on float32 tiles against the token-by-token recurrence
+    and against the plain path: outputs and the gradients of all five
+    inputs. What separates them is the solve's three bfloat16 passes (16
+    bits of mantissa, as the plain path's on a TPU; float32 on this
+    backend), so 1e-4 of each one's largest entry where the plain path
+    holds 2e-5; a wrong decay on one edge moves an output by 1e-2."""
+    args = inputs(T, invalid, seed=3, B=rows, H=heads, K=D, V=D)
+    kernel, fn = kernel_and(other, jnp.float32)
+    got, grads = values_and_gradients(kernel, args)
+    want, wants = values_and_gradients(fn, args)
+    assert got.shape == want.shape == (rows, T, heads, D)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-4 * float(
+        jnp.max(jnp.abs(want)))
+    for name, a, b in zip("qkvgb", grads, wants):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-4 * float(
+            jnp.max(jnp.abs(b))), name
+
+
+@pytest.mark.parametrize("where", ["usual", "every_token",
+                                   "every_other_token"])
+@pytest.mark.parametrize("other", ["recurrence", "plain"])
+def test_kernel_pair_with_bfloat16_tiles(other, where):
+    """The trunk's call: q, k, v and the tile products in bfloat16, at
+    usual decays and with every gate at the family's bound ``g = -5`` (on
+    every token, or on every other one), where a sub-block's keys decay by
+    ``exp(-80)`` and the factors ``exp(80)`` meet ``exp(-75)`` ones in the
+    backward products too. Against the plain path on the same bfloat16
+    tiles and against the float32 recurrence: finite, and within 1e-2 of
+    the largest entry (the five gradients on their common scale), the
+    plain path's own tolerance at the bound."""
+    T = 150
+    q, k, v, g, beta = inputs(T, (40, 41, 42), seed=2, B=2, H=2, K=D, V=D)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    at_bound = jnp.full_like(g, -kda.MAX_LOG_DECAY / kda.SUB)
+    there = beta[..., None] > 0
+    if where == "every_token":
+        g = jnp.where(there, at_bound, 0.0)
+    elif where == "every_other_token":
+        g = jnp.where(there & (jnp.arange(T) % 2 == 0)[None, :, None, None],
+                      at_bound, g)
+    kernel, fn = kernel_and(other, jnp.bfloat16)
+    if other == "recurrence":
+        fn = lambda *a, f=fn: f(*(x.astype(jnp.float32) for x in a))
+    got, grads = values_and_gradients(kernel, (q, k, v, g, beta))
+    want, wants = values_and_gradients(fn, (q, k, v, g, beta))
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-2 * float(
+        jnp.max(jnp.abs(want)))
+    f32 = lambda x: x.astype(jnp.float32)
+    scale = max(float(jnp.max(jnp.abs(f32(x)))) for x in wants)
+    for name, a, b in zip("qkvgb", grads, wants):
+        assert a.dtype == b.dtype, name
+        assert bool(jnp.all(jnp.isfinite(f32(a)))), name
+        assert float(jnp.max(jnp.abs(f32(a) - f32(b)))) < 1e-2 * scale, name
+
+
+def test_kernel_passes_over_a_token_as_the_plain_path_does():
+    """What a token passed over holds in q, k and v reaches no later
+    output through the kernels either, bit for bit."""
+    hole = (58, 59, 63, 64, 65, 149)
+    q, k, v, g, beta = inputs(150, hole, B=1, H=2, K=D, V=D)
+    other = inputs(150, (), seed=5, B=1, H=2, K=D, V=D)
+    at = jnp.zeros((150,), bool).at[jnp.asarray(hole)].set(True)
+    swap = lambda a, b: jnp.where(at[None, :, None, None], b, a)
+    rule = jax.jit(kernel_and("plain", jnp.float32)[0])
+    a = rule(q, k, v, g, beta)
+    b = rule(swap(q, other[0]), swap(k, other[1]), swap(v, other[2]), g,
+             beta)
+    keep = ~np.asarray(at)
+    assert np.array_equal(np.asarray(a)[:, keep], np.asarray(b)[:, keep])
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("build,path", [
+    # backend, K, V, chunk, dtype, mesh bound
+    (("tpu", 128, 128, 64, BF16, False), kda.KERNEL),   # the ling cell
+    (("tpu", 128, 128, 16, BF16, False), kda.KERNEL),   # one sub-block
+    (("tpu", 128, 256, 64, BF16, False), kda.KERNEL),   # whole lane tiles
+    (("cpu", 128, 128, 64, BF16, False), kda.PLAIN),    # any other backend
+    (("gpu", 128, 128, 64, BF16, False), kda.PLAIN),
+    (("tpu", 128, 128, 64, F32, False), kda.PLAIN),     # a float32 build
+    (("tpu", 128, 128, 64, BF16, True), kda.PLAIN),     # GSPMD over a mesh
+    (("tpu", 16, 16, 8, BF16, False), kda.PLAIN),       # the tiny preset
+    (("tpu", 64, 128, 64, BF16, False), kda.PLAIN),     # half a lane tile
+    (("tpu", 128, 64, 64, BF16, False), kda.PLAIN),
+    (("tpu", 128, 128, 8, BF16, False), kda.PLAIN),     # under a sub-block
+])
+def test_which_build_gets_the_kernels(build, path):
+    assert kda.delta_rule_path(*build) == path
+
+
+def test_the_published_block_gets_the_kernels_and_the_tiny_one_does_not():
+    from rlgpuschedule_tpu.models.trunk import TRUNKS
+    answer = lambda c, backend: kda.delta_rule_path(
+        backend, c.head_dim, c.head_dim, c.kda_chunk, BF16, False)
+    assert answer(TRUNKS["ling"], "tpu") == kda.KERNEL
+    assert answer(TRUNKS["ling"], "cpu") == kda.PLAIN
+    assert answer(TRUNKS["ling-tiny"], "tpu") == kda.PLAIN

@@ -20,6 +20,7 @@ rehearsal (CHANGES.md, PR 24).
 import contextlib
 import dataclasses
 import os
+import re
 import time
 
 import jax
@@ -182,6 +183,109 @@ def test_chunked_delta_rule_compiles_at_the_cells_call_shape(one_chip):
             sds(b, T, H, dtype=jnp.float32)).compile()
     assert "64,64,128]" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def kda_call(one_chip, tokens: int = 832):
+    """One call of ``ops.kda.chunked_delta_rule`` on the kernel path as
+    the ``ling`` cell makes it (``ROW_BLOCK`` rows of 832 tokens, 32 heads
+    of 128, chunks of 64, bfloat16 tiles), and its arguments as shapes on
+    the described chip."""
+    from rlgpuschedule_tpu.models.trunk import ROW_BLOCK
+    from rlgpuschedule_tpu.ops import kda
+    b, H, D = ROW_BLOCK, 32, 128
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    rule = lambda q, k, v, g, beta: kda.chunked_delta_rule(
+        q, k, v, g, beta, chunk=64, dtype=jnp.bfloat16, path=kda.KERNEL,
+        interpret=False)
+    return rule, (sds(b, tokens, H, D), sds(b, tokens, H, D),
+                  sds(b, tokens, H, D),
+                  sds(b, tokens, H, D, dtype=jnp.float32),
+                  sds(b, tokens, H, dtype=jnp.float32))
+
+
+def lowered_gradient(fn, args):
+    """The gradient of ``sum(fn(q, k, v, g, beta))`` in all five, lowered
+    for the described chip."""
+    loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32))
+    return jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args)
+
+
+def kernel_bodies(text: str) -> list[str]:
+    """The serialized Mosaic module of every ``tpu_custom_call`` in a
+    lowered program's StableHLO text, in order."""
+    return re.findall(r'\\22body\\22: \\22([^\\]+)\\22', text)
+
+
+def test_delta_rule_kernels_compile_at_the_cells_call_shape(one_chip):
+    """``ops.kda_kernel``, forward and gradient, at one call of the
+    ``ling`` cell: Mosaic takes both bodies (the batched products with a
+    transposed operand, the sub-block slices, the VMEM they ask for), the
+    program holds the two kernels by their fixed names, and what it keeps
+    (the kernel's residuals: 184 KB a head and chunk, 0.31 GB; the plain
+    path's temporaries at this call are 2.2 GB) stays under 0.6 GB."""
+    with time_limit(60):
+        compiled = lowered_gradient(*kda_call(one_chip)).compile()
+    text = compiled.as_text()
+    assert "kda_scan_forward" in text and "kda_scan_backward" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_delta_rule_kernel_bodies_do_not_grow_with_the_chunks(one_chip):
+    """Rule 1 of the set-up budget: the body that is lowered handles ONE
+    chunk, so a row of 2 chunks and a row of 13 lower Mosaic modules of
+    one size, forward and backward (the grid's extent is a number in the
+    module, not a loop written out)."""
+    sizes = {}
+    for tokens in (128, 832):
+        with time_limit(30):
+            text = lowered_gradient(*kda_call(one_chip, tokens)).as_text()
+        bodies = kernel_bodies(text)
+        assert len(bodies) == 2, len(bodies)        # forward, backward
+        sizes[tokens] = [len(b) for b in bodies]
+    for short, long in zip(sizes[128], sizes[832]):
+        # the grid's extents and the arrays' types are written into the
+        # module: 2 % as this was written; 13 chunks written out are 6x
+        assert abs(short - long) <= 0.05 * long, sizes
+    assert max(sizes[832]) < 40_000, sizes  # 16 and 21 KB of base64 now
+
+
+def test_five_layers_lower_each_delta_rule_kernel_once(one_chip):
+    """Rule 2: the five KDA layers call the same shapes, and each pass is
+    ONE module-level jitted function, so a program that applies the rule
+    five times under ``jax.checkpoint`` and differentiates it holds the
+    forward body twice (the forward pass and its recomputation) and the
+    backward body once, and calls them; site by site it would hold 15."""
+    rule, args = kda_call(one_chip)
+
+    @jax.checkpoint
+    def layer(q, k, v, g, beta):
+        return rule(q, k, v, g, beta).astype(q.dtype)
+
+    def layers(q, k, v, g, beta):
+        for _ in range(5):
+            q = layer(q, k, v, g, beta)
+        return q
+
+    with time_limit(60):
+        text = lowered_gradient(layers, args).as_text()
+    assert len(kernel_bodies(text)) == 3, len(kernel_bodies(text))
+    assert text.count("call @") >= 15       # ... and fifteen calls
+
+
+def test_delta_rule_kernels_lower_to_the_same_text_twice(one_chip):
+    """Rule 4: nothing in the lowered module differs between two
+    lowerings of one program (no counter, address or temporary name in a
+    kernel's name, metadata or serialized body), so a second process finds
+    the first one's executable in the persistent cache."""
+    texts = []
+    for _ in range(2):
+        jax.clear_caches()
+        with time_limit(30):
+            texts.append(lowered_gradient(*kda_call(one_chip)).as_text(
+                debug_info=True))
+    assert texts[0] == texts[1]
+    assert "kda_scan_forward" in texts[0]
 
 
 @pytest.mark.parametrize("path", ["plain", "kernel"])

@@ -295,6 +295,72 @@ def test_mla_against_materialised_scores(monkeypatch, path):
     assert err.max() < 2e-5, err.max()
 
 
+# ---- KDA on both lowerings -------------------------------------------------
+
+# the tiny block with heads the kernels take: 128 channels a head, chunks of
+# one sub-block
+WIDE_HEADS = dataclasses.replace(TINY, num_attention_heads=2, head_dim=128,
+                                 kda_chunk=16)
+
+
+@pytest.mark.parametrize("path", [trunk_lib.kda.PLAIN, trunk_lib.kda.KERNEL])
+def test_kda_layer_against_the_token_by_token_reference(monkeypatch, path):
+    """A KDA layer through the plain chunks and through the kernel pair
+    (interpreted here), 20 tokens in two chunks of 16 with tokens passed
+    over, equals the reference's recurrence, float32 to 1e-4 of the
+    output's scale (the kernel's solve is three bfloat16 passes); the
+    layer's counter says which ran."""
+    monkeypatch.setattr(trunk_lib.kda, "delta_rule_path",
+                        lambda *build: path)
+    layer = trunk_lib.KDA(WIDE_HEADS, jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(5), (3, T, TINY.hidden_size))
+    valid = jnp.ones((3, T), bool).at[:, 6].set(False).at[1, 15:].set(False)
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0), u, valid)
+    params = weights.make_params(shapes, 11)
+    with jax.default_matmul_precision("highest"):
+        got, sown = layer.apply(params, u, valid,
+                                mutable=[trunk_lib.COUNTERS])
+        want = ref.kda(params["params"], u, valid, spec_of(TINY), None)
+    assert float(sown[trunk_lib.COUNTERS]["kda_kernel"][0]) == (
+        path == trunk_lib.kda.KERNEL)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-4 * float(
+        jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("backend,layers", [("cpu", 0.0), ("tpu", 2.0)])
+def test_the_counter_reads_the_path_functions_answer(monkeypatch, backend,
+                                                     layers):
+    """``kda_kernel_layers`` is ``ops.kda.delta_rule_path``'s answer summed
+    over the KDA layers. The trunk asks ``jax.default_backend()`` and the
+    test answers for it: a bfloat16 trunk of three layers, two of them KDA
+    with heads the kernels take, reads 0 on this backend and 2 where the
+    answer is a TPU (no TPU is attached, so the kernels are made to run
+    interpreted whatever the backend is said to be); a float32 build of it
+    reads 0 on either."""
+    from rlgpuschedule_tpu.ops import kda_kernel
+    real = kda_kernel.chunks
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    # the described backend is not attached: interpret whatever it says
+    monkeypatch.setattr(kda_kernel, "chunks",
+                        lambda *a: real(*a[:-1], True))
+    monkeypatch.setattr(trunk_lib, "attention_path",
+                        lambda *build: trunk_lib.PLAIN)
+    cfg = dataclasses.replace(WIDE_HEADS, num_hidden_layers=3,
+                              layer_group_size=3)
+    assert cfg.kda_layers == 2
+    net = trunk_lib.TokenTrunk(cfg, dtype=jnp.bfloat16)
+    obs = observations(jax.random.PRNGKey(3), 2)
+    params = net.init(jax.random.PRNGKey(0), obs[:1])
+    out, sown = net.apply(params, obs, mutable=[trunk_lib.COUNTERS])
+    c = trunk_lib.read_counters(sown[trunk_lib.COUNTERS])
+    assert float(c["kda_kernel_layers"]) == layers
+    assert bool(jnp.all(jnp.isfinite(out)))
+    f32 = trunk_lib.TokenTrunk(cfg, dtype=jnp.float32)
+    _, sown = f32.apply(params, obs, mutable=[trunk_lib.COUNTERS])
+    assert float(trunk_lib.read_counters(sown[trunk_lib.COUNTERS])[
+        "kda_kernel_layers"]) == 0.0
+
+
 # ---- invalid tokens -----------------------------------------------------
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -335,6 +401,7 @@ def test_preset_trains_three_iterations_through_experiment_run():
         # what the configuration fixes is no counter (trunk.describe)
         assert not {"kda_layers", "kda_chunk", "moe_groups_kept"} & set(h)
         assert h["attn_kernel_layers"] == 0.0       # a CPU: the plain path
+        assert h["kda_kernel_layers"] == 0.0        # ... for the rule too
         assert 0 < h["moe_assignments_held"] <= 16 * 18 * 2 * 5
 
 
@@ -486,6 +553,7 @@ def test_a_mesh_build_meets_no_unknown_leaf():
                                [h["total_loss"] for h in plain],
                                rtol=1e-2, atol=1e-3)
     assert all(h["attn_kernel_layers"] == 0.0 for h in meshed)
+    assert all(h["kda_kernel_layers"] == 0.0 for h in meshed)
 
 
 def test_train_cli_says_once_what_the_trunk_fixes(tmp_path):
