@@ -89,45 +89,14 @@ def check(cond: bool, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-class CompileMeter:
-    """Seconds spent tracing/lowering/compiling and persistent-cache
-    hits/misses since the last :meth:`reset`, from jax's own monitoring
-    events (compiles are synchronous, so ``wall - compile`` is run time)."""
-
-    DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
-                 "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                 "/jax/core/compile/backend_compile_duration")
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self):
-        import jax
-        self.reset()
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def reset(self) -> None:
-        self.compile_s = 0.0
-        self.backend_compiles = 0
-        self.hits = 0
-        self.misses = 0
-
-    def _duration(self, event: str, duration: float, **_kw) -> None:
-        if event in self.DURATIONS:
-            self.compile_s += duration
-            self.backend_compiles += event == self.DURATIONS[-1]
-
-    def _event(self, event: str, **_kw) -> None:
-        self.hits += event == self.HIT
-        self.misses += event == self.MISS
-
-
 class Smoke:
     """Runs phases in order, prints one JSON line each, and skips what
     follows a failure (later phases consume earlier ones' output)."""
 
-    def __init__(self, meter: CompileMeter):
-        self.meter = meter
+    def __init__(self):
+        # the program's own compile listener (one for the process's life)
+        from rlgpuschedule_tpu.obs.startup import ACCOUNT
+        self.compiles = ACCOUNT.compiles
         self.failed: list[str] = []
         self.ran: list[str] = []
 
@@ -143,7 +112,7 @@ class Smoke:
         limit = PHASE_LIMIT_S[name]
         timer = threading.Timer(limit, _phase_timeout, (name, limit))
         timer.daemon = True
-        self.meter.reset()
+        before = self.compiles.counts()
         t0 = time.monotonic()
         timer.start()
         try:
@@ -159,12 +128,18 @@ class Smoke:
         finally:
             timer.cancel()
         wall = time.monotonic() - t0
+        # the UNION of the phase's trace, lowering, compile and cache-load
+        # intervals (compiles are synchronous, so wall - compile is run
+        # time; a sum would count an inner jit's trace twice)
+        compile_s = self.compiles.covered(t0, t0 + wall)
+        during = {k: v - before[k]
+                  for k, v in self.compiles.counts().items()}
         rec.update(
-            wall_s=round(wall, 3),
-            compile_s=round(self.meter.compile_s, 3),
-            run_s=round(wall - self.meter.compile_s, 3),
-            backend_compiles=self.meter.backend_compiles,
-            cache_hits=self.meter.hits, cache_misses=self.meter.misses)
+            wall_s=round(wall, 3), compile_s=round(compile_s, 3),
+            run_s=round(wall - compile_s, 3),
+            backend_compiles=during["backend_compiles"],
+            cache_hits=during["cache_hits"],
+            cache_misses=during["cache_misses"])
         self.ran.append(name)
         print(json.dumps(rec), flush=True)
 
@@ -435,7 +410,6 @@ def main(argv: "list[str] | None" = None) -> int:
               f"{device['count']} device(s)", file=sys.stderr)
         return 5
     cache = enable_compile_cache()
-    meter = CompileMeter()
     import jax
 
     root = os.path.dirname(os.path.abspath(__file__))
@@ -460,7 +434,7 @@ def main(argv: "list[str] | None" = None) -> int:
         "native_oracle": native.available(),
         "native_build_error": native.build_error()}), flush=True)
 
-    smoke = Smoke(meter)
+    smoke = Smoke()
     if args.chips == 4:
         run_four_chips(smoke, size, args.seed)
     else:

@@ -19,4 +19,22 @@ driver's capability spec, provenance tag ``[B]`` in SURVEY.md):
 - L6 driver:      named configs, train/evaluate CLIs, metrics, checkpoints.
 """
 
+import time as _time
+
+# the start-up account's origin (``obs.startup``): the first statement any
+# import of the package runs, on the event bus's clock
+T0 = _time.monotonic()
+# (name, start, end) of what a process pays before the account can be
+# imported, stamped by the modules that pay: ``import`` (the bodies that
+# import jax, flax and optax: ``utils``, ``configs``, ``experiment``,
+# whichever an entry point reaches first) and ``backend``
+# (``utils.platform.device_record``: the TPU client's start)
+EARLY_SPANS: list = []
+
+
+def stamp(name: str, since: float) -> None:
+    """What began at ``since`` under ``name`` ends now."""
+    EARLY_SPANS.append((name, since, _time.monotonic()))
+
+
 __version__ = "0.1.0"

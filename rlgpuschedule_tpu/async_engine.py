@@ -101,6 +101,7 @@ from .algos.a2c import make_learn_step as make_a2c_learn_step
 from .algos.ppo import make_learn_step as make_ppo_learn_step
 from .algos.rollout import make_rollout_step
 from .analysis.sentinels import no_implicit_transfers
+from .obs import startup
 from .obs.telemetry import AsyncGauges, OverlapMeter
 from .obs.scopes import TRAIN_ITERATION
 from .obs.trace import tracer_of
@@ -470,6 +471,7 @@ class AsyncRunner:
 
     # -- the learner loop (caller thread) -----------------------------------
 
+    @startup.recorded_run
     def run(self, iterations: int | None = None, log_every: int = 0,
             logger: Callable[[int, dict], None] | None = None,
             ckpt=None, ckpt_every: int = 0,
@@ -488,8 +490,7 @@ class AsyncRunner:
         base = self._iterations_done
         history: list[dict] = []
         eval_history: list[dict] = []
-        sections = (telemetry.sections if telemetry is not None
-                    else SectionTimer())
+        sections = startup.sections_of(telemetry)
         gauges = (AsyncGauges(telemetry.registry)
                   if telemetry is not None else None)
         tracer = tracer_of(telemetry)
@@ -943,6 +944,7 @@ class AsyncPopulationRunner:
 
     # -- the learner loop (caller thread) -----------------------------------
 
+    @startup.recorded_run
     def run(self, iterations: int | None = None, log_every: int = 0,
             logger: Callable[[int, dict], None] | None = None,
             ckpt=None, ckpt_every: int = 0,
@@ -961,8 +963,7 @@ class AsyncPopulationRunner:
         base = self._iterations_done
         history: list[dict] = []
         eval_history: list[dict] = []
-        sections = (telemetry.sections if telemetry is not None
-                    else SectionTimer())
+        sections = startup.sections_of(telemetry)
         gauges = (AsyncGauges(telemetry.registry)
                   if telemetry is not None else None)
         tracer = tracer_of(telemetry)

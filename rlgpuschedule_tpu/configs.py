@@ -7,11 +7,18 @@ exactly (SURVEY.md §5 "Config / flag system").
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Literal
 
+_t_import = time.monotonic()
 from .algos.a2c import A2CConfig
 from .algos.ppo import PPOConfig
 from .models.trunk import TRUNKS
+
+from . import stamp
+
+# where the CLIs pay for jax, optax and flax (obs.startup)
+stamp("import", _t_import)
 
 # the token trunks by name, for every CLI's --trunk (one list: the
 # registry's own)

@@ -10,6 +10,7 @@ import dataclasses
 import time
 from typing import Any, Callable
 
+_t_import = time.monotonic()
 import jax
 import jax.numpy as jnp
 
@@ -29,6 +30,13 @@ from .traces import (ArrayTrace, gen_domain_window, gen_poisson_trace,
                      load_pai, load_philly)
 from .traces.fit import domain_fit
 from flax.training.train_state import TrainState
+
+from . import stamp
+from .obs import startup
+
+# where a library caller pays for jax, flax, optax and every layer's module
+stamp("import", _t_import)
+phase = startup.ACCOUNT.phase    # a set-up phase of the start-up account
 
 
 def build_env_params(cfg: ExperimentConfig) -> EnvParams:
@@ -132,33 +140,44 @@ def build_stack(cfg: ExperimentConfig):
             n_pods=cfg.n_pods, pod_sim=pod_sim, time_scale=cfg.time_scale,
             reward_scale=cfg.reward_scale, place_bonus=cfg.place_bonus,
             horizon=cfg.horizon)
-        source = validate_trace(pod_sim, load_source_trace(cfg), clamp=True)
-        windows = make_env_windows(cfg, source)
-        traces = stack_traces(windows, pod_sim)
-        net = HierActorCritic(n_top_actions=env_params.n_top_actions,
-                              n_pod_actions=pod_sim.n_actions)
+        with phase("build_source"):
+            source = validate_trace(pod_sim, load_source_trace(cfg),
+                                    clamp=True)
+        with phase("build_windows"):
+            windows = make_env_windows(cfg, source)
+        with phase("build_upload"):
+            traces = stack_traces(windows, pod_sim)
+        with phase("build_policy"):
+            net = HierActorCritic(n_top_actions=env_params.n_top_actions,
+                                  n_pod_actions=pod_sim.n_actions)
         apply_fn = lambda p, obs, mask: net.apply(p, obs, mask)
         return env_params, windows, traces, net, apply_fn, (), source
 
     env_params = build_env_params(cfg)
-    source = validate_trace(env_params.sim, load_source_trace(cfg),
-                            clamp=True)
-    if env_params.domain_process is not None:
-        # domain windows are GENERATED per env from the config's fitted
-        # job mix under each env's seeded domain draw (arrival knobs +
-        # that draw's actual capacity), not cut from the source — the
-        # source stays loaded so --full-trace/window accounting on the
-        # same config keep working
-        draws = sample_env_domains(env_params.domain_process, cfg.n_nodes,
-                                   cfg.gpus_per_node, cfg.seed, cfg.n_envs)
-        windows = make_domain_windows(cfg, draws)
-    else:
-        windows = make_env_windows(cfg, source)
-    traces = stack_traces(windows, env_params)
-    net = make_policy(cfg.obs_kind, env_params.n_actions,
-                      n_cluster_nodes=cfg.n_nodes, queue_len=cfg.queue_len,
-                      n_placements=cfg.n_placements,
-                      preempt_len=cfg.preempt_len, trunk=cfg.trunk)
+    with phase("build_source"):
+        source = validate_trace(env_params.sim, load_source_trace(cfg),
+                                clamp=True)
+    with phase("build_windows"):
+        if env_params.domain_process is not None:
+            # domain windows are GENERATED per env from the config's
+            # fitted job mix under each env's seeded domain draw (arrival
+            # knobs + that draw's actual capacity), not cut from the
+            # source — the source stays loaded so --full-trace/window
+            # accounting on the same config keep working
+            draws = sample_env_domains(
+                env_params.domain_process, cfg.n_nodes, cfg.gpus_per_node,
+                cfg.seed, cfg.n_envs)
+            windows = make_domain_windows(cfg, draws)
+        else:
+            windows = make_env_windows(cfg, source)
+    with phase("build_upload"):
+        traces = stack_traces(windows, env_params)
+    with phase("build_policy"):
+        net = make_policy(cfg.obs_kind, env_params.n_actions,
+                          n_cluster_nodes=cfg.n_nodes,
+                          queue_len=cfg.queue_len,
+                          n_placements=cfg.n_placements,
+                          preempt_len=cfg.preempt_len, trunk=cfg.trunk)
     if cfg.obs_kind == "graph":
         adj = jnp.asarray(build_adjacency(cfg.n_nodes, cfg.queue_len,
                                           cfg.nodes_per_rack,
@@ -289,38 +308,46 @@ class Experiment:
     mesh: Any = None
 
     @staticmethod
+    @startup.recorded_build
     def build(cfg: ExperimentConfig, axis_name: str | None = None,
-              jit: bool = True, mesh=None) -> "Experiment":
+              jit: bool = True, mesh=None, *,
+              telemetry=None) -> "Experiment":
+        """Assemble the experiment. Its phases (``build_source`` ...
+        ``build_step``, ``obs.startup``) go to the start-up account and,
+        as ``rlsched:<phase>``, to a profile that is running; with
+        ``telemetry`` (:class:`obs.RunTelemetry`, as :meth:`run` takes it)
+        they are spans on its bus too."""
         env_params, windows, traces, net, apply_fn, extra, source = \
             build_stack(cfg)
         faults = None
         domains = None
         fp = getattr(env_params, "fault_process", None)
-        if getattr(env_params, "domain_process", None) is not None:
-            # the SAME seeded draws build_stack generated windows from
-            # (host sampling is deterministic in (seed, env)); the
-            # device data is one batched DomainSchedule riding the
-            # faults slot, composing any cfg.faults draw per env
-            domains = sample_env_domains(
-                env_params.domain_process, cfg.n_nodes, cfg.gpus_per_node,
-                cfg.seed, cfg.n_envs)
-            horizon_s = fault_horizon(windows)
-            schedules = []
-            for e, d in enumerate(domains):
-                f = (sample_fault_schedule(cfg.n_nodes, fp, (cfg.seed, e),
-                                           horizon_s)
-                     if fp is not None else None)
-                schedules.append(validate_domain_schedule(
-                    cfg.n_nodes, cfg.gpus_per_node, domain_schedule(d, f)))
-            faults = stack_domain_schedules(schedules)
-        elif fp is not None:
-            # seeded per-env draws over the window batch's time span, so
-            # drain windows intersect live episodes at every trace scale
-            faults = sample_env_fault_schedules(
-                cfg.n_nodes, fp, cfg.seed, cfg.n_envs,
-                fault_horizon(windows))
-        key = jax.random.PRNGKey(cfg.seed)
-        key, init_key, carry_key = jax.random.split(key, 3)
+        with phase("build_upload"):    # the fault and domain schedules
+            if getattr(env_params, "domain_process", None) is not None:
+                # the SAME seeded draws build_stack generated windows from
+                # (host sampling is deterministic in (seed, env)); the
+                # device data is one batched DomainSchedule riding the
+                # faults slot, composing any cfg.faults draw per env
+                domains = sample_env_domains(
+                    env_params.domain_process, cfg.n_nodes,
+                    cfg.gpus_per_node, cfg.seed, cfg.n_envs)
+                horizon_s = fault_horizon(windows)
+                schedules = []
+                for e, d in enumerate(domains):
+                    f = (sample_fault_schedule(cfg.n_nodes, fp,
+                                               (cfg.seed, e), horizon_s)
+                         if fp is not None else None)
+                    schedules.append(validate_domain_schedule(
+                        cfg.n_nodes, cfg.gpus_per_node,
+                        domain_schedule(d, f)))
+                faults = stack_domain_schedules(schedules)
+            elif fp is not None:
+                # seeded per-env draws over the window batch's time span,
+                # so drain windows intersect live episodes at every trace
+                # scale
+                faults = sample_env_fault_schedules(
+                    cfg.n_nodes, fp, cfg.seed, cfg.n_envs,
+                    fault_horizon(windows))
         algo_cfg = cfg.ppo if cfg.algo == "ppo" else cfg.a2c
         # fail fast on a geometry that cannot tile the rollout batch —
         # inside the jitted step the same check would surface as an
@@ -335,64 +362,71 @@ class Experiment:
             from .algos.a2c import make_optimizer as a2c_opt
             tx = a2c_opt(algo_cfg)
             step_fn = make_a2c_step(apply_fn, env_params, algo_cfg, axis_name)
-        carry = init_carry(env_params, traces, carry_key, faults)
-        ex_obs, ex_mask = jax.tree.map(lambda x: x[:1],
-                                       (carry.obs, carry.mask))
-        train_state = make_train_state(net, init_key, ex_obs, ex_mask, tx,
-                                       extra,
-                                       reward_norm=algo_cfg.reward_norm)
-        if jit:
-            if axis_name is not None:
-                # pmean(axis_name) is unbound under plain jit — the
-                # explicit-collective assembly lives in
-                # parallel.dp.shard_map_train: build with jit=False and
-                # hand the returned step to it (module docstring there)
-                raise ValueError(
-                    "axis_name requires jit=False: hand the returned "
-                    "train_step to parallel.dp.shard_map_train, which "
-                    "wraps it in shard_map over the mesh axis")
-            if mesh is not None:
-                # rule-sharded single program: params/opt-state laid out
-                # by the model family's partition-rule table, env batch
-                # over data, and the step traced with the mesh bound so
-                # rollout's with_sharding_constraint pins the trajectory
-                from .parallel import sharding as shardlib
-                from .parallel.dp import carry_sharding_prefix
-                from .parallel.mesh import (DATA_AXIS, env_sharded,
-                                            replicated)
-                if cfg.n_envs % mesh.shape[DATA_AXIS]:
+        with phase("build_carry"):     # the keys' eager programs too
+            key = jax.random.PRNGKey(cfg.seed)
+            key, init_key, carry_key = jax.random.split(key, 3)
+            carry = init_carry(env_params, traces, carry_key, faults)
+        with phase("build_train_state"):
+            ex_obs, ex_mask = jax.tree.map(lambda x: x[:1],
+                                           (carry.obs, carry.mask))
+            train_state = make_train_state(
+                net, init_key, ex_obs, ex_mask, tx, extra,
+                reward_norm=algo_cfg.reward_norm)
+        # the step jitted and, under a mesh, everything it takes placed
+        with phase("build_step"):
+            if jit:
+                if axis_name is not None:
+                    # pmean(axis_name) is unbound under plain jit — the
+                    # explicit-collective assembly lives in
+                    # parallel.dp.shard_map_train: build with jit=False and
+                    # hand the returned step to it (module docstring there)
                     raise ValueError(
-                        f"n_envs={cfg.n_envs} not divisible by the mesh's "
-                        f"data axis size {mesh.shape[DATA_AXIS]}")
-                rules = shardlib.rules_for(cfg)
-                state_sh = shardlib.tree_shardings(train_state, rules, mesh)
-                env = env_sharded(mesh)
-                rep = replicated(mesh)
-                carry_sh = carry_sharding_prefix(mesh)
-                jit_step = jax.jit(
-                    shardlib.bind_mesh(step_fn, mesh),
-                    in_shardings=(state_sh, carry_sh, env, rep, env),
-                    out_shardings=(state_sh, carry_sh, rep),
-                    donate_argnums=(0, 1))
-                train_state = shardlib.put_tree(train_state, state_sh)
-                carry = RolloutCarry(
-                    env_state=shardlib.put_global(carry.env_state, env),
-                    obs=shardlib.put_global(carry.obs, env),
-                    mask=shardlib.put_global(carry.mask, env),
-                    key=shardlib.put_global(carry.key, rep))
-                traces = shardlib.put_global(traces, env)
-                if faults is not None:
-                    faults = shardlib.put_global(faults, env)
+                        "axis_name requires jit=False: hand the returned "
+                        "train_step to parallel.dp.shard_map_train, which "
+                        "wraps it in shard_map over the mesh axis")
+                if mesh is not None:
+                    # rule-sharded single program: params/opt-state laid out
+                    # by the model family's partition-rule table, env batch
+                    # over data, and the step traced with the mesh bound so
+                    # rollout's with_sharding_constraint pins the trajectory
+                    from .parallel import sharding as shardlib
+                    from .parallel.dp import carry_sharding_prefix
+                    from .parallel.mesh import (DATA_AXIS, env_sharded,
+                                                replicated)
+                    if cfg.n_envs % mesh.shape[DATA_AXIS]:
+                        raise ValueError(
+                            f"n_envs={cfg.n_envs} not divisible by the "
+                            f"mesh's data axis size {mesh.shape[DATA_AXIS]}")
+                    rules = shardlib.rules_for(cfg)
+                    state_sh = shardlib.tree_shardings(train_state, rules,
+                                                       mesh)
+                    env = env_sharded(mesh)
+                    rep = replicated(mesh)
+                    carry_sh = carry_sharding_prefix(mesh)
+                    jit_step = jax.jit(
+                        shardlib.bind_mesh(step_fn, mesh),
+                        in_shardings=(state_sh, carry_sh, env, rep, env),
+                        out_shardings=(state_sh, carry_sh, rep),
+                        donate_argnums=(0, 1))
+                    train_state = shardlib.put_tree(train_state, state_sh)
+                    carry = RolloutCarry(
+                        env_state=shardlib.put_global(carry.env_state, env),
+                        obs=shardlib.put_global(carry.obs, env),
+                        mask=shardlib.put_global(carry.mask, env),
+                        key=shardlib.put_global(carry.key, rep))
+                    traces = shardlib.put_global(traces, env)
+                    if faults is not None:
+                        faults = shardlib.put_global(faults, env)
+                else:
+                    # state and carry are replaced every iteration in run(),
+                    # so donating them halves live copies in the benchmarked
+                    # hot loop
+                    jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
             else:
-                # state and carry are replaced every iteration in run(),
-                # so donating them halves live copies in the benchmarked
-                # hot loop
-                jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
-        else:
-            if mesh is not None:
-                raise ValueError("mesh requires jit=True (the rule-table "
-                                 "shardings are jit in/out_shardings)")
-            jit_step = step_fn
+                if mesh is not None:
+                    raise ValueError("mesh requires jit=True (the rule-table "
+                                     "shardings are jit in/out_shardings)")
+                jit_step = step_fn
         return Experiment(cfg=cfg, env_params=env_params, windows=windows,
                           traces=traces, net=net, apply_fn=apply_fn,
                           train_state=train_state, train_step=jit_step,
@@ -566,6 +600,7 @@ class Experiment:
         trajectory that just diverged)."""
         self.key = jax.random.fold_in(self.key, n)
 
+    @startup.recorded_run
     def run(self, iterations: int | None = None, log_every: int = 0,
             logger: Callable[[int, dict], None] | None = None,
             ckpt=None, ckpt_every: int = 0,
@@ -639,14 +674,13 @@ class Experiment:
         eval_history = []
         t0 = time.monotonic()
         stride = fused_chunk if fused_chunk > 1 else 1
-        # telemetry spans: with no telemetry attached, a throwaway timer
-        # keeps the section sites branch-free (its cost is two
-        # perf_counter reads per section — noise next to a dispatch)
+        # the sections' sink: the telemetry's timer, else this call's
+        # ``run`` record in the start-up account, so the section sites
+        # stay branch-free (two perf_counter reads a section: noise next
+        # to a dispatch) and the totals are kept either way
         from .obs.scopes import TRAIN_ITERATION
         from .obs.trace import tracer_of
-        from .utils.profiling import SectionTimer
-        sections = (telemetry.sections if telemetry is not None
-                    else SectionTimer())
+        sections = startup.sections_of(telemetry)
         tracer = tracer_of(telemetry)
         if telemetry is not None:
             telemetry.run_start(
@@ -830,8 +864,12 @@ class PopulationExperiment:
     faults: Any = None
 
     @staticmethod
+    @startup.recorded_build
     def build(cfg: ExperimentConfig, n_pop: int = 4, mesh=None,
-              pbt_cfg=None) -> "PopulationExperiment":
+              pbt_cfg=None, *, telemetry=None) -> "PopulationExperiment":
+        """Assemble the population; phases and ``telemetry`` as
+        :meth:`Experiment.build` has them (a member's carry and state are
+        a ``build_carry`` and a ``build_train_state`` each)."""
         from .parallel.pbt import PBTConfig, PBTController
         from .parallel.population import (init_member, jit_population_step,
                                           make_population_step,
@@ -869,75 +907,81 @@ class PopulationExperiment:
         if fp is not None:
             from .sim.faults import stack_fault_schedules
             horizon_s = fault_horizon(windows)
-            member_faults = [
-                stack_fault_schedules(
-                    [sample_fault_schedule(cfg.n_nodes, fp,
-                                           (cfg.seed, p, e), horizon_s)
-                     for e in range(cfg.n_envs)])
-                for p in range(n_pop)]
+            with phase("build_upload"):
+                member_faults = [
+                    stack_fault_schedules(
+                        [sample_fault_schedule(cfg.n_nodes, fp,
+                                               (cfg.seed, p, e), horizon_s)
+                         for e in range(cfg.n_envs)])
+                    for p in range(n_pop)]
 
-        key = jax.random.PRNGKey(cfg.seed)
-        member_keys = jax.random.split(key, n_pop * 3).reshape(n_pop, 3, 2)
+        with phase("build_carry"):     # the keys' eager programs
+            key = jax.random.PRNGKey(cfg.seed)
+            member_keys = jax.random.split(key, n_pop * 3).reshape(
+                n_pop, 3, 2)
         members, carries = [], []
         for p in range(n_pop):
-            carry = init_carry(env_params, traces, member_keys[p, 1],
-                               member_faults[p] if member_faults is not None
-                               else None)
-            ex_obs, ex_mask = jax.tree.map(lambda x: x[:1],
-                                           (carry.obs, carry.mask))
-            members.append(init_member(net, member_keys[p, 0], ex_obs,
-                                       ex_mask, cfg.ppo, extra))
+            with phase("build_carry"):
+                carry = init_carry(
+                    env_params, traces, member_keys[p, 1],
+                    member_faults[p] if member_faults is not None else None)
+            with phase("build_train_state"):
+                ex_obs, ex_mask = jax.tree.map(lambda x: x[:1],
+                                               (carry.obs, carry.mask))
+                members.append(init_member(net, member_keys[p, 0], ex_obs,
+                                           ex_mask, cfg.ppo, extra))
             carries.append(carry)
-        states = stack_members(members)
-        stacked_carries = stack_members(carries)
-        hparams = sample_hparams(cfg.ppo, n_pop, cfg.seed)
-        keys = member_keys[:, 2]
-        faults = (stack_members(member_faults)
-                  if member_faults is not None else None)
-
-        pop_step = make_population_step(apply_fn, env_params, cfg.ppo,
-                                        with_faults=faults is not None)
-        if mesh is not None:
-            if n_pop % mesh.shape["pop"] != 0:
-                raise ValueError(f"n_pop={n_pop} not divisible by pop axis "
-                                 f"size {mesh.shape['pop']}")
-            if cfg.n_envs % mesh.shape["data"] != 0:
-                raise ValueError(f"n_envs={cfg.n_envs} not divisible by "
-                                 f"data axis size {mesh.shape['data']}")
-            # member-state layout resolved per-leaf from the same
-            # partition-rule table the single-run path uses: pop axis on
-            # the member stack, model axis on kernels within each member
-            from .parallel import sharding as shardlib
-            from .parallel.population import population_shardings
-            rules = shardlib.rules_for(cfg)
-            jitted = jit_population_step(mesh, pop_step, states=states,
-                                         rules=rules,
-                                         with_faults=faults is not None)
-            st_sh, ca_sh, tr_sh, key_sh, hp_sh = population_shardings(
-                mesh, states=states, rules=rules)
-            states = jax.device_put(states, st_sh)
-            stacked_carries = jax.device_put(stacked_carries, ca_sh)
-            traces = jax.device_put(traces, tr_sh)
-            keys = jax.device_put(keys, key_sh)
-            hparams = jax.device_put(hparams, hp_sh)
-            if faults is not None:
-                from .parallel.mesh import pop_env_sharded
-                faults = jax.device_put(faults, pop_env_sharded(mesh))
+        with phase("build_train_state"):
+            states = stack_members(members)
+            stacked_carries = stack_members(carries)
+            hparams = sample_hparams(cfg.ppo, n_pop, cfg.seed)
+            keys = member_keys[:, 2]
+            faults = (stack_members(member_faults)
+                      if member_faults is not None else None)
+        with phase("build_step"):
+            pop_step = make_population_step(apply_fn, env_params, cfg.ppo,
+                                            with_faults=faults is not None)
+            if mesh is not None:
+                if n_pop % mesh.shape["pop"] != 0:
+                    raise ValueError(f"n_pop={n_pop} not divisible by pop "
+                                     f"axis size {mesh.shape['pop']}")
+                if cfg.n_envs % mesh.shape["data"] != 0:
+                    raise ValueError(f"n_envs={cfg.n_envs} not divisible by "
+                                     f"data axis size {mesh.shape['data']}")
+                # member-state layout resolved per-leaf from the same
+                # partition-rule table the single-run path uses: pop axis on
+                # the member stack, model axis on kernels within each member
+                from .parallel import sharding as shardlib
+                from .parallel.population import population_shardings
+                rules = shardlib.rules_for(cfg)
+                jitted = jit_population_step(mesh, pop_step, states=states,
+                                             rules=rules,
+                                             with_faults=faults is not None)
+                st_sh, ca_sh, tr_sh, key_sh, hp_sh = population_shardings(
+                    mesh, states=states, rules=rules)
+                states = jax.device_put(states, st_sh)
+                stacked_carries = jax.device_put(stacked_carries, ca_sh)
+                traces = jax.device_put(traces, tr_sh)
+                keys = jax.device_put(keys, key_sh)
+                hparams = jax.device_put(hparams, hp_sh)
+                if faults is not None:
+                    from .parallel.mesh import pop_env_sharded
+                    faults = jax.device_put(faults, pop_env_sharded(mesh))
+                return PopulationExperiment(
+                    cfg=cfg, n_pop=n_pop, env_params=env_params,
+                    traces=traces, apply_fn=apply_fn, states=states,
+                    carries=stacked_carries, hparams=hparams, keys=keys,
+                    pop_step=jitted,
+                    controller=PBTController(n_pop, pbt_cfg),
+                    windows=windows, mesh=mesh, state_sharding=st_sh,
+                    hparam_sharding=hp_sh, faults=faults)
+            jitted = jax.jit(pop_step, donate_argnums=(0, 1))
             return PopulationExperiment(
-                cfg=cfg, n_pop=n_pop, env_params=env_params,
-                traces=traces, apply_fn=apply_fn, states=states,
-                carries=stacked_carries, hparams=hparams, keys=keys,
-                pop_step=jitted,
-                controller=PBTController(n_pop, pbt_cfg),
-                windows=windows, mesh=mesh, state_sharding=st_sh,
-                hparam_sharding=hp_sh, faults=faults)
-        jitted = jax.jit(pop_step, donate_argnums=(0, 1))
-        return PopulationExperiment(
-            cfg=cfg, n_pop=n_pop, env_params=env_params, traces=traces,
-            apply_fn=apply_fn, states=states, carries=stacked_carries,
-            hparams=hparams, keys=keys, pop_step=jitted,
-            controller=PBTController(n_pop, pbt_cfg), windows=windows,
-            faults=faults)
+                cfg=cfg, n_pop=n_pop, env_params=env_params, traces=traces,
+                apply_fn=apply_fn, states=states, carries=stacked_carries,
+                hparams=hparams, keys=keys, pop_step=jitted,
+                controller=PBTController(n_pop, pbt_cfg), windows=windows,
+                faults=faults)
 
     @property
     def steps_per_iteration(self) -> int:
@@ -1045,6 +1089,7 @@ class PopulationExperiment:
         (watchdog retry — same contract as :meth:`Experiment.fold_key`)."""
         self.keys = jax.vmap(lambda k: jax.random.fold_in(k, n))(self.keys)
 
+    @startup.recorded_run
     def run(self, iterations: int | None = None, log_every: int = 0,
             logger: Callable[[int, dict], None] | None = None,
             ckpt=None, ckpt_every: int = 0,
@@ -1082,9 +1127,7 @@ class PopulationExperiment:
         t0 = time.monotonic()
         from .obs.scopes import TRAIN_ITERATION
         from .obs.trace import tracer_of
-        from .utils.profiling import SectionTimer
-        sections = (telemetry.sections if telemetry is not None
-                    else SectionTimer())
+        sections = startup.sections_of(telemetry)
         tracer = tracer_of(telemetry)
         if telemetry is not None:
             telemetry.run_start(

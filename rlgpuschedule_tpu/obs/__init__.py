@@ -27,10 +27,19 @@ to *when and on which rank*.
   and :class:`Alarms` (``CompileCounter`` + transfer-guard promoted
   from test-only sentinels to production: ``recompile``/``transfer``
   events, optional slow-iteration ``jax.profiler`` auto-capture).
+- :mod:`.startup` — the start-up account (:data:`.startup.ACCOUNT`):
+  always on and in memory, where the process's time went before and
+  while its first iterations ran — ``import`` / ``backend`` / ``build``
+  (by phase) / ``run`` spans and every program's trace, lowering,
+  compile and cache-load intervals from the ONE ``CompileCounter`` it
+  installs for the process's life; :meth:`~.startup.StartupAccount.summary`
+  reduces it to exclusive seconds. ``train.py``'s closing summary and
+  each ``run_start`` event carry it (``startup``), the report prints it,
+  and the benchmark's ``setup_*_s.train`` metrics read it.
 - :mod:`.report` — ``python -m rlgpuschedule_tpu.obs.report <dir>``:
-  merged timeline post-mortem (phase-time table, span tree, restart/
-  rollback history, steps/s curve, alarm summary; ``--strict-alarms``
-  for CI, ``--trace-out`` for the Perfetto export).
+  merged timeline post-mortem (start-up table, phase-time table, span
+  tree, restart/rollback history, steps/s curve, alarm summary;
+  ``--strict-alarms`` for CI, ``--trace-out`` for the Perfetto export).
 - :mod:`.trace` — the span-tracing flight recorder: nestable,
   thread-aware :meth:`Tracer.span` extents on the same bus (track =
   ``(rank, thread)``), plus :func:`to_chrome_trace` so any run opens in
@@ -42,12 +51,15 @@ to *when and on which rank*.
 
 Event kinds by emitter:
 
-== run loops (``experiment.py``): ``run_start``, ``iteration``,
+== run loops (``experiment.py``): ``run_start`` (its ``startup`` field:
+   the start-up account's summary as the run begins), ``iteration``,
    ``run_end``, ``pbt_exploit``
 == tracer (any layer, ``--trace``): ``span_begin``, ``span_end``,
-   ``span_point``
-== alarms: ``compile`` (warmup/expected), ``recompile``, ``transfer``,
-   ``slow_iteration``, ``profile_captured``
+   ``span_point`` (the run loops' phases, and ``build`` with its
+   ``build_*`` phases where ``build`` was handed the telemetry)
+== alarms: ``compile`` (warmup/expected), ``recompile`` (both with
+   ``programs``: ``[{fun, trace_s, lower_s, compile_s, cache_hit}]`` of
+   the dispatch), ``transfer``, ``slow_iteration``, ``profile_captured``
 == checkpoint: ``ckpt_save``, ``ckpt_restore``, ``ckpt_reject``,
    ``ckpt_crc_reject``, ``ckpt_elastic_restore``
 == resilience: ``rollback`` (watchdog), ``fault`` (injector)

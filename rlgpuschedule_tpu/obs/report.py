@@ -5,6 +5,11 @@ per-rank event stream under ``<obs-dir>`` into one monotonic-ordered
 timeline and prints the run's post-mortem:
 
 - header: schema versions, emitting ranks, event count, time span;
+- start-up table (``obs.startup``, from each ``run_start`` event's
+  ``startup`` field): where the process's time went before its run
+  began — imports, the backend's start, ``build`` by phase, tracing and
+  lowering, compiling, cache loads, earlier run calls by loop section —
+  and the programs that cost most;
 - phase-time table (host wall seconds per run-loop phase, from the
   ``iteration`` spans);
 - span tree (``--trace`` runs): per-phase self/child time from the
@@ -95,6 +100,9 @@ def build_report(events: list[dict]) -> dict:
                       "steps_per_sec": e.get("steps_per_sec"),
                       "wall_s": e.get("wall_s")})
 
+    startups = [{"rank": e.get("rank", 0), **e["startup"]}
+                for e in events
+                if e.get("kind") == "run_start" and e.get("startup")]
     history = [e for e in events if e.get("kind") in _HISTORY_KINDS]
     fleet = [e for e in events if e.get("kind") in _FLEET_KINDS]
     restores = [e for e in events if e.get("kind") == "ckpt_restore"]
@@ -115,6 +123,7 @@ def build_report(events: list[dict]) -> dict:
     span_tree = build_span_tree(events) if has_spans else []
     return {"schema_versions": versions, "ranks": ranks,
             "n_events": len(events), "span_s": span_s, "t0_mono": t0,
+            "startup": startups,
             "phase_seconds": phases, "steps_curve": curve,
             "history": history, "fleet": fleet,
             "ckpt_restores": restores,
@@ -256,6 +265,46 @@ def _fmt_history_line(e: dict, t0: float) -> str:
     return f"  +{t:9.3f}s  rank {rank:>3}  {e.get('kind'):<22s} {body}"
 
 
+# the start-up summary's exclusive parts, in the order a process pays them
+_STARTUP_PARTS = (("import_s", "import"), ("backend_s", "backend"),
+                  ("build_s", "build"), ("trace_lower_s", "trace + lower"),
+                  ("compile_s", "compile"), ("cache_load_s", "cache load"),
+                  ("run_s", "run"), ("unnamed_s", "(unnamed)"))
+
+
+def _format_startup(st: dict) -> list[str]:
+    """One ``run_start`` event's start-up account as a table."""
+    total = st.get("until_s") or 1.0
+    c = st.get("counts", {})
+    lines = [f"start-up table (rank {st.get('rank', 0)}: process start to "
+             f"run start, {st.get('until_s', 0.0):.3f}s; exclusive seconds; "
+             f"{c.get('programs', 0)} programs, {c.get('traces', 0)} traces, "
+             f"{c.get('backend_compiles', 0)} backend compiles, "
+             f"{c.get('cache_hits', 0)} cache hits, "
+             f"{c.get('cache_misses', 0)} misses):",
+             f"  {'part':<22s} {'seconds':>10s} {'share':>7s}"]
+    for key, label in _STARTUP_PARTS:
+        secs = st.get(key, 0.0)
+        lines.append(f"  {label:<22s} {secs:>10.3f} "
+                     f"{100.0 * secs / total:>6.1f}%")
+        # under build its phases (exclusive too); under run the loops'
+        # own sections (wall seconds, a first step's compiles included)
+        inner = {"build_s": "build_by_phase", "run_s": "run_sections"}
+        for name, secs in sorted((st.get(inner.get(key)) or {}).items(),
+                                 key=lambda kv: -kv[1]):
+            lines.append(f"    {name:<20s} {secs:>10.3f}")
+    if st.get("programs"):
+        lines.append(f"  {'program':<22s} {'trace s':>9s} {'lower s':>9s} "
+                     f"{'compile s':>9s} {'load s':>9s}")
+        for p in st["programs"]:
+            lines.append(
+                f"  {p['fun'][:22]:<22s} {p['trace_s']:>9.3f} "
+                f"{p['lower_s']:>9.3f} {p['compile_s']:>9.3f} "
+                f"{p['cache_load_s']:>9.3f}")
+    lines.append("")
+    return lines
+
+
 def format_report(rep: dict) -> str:
     """The human post-mortem. Sections keyed to build_report's dict."""
     lines = [
@@ -264,6 +313,8 @@ def format_report(rep: dict) -> str:
         f"schema v{rep['schema_versions']}, span {rep['span_s']:.3f}s",
         "",
     ]
+    for st in rep.get("startup") or ():
+        lines.extend(_format_startup(st))
     if rep["phase_seconds"]:
         total = sum(rep["phase_seconds"].values()) or 1.0
         lines.append("phase-time table (host wall, from iteration spans):")

@@ -14,12 +14,15 @@ host↔device syncs as a bare one (asserted in tests/test_obs.py).
 
 :class:`Alarms` promotes PR 3's test-only sentinels to production:
 
-- **recompile** — a ``CompileCounter`` (jax.monitoring listeners) spans
-  the run; any trace/compile activity observed during a post-warmup
+- **recompile** — the process's ``CompileCounter`` (jax.monitoring
+  listeners; the start-up account's, ``obs.startup``) is read around each
+  dispatch; any trace/compile activity observed during a post-warmup
   dispatch emits a ``recompile`` event and bumps a counter instead of
   only failing a sanitize test. Legitimate re-traces (warmup, the
   watchdog's LR-rescale rollback) are granted amnesty via
-  :meth:`Alarms.expect_recompile` and land as ``compile`` events.
+  :meth:`Alarms.expect_recompile` and land as ``compile`` events. Both
+  kinds name the programs the dispatch traced, lowered, compiled or
+  loaded, with each one's seconds (``programs``).
 - **transfer** — post-warmup dispatches run under
   ``jax.transfer_guard("disallow")``: an implicit host↔device transfer
   in the hot path emits a ``transfer`` event and raises
@@ -38,8 +41,9 @@ import threading
 import time
 from typing import Any, Callable, Iterator, Mapping
 
-from ..analysis.sentinels import CompileCounter, no_implicit_transfers
+from ..analysis.sentinels import no_implicit_transfers
 from ..utils.profiling import SectionTimer
+from . import startup
 from .events import EventBus
 from .metrics import Registry
 from .trace import Tracer
@@ -58,11 +62,19 @@ class Alarms:
 
     ``warmup_iters`` dispatches are exempt (the first iteration MUST
     compile); compile activity inside them is still recorded, as
-    ``compile`` events, so the post-mortem shows where compile time
-    went. ``expect_recompile(reason)`` grants the next dispatch the same
-    amnesty — the run loop calls it after a watchdog rollback, whose LR
-    rescale legitimately re-traces the step.
+    ``compile`` events, and every ``compile`` / ``recompile`` event
+    carries ``programs``: ``[{fun, trace_s, lower_s, compile_s,
+    cache_hit}]`` for the dispatch it belongs to (``compile_s`` is the
+    backend's interval, a load where ``cache_hit``), heaviest first, so
+    the post-mortem shows where compile time went and WHICH step
+    recompiled. ``expect_recompile(reason)`` grants the next dispatch the
+    same amnesty — the run loop calls it after a watchdog rollback, whose
+    LR rescale legitimately re-traces the step.
     """
+
+    # a dispatch's event names at most this many programs (a train step
+    # traces hundreds of inner functions; its own events close LAST)
+    MAX_PROGRAMS = 16
 
     def __init__(self, bus: EventBus, registry: Registry | None = None,
                  warmup_iters: int = 1, transfer_guard: bool = True,
@@ -77,7 +89,7 @@ class Alarms:
         self.transfer_guard = transfer_guard
         self.slow_iter_s = slow_iter_s
         self.profile_dir = profile_dir
-        self._counter: CompileCounter | None = None
+        self._counter = None     # the compile listener, inside the scope
         self._dispatches = 0
         self._amnesty: str | None = None
         self._profile_pending = False
@@ -94,14 +106,14 @@ class Alarms:
             "iterations slower than the slow_iter_s threshold")
 
     def __enter__(self) -> "Alarms":
-        self._counter = CompileCounter().__enter__()
+        # the process's one listener (the start-up account's), read by a
+        # mark before and after each dispatch
+        self._counter = startup.ACCOUNT.compiles
         return self
 
     def __exit__(self, *exc) -> None:
         self.stop_profile()
-        if self._counter is not None:
-            self._counter.__exit__(*exc)
-            self._counter = None
+        self._counter = None
 
     def expect_recompile(self, reason: str) -> None:
         """Grant the NEXT dispatch compile amnesty (e.g. a rollback's LR
@@ -119,6 +131,7 @@ class Alarms:
         amnesty, self._amnesty = self._amnesty, None
         self._dispatches += 1
         t0 = self._counter.total
+        mark = self._counter.n_events
         guard = (no_implicit_transfers()
                  if self.transfer_guard and not warm and amnesty is None
                  else contextlib.nullcontext())
@@ -138,13 +151,26 @@ class Alarms:
         compiles = self._counter.total - t0
         if compiles <= 0:
             return
+        programs = self._programs_since(mark)
         if warm or amnesty is not None:
             self.bus.emit("compile", iteration=iteration, events=compiles,
-                          warmup=warm, expected=amnesty)
+                          warmup=warm, expected=amnesty, programs=programs)
         else:
             self._recompiles.inc()
             self.bus.emit("recompile", iteration=iteration,
-                          events=compiles)
+                          events=compiles, programs=programs)
+
+    def _programs_since(self, mark: int) -> list[dict]:
+        """What the counter's events from ``mark`` on were spent on, by
+        program, most seconds first."""
+        rows = [{"fun": fun, "trace_s": round(r["trace_s"], 6),
+                 "lower_s": round(r["lower_s"], 6),
+                 "compile_s": round(r["compile_s"] + r["cache_load_s"], 6),
+                 "cache_hit": bool(r["cache_loads"])}
+                for fun, r in self._counter.programs_since(mark).items()]
+        rows.sort(key=lambda r: -(r["trace_s"] + r["lower_s"]
+                                  + r["compile_s"]))
+        return rows[:self.MAX_PROGRAMS]
 
     def observe_wall(self, iteration: int, wall_s: float) -> None:
         """Slow-iteration trigger: emit the alarm and arm a one-shot
@@ -244,7 +270,10 @@ class RunTelemetry:
         self.bus.emit(kind, **fields)
 
     def run_start(self, **info: Any) -> None:
-        self.bus.emit("run_start", **info)
+        """``startup``: the start-up account's summary as the run begins
+        (import, backend, build, compile-side seconds so far)."""
+        self.bus.emit("run_start", startup=startup.rounded(
+            startup.ACCOUNT.summary()), **info)
 
     def run_end(self, **info: Any) -> None:
         self.bus.emit("run_end", phase_seconds=self._rounded_sections(),

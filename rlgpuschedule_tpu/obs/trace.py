@@ -137,12 +137,14 @@ class Tracer:
     @contextlib.contextmanager
     def phase(self, sections, name: str, *, span: str | None = None,
               meter=None, **attrs: Any) -> Iterator[None]:
-        """One boundary of a run loop, named once: its wall time adds to
-        ``sections[name]`` (the ``SectionTimer`` that
-        ``RunTelemetry._section_delta`` and the report's phase table
-        read), it is a span (named ``span`` where the stream's name for
-        the boundary differs from the section's), and with ``meter`` (an
-        ``OverlapMeter``) it is that meter's busy lane ``name``."""
+        """One boundary of a run loop or of set-up, named once: its wall
+        time goes to ``sections(name)`` (a run loop's ``SectionTimer``,
+        which ``RunTelemetry._section_delta`` and the report's phase
+        table read; for a set-up phase the start-up account's
+        ``StartupAccount.span``), it is a span (named ``span`` where the
+        stream's name for the boundary differs from the section's), and
+        with ``meter`` (an ``OverlapMeter``) it is that meter's busy lane
+        ``name``."""
         with sections(name), self.span(span or name, **attrs):
             if meter is None:
                 yield

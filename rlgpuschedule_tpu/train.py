@@ -715,11 +715,12 @@ def main(argv: list[str] | None = None) -> dict:
             exp = PopulationExperiment.build(
                 cfg, n_pop=args.n_pop, mesh=run_mesh,
                 pbt_cfg=PBTConfig(ready_iters=args.pbt_ready,
-                                  seed=cfg.seed))
+                                  seed=cfg.seed),
+                telemetry=telemetry)
         else:
             from .experiment import Experiment
             run_mesh = make_run_mesh(args.mesh, cfg.n_envs)
-            exp = Experiment.build(cfg, mesh=run_mesh)
+            exp = Experiment.build(cfg, mesh=run_mesh, telemetry=telemetry)
         if run_mesh is not None:
             from .parallel import rule_table_hash, rules_for
             print(f"mesh: {dict(run_mesh.shape)} rules="
@@ -850,6 +851,11 @@ def main(argv: list[str] | None = None) -> dict:
             sys.exit(f"divergence watchdog gave up: {e}")
 
         summary = {k: v for k, v in out.items() if k != "history"}
+        # from the process's start to the end of its first run call, by
+        # part (import, backend, build, tracing and lowering, compiling,
+        # cache loads, running): obs.startup
+        from .obs import startup
+        summary["startup"] = startup.first_run_summary()
         if trunk_record is not None:
             summary["trunk"] = trunk_record
         if run_mesh is not None:

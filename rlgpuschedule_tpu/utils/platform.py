@@ -12,6 +12,9 @@ one fixed path inside the checkout.
 from __future__ import annotations
 
 import os
+import time
+
+from .. import stamp
 
 # <repo>/.jax_cache, resolved from this file's own location: the cache
 # path is part of what a caller must be able to predict and carry, so it
@@ -62,7 +65,9 @@ def device_record() -> dict:
     run can never be read as the chip's."""
     import jax
 
-    devices = jax.devices()
+    start = time.monotonic()
+    devices = jax.devices()     # the first call starts the backend's client
+    stamp("backend", start)     # a span of the start-up account
     return {"platform": devices[0].platform,
             "kind": devices[0].device_kind, "count": len(devices)}
 

@@ -403,6 +403,13 @@ def test_preset_trains_three_iterations_through_experiment_run():
         assert h["attn_kernel_layers"] == 0.0       # a CPU: the plain path
         assert h["kda_kernel_layers"] == 0.0        # ... for the rule too
         assert 0 < h["moe_assignments_held"] <= 16 * 18 * 2 * 5
+    # the start-up account's record of that call keeps its last logged
+    # iteration, path counters and all (benchmark/readers/run_counters.py)
+    from rlgpuschedule_tpu.algos.ppo import MOE_COUNTERS
+    from rlgpuschedule_tpu.obs.startup import ACCOUNT
+    run = [s for s in ACCOUNT.snapshot()["spans"] if s["name"] == "run"][-1]
+    assert run["metrics"] == out["history"][-1]
+    assert set(MOE_COUNTERS) <= set(run["metrics"])
 
 
 def test_preset_trains_checkpoints_serves_and_evaluates(tmp_path,
@@ -414,7 +421,7 @@ def test_preset_trains_checkpoints_serves_and_evaluates(tmp_path,
     monkeypatch.setattr(chip_smoke, "CONFIG", "ppo-ling-philly512")
     size = dict(chip_smoke.TINY,
                 shape=[*chip_smoke.TINY["shape"], "--trunk", "ling-tiny"])
-    smoke = chip_smoke.Smoke(chip_smoke.CompileMeter())
+    smoke = chip_smoke.Smoke()
     chip_smoke.run_one_chip(smoke, size, str(tmp_path), seed=0)
     assert smoke.ran == ["train", "serve", "evaluate"]
     assert not smoke.failed

@@ -63,7 +63,7 @@ def test_preset_trains_checkpoints_serves_and_evaluates(tmp_path,
     monkeypatch.setattr(chip_smoke, "CONFIG", "ppo-ouro-philly512")
     size = dict(chip_smoke.TINY,
                 shape=[*chip_smoke.TINY["shape"], "--trunk", "ouro-tiny"])
-    smoke = chip_smoke.Smoke(chip_smoke.CompileMeter())
+    smoke = chip_smoke.Smoke()
     chip_smoke.run_one_chip(smoke, size, str(tmp_path), seed=0)
     assert smoke.ran == ["train", "serve", "evaluate"]
     assert not smoke.failed
